@@ -9,8 +9,35 @@ import csv
 import json
 import os
 import subprocess
+import sys
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+# exit code of a measuring script that was not pinned to the CPU and
+# found no TPU
+NO_TPU_RC = 4
+
+
+def require_tpu_unless_cpu_pinned(who: str) -> str:
+    """The platform label of a process about to measure, e.g. "tpu x4".
+    Initialises the jax backend, so the process that calls this owns the
+    chip.  The only CPU measurement is one the caller asked for by
+    pinning JAX_PLATFORMS=cpu (the CI smoke, the tests); without the pin
+    a machine with no TPU ends the script here — jax falls back to the
+    CPU silently, and a CPU number must never be printed under a device
+    metric's name."""
+    import jax
+
+    devs = jax.devices()
+    if os.environ.get("JAX_PLATFORMS", "") != "cpu" and devs[0].platform != "tpu":
+        print(
+            f"{who}: no TPU (jax.devices()[0].platform is "
+            f"{devs[0].platform!r}) and JAX_PLATFORMS=cpu was not pinned; "
+            "nothing measured",
+            file=sys.stderr, flush=True,
+        )
+        raise SystemExit(NO_TPU_RC)
+    return ",".join(sorted({d.platform for d in devs})) + f" x{len(devs)}"
 
 
 def with_benchmark(name: str, fn: Callable[[], Any]) -> Tuple[Any, float]:

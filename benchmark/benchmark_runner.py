@@ -398,9 +398,9 @@ BENCHMARKS: Dict[str, Callable[[Any, Report], None]] = {
 
 
 def main(argv: Optional[list] = None) -> None:
-    from spark_rapids_ml_tpu._jax_env import apply_jax_platforms_env
+    from spark_rapids_ml_tpu._jax_env import configure_compile_cache
 
-    apply_jax_platforms_env()
+    configure_compile_cache()
     p = argparse.ArgumentParser(
         description="spark_rapids_ml_tpu benchmark runner "
         "(reference benchmark_runner.py registry)"

@@ -76,6 +76,66 @@ def gen_default(n_rows: int, n_cols: int, *, seed: int = 0):
     return rng.random((n_rows, n_cols), dtype=np.float32), None
 
 
+def classification_slab(n_cols: int, seed: int, index: int, rows: int):
+    """Slab `index` of the seeded dense binary-classification stream:
+    standard-normal f32 features, label = [x . true_w > 0].  Each slab
+    draws from its own child of `seed`, so any slab regenerates alone
+    (a caller checking a transform re-derives its rows without re-reading
+    the file) and slabs generate in parallel."""
+    true_w = np.random.default_rng(seed).standard_normal(n_cols).astype(
+        np.float32
+    )
+    rng = np.random.default_rng([seed, 1 + index])
+    X = rng.standard_normal((rows, n_cols), dtype=np.float32)
+    return X, (X @ true_w > 0).astype(np.float64)
+
+
+def write_classification_slabs(
+    path: str, n_rows: int, n_cols: int, *, seed: int = 11,
+    slab_rows: int = 50_000, workers: int = 1, **writer_kwargs,
+) -> None:
+    """Write `classification_slab`s to ONE parquet file, a row group per
+    slab, holding at most `workers` slabs at a time: the way the refconfig
+    1M x 3000 file (12 GB) is made on a host that could not hold it twice.
+    `features` is a fixed-size-list column, `label` float64."""
+    from collections import deque
+    from concurrent.futures import ThreadPoolExecutor
+
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    sizes = [
+        min(slab_rows, n_rows - at) for at in range(0, n_rows, slab_rows)
+    ]
+    workers = max(1, workers)
+    writer = None
+    futures: deque = deque()
+    nxt = 0
+    with ThreadPoolExecutor(workers) as pool:
+        try:
+            while nxt < len(sizes) or futures:
+                while nxt < len(sizes) and len(futures) < workers:
+                    futures.append(pool.submit(
+                        classification_slab, n_cols, seed, nxt, sizes[nxt]
+                    ))
+                    nxt += 1
+                Xs, ys = futures.popleft().result()
+                t = pa.table({
+                    "features": pa.FixedSizeListArray.from_arrays(
+                        pa.array(Xs.reshape(-1)), n_cols
+                    ),
+                    "label": pa.array(ys),
+                })
+                if writer is None:
+                    writer = pq.ParquetWriter(path, t.schema, **writer_kwargs)
+                writer.write_table(t)
+                del Xs, ys, t
+        finally:
+            if writer is not None:
+                writer.close()
+
+
 GENERATORS = {
     "blobs": gen_blobs,
     "low_rank_matrix": gen_low_rank_matrix,

@@ -10,9 +10,8 @@
 #
 # Only numeric metrics are kept (config strings, error strings and the
 # embedded `*_telemetry` dicts stay in the raw artifact); appends are
-# idempotent per (run_id, section) so bench.py's per-section flushes and
-# ci/tpu_bench_loop.py's post-run append can both fire without
-# duplicating records.  `benchmark/compare.py` consumes this file to
+# idempotent per (run_id, section) so bench.py's per-section flushes
+# never duplicate records.  `benchmark/compare.py` consumes this file to
 # gate regressions against the median of the last k runs.
 #
 from __future__ import annotations
@@ -206,7 +205,7 @@ def append_records(records: List[Dict[str, Any]], path: str) -> int:
     if dirname:
         os.makedirs(dirname, exist_ok=True)
     # ONE O_APPEND os.write for the whole batch: concurrent bench runs
-    # sharing a history file (tpu_bench_loop's default) and a SIGTERM
+    # sharing a history file and a SIGTERM
     # handler re-entering mid-flush interleave at write boundaries, not
     # mid-line — a buffered line-by-line append could tear records,
     # which load_history would then drop silently
@@ -229,8 +228,7 @@ def append_run(
 ) -> int:
     """Normalize + append one bench payload.  Idempotent per
     (run_id, section): bench.py calls this after every completed section
-    (the partial-flush cadence) and ci/tpu_bench_loop.py once more on
-    the committed artifact — later calls only add sections that
+    (the partial-flush cadence) — later calls only add sections that
     completed since."""
     return append_records(normalize_run(payload, run_id, ts), path)
 

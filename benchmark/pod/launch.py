@@ -61,15 +61,14 @@ def _run_shard(
     """Configure distributed bootstrap in THIS process and exec the
     benchmark runner (each pod host runs exactly this)."""
     if platform == "cpu":
+        # before jax is imported: it reads both at import
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={devices_per_process}"
         )
+    sys.path.insert(0, REPO)
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    sys.path.insert(0, REPO)
     from spark_rapids_ml_tpu import init_distributed
     from spark_rapids_ml_tpu.config import set_config
 
@@ -137,7 +136,14 @@ def main(argv=None) -> int:
             runner_args, args.platform or "tpu", args.devices_per_process,
         )
 
-    # local emulation: spawn one subprocess per "host"
+    # local emulation: spawn one subprocess per "host".  A CPU mode only:
+    # every local process would open every chip of this host, and a chip
+    # belongs to one process at a time
+    if args.platform == "tpu":
+        ap.error(
+            "local emulation is a CPU mode (N local processes cannot "
+            "share this host's chips); use --pod, one process per host"
+        )
     port = _free_port()
     procs = []
     for pid in range(args.num_processes):

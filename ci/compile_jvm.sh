@@ -12,7 +12,7 @@
 #     echo says so.
 #   * neither, but network   -> opportunistic fetch: coursier -> scalac,
 #     then the syntax gate (first networked environment produces a real
-#     compile log — VERDICT r4 item 7).
+#     compile log).
 #   * air-gapped, no JVM     -> the structural gate
 #     (ci/jvm_structural_check.py): brace balancing, ServiceLoader
 #     registration resolution, Plugin target resolution, operator

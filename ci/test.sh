@@ -193,9 +193,7 @@ echo "== pod chaos smoke: kill -9 one rank mid-pass, survivor byte parity =="
 # the reduction generation (zombie-rank safety), reassign the dead
 # rank's row-group share to itself, replay its OWN share from the chunk
 # cache, and finish with coefficients BYTE-identical to a fault-free
-# 1-process fit.  Self-skips via the require_coordination_cpu probe on
-# builds whose CPU coordination service can't host two ranks.
-# Intentionally ALSO in a tier-1 batch above (the batch-completeness
+# 1-process fit.  Intentionally ALSO in a tier-1 batch above (the batch-completeness
 # guard requires it there); this dedicated step keeps the chaos gate
 # visible and runnable in isolation.
 JAX_PLATFORMS=cpu WEDGE_GUARD_S=540 \
@@ -212,8 +210,7 @@ echo "== pod observatory smoke: straggler named, one incident bundle per pod =="
 # absent ring NAMED and the merged pod trace parseable.  (3) 2-rank
 # split shifted traffic — the fleet-merged drift_score equals the
 # 1-process score over the combined rows, one drift bundle per pod.
-# Self-skips via require_coordination_cpu where 2-rank coordination is
-# unavailable.  Intentionally ALSO in a tier-1 batch above (the
+# Intentionally ALSO in a tier-1 batch above (the
 # batch-completeness guard requires it there); this dedicated step
 # keeps the observatory gate visible and runnable in isolation.
 JAX_PLATFORMS=cpu WEDGE_GUARD_S=540 \

@@ -121,9 +121,8 @@ def _const_bytes(consts: Sequence[Any]) -> int:
 
 
 def _retrace(real_jax: Any, fn: Any, kw: dict, args: tuple, kwargs: dict):
-    """Re-trace `fn` the way its jit saw the first call.  0.4.x
-    `make_jaxpr` has no static_argnames, so statics passed as KWARGS
-    bind into a partial and statics passed POSITIONALLY map to
+    """Re-trace `fn` the way its jit saw the first call: statics passed
+    as KWARGS bind into a partial and statics passed POSITIONALLY map to
     static_argnums through the signature — either way they stay Python
     values while everything else traces."""
     import inspect
@@ -291,8 +290,7 @@ class CompileDelta:
 @contextlib.contextmanager
 def count_compiles() -> Iterator[CompileDelta]:
     """Measure `compiles_total` / `recompiles_total` growth across the
-    block (the jax.monitoring listener installs on entry; on jax builds
-    without it `listener` stays False and compiles reads 0)."""
+    block (the jax.monitoring listener installs on entry)."""
     from ..telemetry.compile import install_jax_listener
 
     delta = CompileDelta(listener=install_jax_listener())

@@ -192,9 +192,6 @@ def main(infile: IO = sys.stdin, outfile: IO = sys.stdout) -> None:
     works — a single line then EOF)."""
     import os
 
-    from ._jax_env import apply_jax_platforms_env
-
-    apply_jax_platforms_env()
     for line in infile:
         line = line.strip()
         if not line:

@@ -1,9 +1,8 @@
 #
 # Fused stage-and-solve engine — the one-pass sufficient-statistics
 # estimators (PCA, LinearRegression) solve WHILE they stage.  The
-# two-phase path pays stage + solve strictly additively (BENCH_r05:
-# refconfig PCA = 220 s stage + 193 s solve); here each host chunk's
-# Gram/moment/cross contribution is folded into a donated device
+# two-phase path pays stage + solve strictly additively; here each host
+# chunk's Gram/moment/cross contribution is folded into a donated device
 # accumulator the moment the chunk lands on the mesh, with the host
 # producer thread (utils.prefetch_iter — the PR-2 staging pipeline's
 # overlap primitive) prepping chunk N+1 while the mesh accumulates chunk
@@ -144,7 +143,7 @@ def _acc_spec(kind: str, d: int, l: int, dtype):
 
 def fused_chunk_rows(n: int, d: int, itemsize: int, n_dev: int) -> int:
     """Rows per fused chunk: bounded by `staging_chunk_bytes` clamped to
-    the transfer-RPC ceiling (the same sizing rule as the staging
+    the single-transfer ceiling (the same sizing rule as the staging
     pipeline's pieces — mesh._staging_chunk_rows), floored so a pass
     still yields >= `_MIN_CHUNKS` chunks to overlap, and device-aligned
     so every chunk shards evenly over the mesh."""

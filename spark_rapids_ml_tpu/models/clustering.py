@@ -220,8 +220,8 @@ class KMeans(KMeansClass, _TpuEstimator, _KMeansTpuParams):
         seed = int(seed) if seed is not None else int(self.getOrDefault("seed"))
         max_iter = int(p["max_iter"])
         # fused single-program Lloyd until the whole solve (init
-        # included) could exceed the per-program device-time budget
-        # (45 s dispatch rule); then host-dispatched per-block
+        # included) could exceed the per-program budget
+        # (`dispatch_flops_limit`); then host-dispatched per-block
         # iterations.  The gate itself lives in ops/kmeans.py
         # kmeans_fit_auto, shared with the IVF quantizer training.
         # `checkpoint_dir` set -> the stepwise (checkpointable) solver

@@ -2,10 +2,12 @@
 # Native host-staging bindings — loads native/staging.cpp (the analog of
 # the reference's native memory layer: `_concat_and_free`/reserved-memory
 # staging utils.py:358-522 and numpy_allocator.py's C hooks) via ctypes,
-# building the shared library on first use with the baked-in g++.  Every
-# entry point has a numpy fallback, so the package works without a
-# compiler; the native path parallelizes the pad/cast/pack/densify loops
-# that feed `jax.device_put`.
+# building the shared library on first use with the host's g++ — and
+# again whenever the one on disk was not built from this source on this
+# machine (`_build_key`).  Every entry point has a numpy fallback, so the
+# package works without a compiler (`status()` says which path runs);
+# the native path parallelizes the pad/cast/pack/densify loops that feed
+# `jax.device_put`.
 #
 from __future__ import annotations
 
@@ -23,10 +25,12 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_REPO_ROOT, "native", "staging.cpp")
 _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
 _LIB_PATH = os.path.join(_BUILD_DIR, "libstaging.so")
+_KEY_PATH = _LIB_PATH + ".key"
 
 _lock = named_lock("native_build")
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+_fallback_reason = ""
 
 
 _BUILD_TIMEOUT_S = 300
@@ -40,16 +44,54 @@ class NativeBuildTimeout(RuntimeError):
     is exactly what's needed to debug it."""
 
 
+_CXXFLAGS = (
+    "-O3", "-march=native", "-fopenmp", "-shared", "-fPIC", "-std=c++17",
+)
+
+
+def _read(path: str, mode: str = "rb"):
+    try:
+        with open(path, mode) as f:
+            return f.read()
+    except OSError:
+        return b"" if "b" in mode else ""
+
+
+def _build_key() -> str:
+    """What a usable libstaging.so was built from and where: the source,
+    the flags, this host's CPU feature flags (`-march=native` bakes them
+    in) and this boot of this machine.  A library copied in beside the
+    tree from another machine — build outputs are git-ignored but tools
+    that copy the directory carry them — never matches, so it is rebuilt
+    rather than dlopen'ed."""
+    import hashlib
+
+    cpu_flags = next(
+        (
+            ln for ln in _read("/proc/cpuinfo", "r").splitlines()
+            if ln.startswith(("flags", "Features"))
+        ),
+        "",
+    )
+    h = hashlib.sha256()
+    for part in (
+        _read(_SRC),
+        " ".join(_CXXFLAGS).encode(),
+        cpu_flags.encode(),
+        _read("/proc/sys/kernel/random/boot_id"),
+    ):
+        h.update(part)
+        h.update(b"\0")
+    return h.hexdigest()
+
+
 def _build() -> bool:
-    global _load_failed
+    global _load_failed, _fallback_reason
     os.makedirs(_BUILD_DIR, exist_ok=True)
     # compile to a process-unique temp path and rename into place so
     # concurrent builders never dlopen a half-written library
     tmp_path = f"{_LIB_PATH}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O3", "-march=native", "-fopenmp", "-shared", "-fPIC",
-        "-std=c++17", _SRC, "-o", tmp_path,
-    ]
+    cmd = ["g++", *_CXXFLAGS, _SRC, "-o", tmp_path]
     try:
         proc = subprocess.run(
             cmd, capture_output=True, text=True, timeout=_BUILD_TIMEOUT_S
@@ -67,30 +109,34 @@ def _build() -> bool:
             f"`{' '.join(cmd)}`"
             + (f"; partial stderr: {stderr[-500:]}" if stderr else "")
         ) from e
-    except Exception as e:  # g++ missing etc.
+    except OSError as e:  # g++ missing
+        _fallback_reason = f"build unavailable ({e})"
         get_logger("spark_rapids_ml_tpu.native").warning(
-            f"native staging build unavailable ({e}); using numpy fallback"
+            f"native staging {_fallback_reason}; using numpy fallback"
         )
         return False
     if proc.returncode != 0:
+        _fallback_reason = f"build failed: {proc.stderr[-200:].strip()}"
         get_logger("spark_rapids_ml_tpu.native").warning(
             f"native staging build failed; using numpy fallback:\n{proc.stderr[-500:]}"
         )
         return False
     os.replace(tmp_path, _LIB_PATH)
+    with open(_KEY_PATH, "w") as f:
+        f.write(_build_key())
     return True
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _load_failed
+    global _lib, _load_failed, _fallback_reason
     if _lib is not None or _load_failed:
         return _lib
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_LIB_PATH) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_LIB_PATH)
+        if (
+            not os.path.exists(_LIB_PATH)
+            or _read(_KEY_PATH, "r") != _build_key()
         ):
             if not _build():
                 _load_failed = True
@@ -98,8 +144,9 @@ def _load() -> Optional[ctypes.CDLL]:
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError as e:
+            _fallback_reason = f"load failed ({e})"
             get_logger("spark_rapids_ml_tpu.native").warning(
-                f"native staging load failed ({e}); using numpy fallback"
+                f"native staging {_fallback_reason}; using numpy fallback"
             )
             _load_failed = True
             return None
@@ -133,6 +180,16 @@ def _load() -> Optional[ctypes.CDLL]:
             f"native staging library loaded ({lib.staging_num_threads()} threads)"
         )
     return _lib
+
+
+def status() -> str:
+    """One line for an operator: whether host staging runs the native
+    kernels or numpy, and why — a missing compiler is otherwise found
+    later as a slow `stage`."""
+    lib = _load()
+    if lib is None:
+        return f"numpy ({_fallback_reason or 'native library unavailable'})"
+    return f"native ({lib.staging_num_threads()} threads)"
 
 
 def available() -> bool:
@@ -325,6 +382,6 @@ def densify_csr(csr, n_pad: int, dtype: np.dtype) -> np.ndarray:
 
 
 __all__ = [
-    "NativeBuildTimeout", "available", "pad_cast", "pack_rows",
+    "NativeBuildTimeout", "available", "status", "pad_cast", "pack_rows",
     "densify_csr", "gather_rows_strided",
 ]

@@ -20,7 +20,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..utils import pcast_compat, shard_map_compat
 from .precision import distance_precision
 
 # ---------------------------------------------------------------------------
@@ -211,8 +210,8 @@ def knn_topk_metric(
         if pcast_axis is not None:
             # under shard_map the merged carry becomes device-varying; the
             # init must match (the ops/knn.py ring does the same)
-            run_d = pcast_compat(run_d, (pcast_axis,), to="varying")
-            run_i = pcast_compat(run_i, (pcast_axis,), to="varying")
+            run_d = jax.lax.pcast(run_d, (pcast_axis,), to="varying")
+            run_i = jax.lax.pcast(run_i, (pcast_axis,), to="varying")
         return jax.lax.fori_loop(0, nib, one_iblock, (run_d, run_i))
 
     ds, ids = jax.lax.map(one_qblock, jnp.arange(nqb, dtype=jnp.int32))
@@ -250,7 +249,7 @@ def umap_knn_graph(
             )
         return finalize_sqdist(d2, metric), ids
     if mesh is not None and mesh.devices.size > 1:
-        kernel = shard_map_compat(
+        kernel = jax.shard_map(
             lambda xi, vi, ii, qs: knn_topk_metric(
                 xi, vi, ii, qs, k=k, metric=metric, p=p,
                 pcast_axis=DATA_AXIS,

@@ -25,21 +25,24 @@
 #     innermost, so the scratch state carries across item tiles and
 #     re-initializes per query tile via pl.when(j == 0).
 #
-# The kernel is exact (same results as the XLA path, modulo distance
-# ULPs) and is dispatched behind the `pallas_knn` config flag: "off"
-# (default), "auto" (real TPU backends), "on" (everywhere; tests run it
-# in interpret mode on CPU).
+# The kernel is exact in interpret mode (the CPU tests) and is
+# dispatched behind the `pallas_knn` config flag: "off" (default), "auto"
+# (real TPU backends), "on" (everywhere; tests run it in interpret mode
+# on CPU).
 #
-# MEASURED OUTCOME (v5e, 100k items x 10k queries x k=32, BENCH_r03):
-# 15.1k QPS fused vs 53.4k QPS XLA — the fused kernel is 3.5x SLOWER.
-# The premise that the (q, n) HBM round-trip dominates was wrong at
-# these shapes: XLA's top_k is the bottleneck on both paths, and its
-# sort-based selection on (block, n) tiles beats this kernel's k-round
-# VPU min/argmin sweep (k passes over (bq, k+bn) on the ~1 Top/s VPU
-# outweigh the MXU matmul).  Mosaic has no in-kernel sort/top_k to close
-# that gap, so the XLA path stays the default; the kernel remains
-# hardware-validated (exact parity on chip) and dispatchable for
-# experimentation.
+# MEASURED OUTCOME (v5e, 100k items x 10k queries x d=64 x k=32, f32;
+# PR 21's chip run on jax 0.9.0 / libtpu 0.0.34): Mosaic compiles the
+# kernel (9.1 s cold) and it runs, 0.590 s warm against 0.122 s for the
+# XLA blocked kernel — 4.8x SLOWER, the third round to measure it losing.
+# The premise that the (q, n) HBM round-trip dominates was wrong at these
+# shapes: XLA's top_k is the bottleneck on both paths, and its sort-based
+# selection on (block, n) tiles beats this kernel's k-round VPU
+# min/argmin sweep.  It is also NOT exact on the chip: the in-kernel
+# dot_general takes no precision argument, so it runs one bf16 pass where
+# the XLA path runs `distance_precision` (exact f32) — the same run gave
+# 85.1 % neighbour-id agreement with the XLA result and squared distances
+# off by up to 0.21.  The XLA path stays the default; ROADMAP Design 5
+# schedules this file's deletion.
 #
 from __future__ import annotations
 
@@ -49,13 +52,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _BIG_F32 = 3.0e38  # "+inf" stand-in that survives arithmetic (python float:
 # a jnp scalar would be a captured constant inside the pallas kernel)
@@ -125,12 +122,6 @@ def fused_topk_sqdist(
     best first; invalid/padded items never appear (+inf distance, index
     -1 past the valid count).  Callers map positions to global ids.
     """
-    if not _HAS_PLTPU:
-        raise RuntimeError(
-            "jax.experimental.pallas.tpu is unavailable in this JAX build; "
-            "use the XLA kernels (config pallas_knn='off', or dispatch via "
-            "ops.knn.knn_topk_single which degrades to them automatically)"
-        )
     q, d = queries.shape
     n = items.shape[0]
     bq = min(bq, max(8, q))
@@ -181,7 +172,7 @@ def pallas_knn_eligible(d: int, dtype=None) -> bool:
     fit VMEM next to the selection temps), and so do non-f32 inputs — the
     kernel computes in f32, which would silently change the f64 results
     the XLA path preserves under float32_inputs=False."""
-    if not _HAS_PLTPU or d > 4096:
+    if d > 4096:
         return False
     return dtype is None or jnp.dtype(dtype) == jnp.float32
 

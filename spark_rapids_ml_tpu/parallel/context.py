@@ -148,16 +148,6 @@ def probe_device_health(devices=None) -> list:
     return lost
 
 
-def _runtime_initialized() -> bool:
-    """Whether the jax distributed runtime is live, across jax versions:
-    `jax.distributed.is_initialized()` where it exists, else the
-    `global_state.client` probe older releases expose."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    return _coordination_client() is not None
-
-
 def init_distributed(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -181,7 +171,7 @@ def init_distributed(
     global _distributed_initialized
     # NB: do not touch jax.process_count()/jax.devices() here — they
     # initialize the XLA backend, after which distributed init is rejected
-    if _distributed_initialized or _runtime_initialized():
+    if _distributed_initialized or jax.distributed.is_initialized():
         _distributed_initialized = True
         _start_pod_liveness()
         return True
@@ -250,7 +240,7 @@ def shutdown_distributed() -> bool:
     tear down (single-host mode, or already shut down)."""
     global _distributed_initialized
     was_live = False
-    if _runtime_initialized():
+    if jax.distributed.is_initialized():
         jax.distributed.shutdown()
         was_live = True
     _distributed_initialized = False
@@ -323,19 +313,12 @@ _psum_fns: Dict = {}
 
 def _coordination_client():
     """The live coordination-service client, or None outside distributed
-    mode.  jax keeps it on the distributed module's `global_state` (the
-    same handle `multihost_utils` and cluster bootstrap use) — public on
-    `jax.distributed` in some releases, only on `jax._src.distributed`
-    in others (0.4.3x); the getattr chain tolerates both."""
-    state = getattr(jax.distributed, "global_state", None)
-    if state is None:
-        try:
-            from jax._src import distributed as _dist
+    mode.  jax keeps it on `jax._src.distributed.global_state` (the same
+    handle `multihost_utils` and cluster bootstrap use) and offers no
+    public accessor."""
+    from jax._src import distributed as _dist
 
-            state = getattr(_dist, "global_state", None)
-        except Exception:
-            state = None
-    return getattr(state, "client", None)
+    return _dist.global_state.client
 
 
 def _reduce_timeout_ms() -> int:

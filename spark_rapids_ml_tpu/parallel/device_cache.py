@@ -1,8 +1,7 @@
 #
 # Device-resident dataset cache — the "stage once, fit/evaluate many"
 # layer (the Snap ML hierarchical-accelerator-cache lesson from PAPERS.md
-# applied to the JAX runtime).  Staging dominates large fits (BENCH_r05:
-# 220 s of a 413 s PCA fit), and before this layer `CrossValidator.fit`
+# applied to the JAX runtime).  Before this layer `CrossValidator.fit`
 # paid `2k+1` full host->device stagings of overlapping rows per run: k
 # fold-train stagings in `fitMultiple`, k fold-eval stagings in
 # `_transformEvaluate`, plus the best-model refit.  Here the full dataset
@@ -85,11 +84,35 @@ def _note(kind: str, detail: str = "") -> None:
     event(f"device_cache_{kind}", detail=detail)
 
 
+def device_hbm_bytes(device) -> int:
+    """Bytes of memory ONE device offers the byte model: what its
+    allocator reports (`memory_stats()["bytes_limit"]` — on a v5e less
+    than the 16 GiB on the data sheet), unless the `hbm_bytes` conf was
+    set explicitly.  Backends that report nothing (the CPU test mesh)
+    get the conf's default; a TPU that reports nothing is an error — a
+    guessed limit there routes a fit that fits to the streaming path, or
+    one that does not into an OOM."""
+    from ..config import get_config, is_explicit
+
+    if is_explicit("hbm_bytes"):
+        return int(get_config("hbm_bytes"))
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        return int(limit)
+    if device.platform == "tpu":
+        raise RuntimeError(
+            f"{device} reports no memory_stats()['bytes_limit']; set the "
+            "hbm_bytes conf to its per-device memory explicitly"
+        )
+    return int(get_config("hbm_bytes"))
+
+
 def device_data_budget_bytes() -> float:
     """The device-memory budget staged training data is accounted
-    against: hbm_bytes * mem_ratio_for_data * n_devices — ONE formula
-    shared with `_TpuCaller._over_device_budget` (core.py) so the cache
-    can never believe in more memory than the staging decisions do.
+    against: mem_ratio_for_data x the sum of `device_hbm_bytes` over the
+    devices — ONE formula shared with `_TpuCaller._over_device_budget`
+    (core.py) so the cache can never believe in more memory than the
+    staging decisions do.
     Counts ACTIVE devices only: after an elastic mesh shrink the lost
     chips' HBM is gone with them.  Multi-process, each rank stages and
     caches only its ADDRESSABLE shards (mesh.ShardedRowWriter), so the
@@ -104,10 +127,8 @@ def device_data_budget_bytes() -> float:
     if jax.process_count() > 1:
         pid = jax.process_index()
         devices = [d for d in devices if d.process_index == pid]
-    return (
-        float(get_config("hbm_bytes"))
-        * float(get_config("mem_ratio_for_data"))
-        * len(devices)
+    return float(get_config("mem_ratio_for_data")) * sum(
+        device_hbm_bytes(d) for d in devices
     )
 
 

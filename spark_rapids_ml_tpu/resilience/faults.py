@@ -1,6 +1,6 @@
 #
 # Deterministic fault injection — the test harness for every recovery
-# path.  Real OOM / tunnel-timeout / TPU-preemption faults only occur on
+# path.  Real OOM / transfer-timeout / TPU-preemption faults only occur on
 # hardware under load; CI runs on a CPU mesh, so recovery code would
 # otherwise ship unexercised (the reference has the same gap: its barrier
 # re-schedule path is only exercised by live executor loss).  Dispatch

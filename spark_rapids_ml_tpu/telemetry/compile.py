@@ -7,10 +7,10 @@
 # flip drops every compiled kernel — none of which was measurable
 # before this module.  Two mechanisms, used together:
 #
-#   jax.monitoring   where available (jax >= 0.4.x ships
-#                    `register_event_duration_secs_listener`), a
-#                    process-global listener turns jax's own compile
-#                    events (`/jax/core/compile/jaxpr_trace_duration`,
+#   jax.monitoring   a process-global listener
+#                    (`register_event_duration_secs_listener`) turns
+#                    jax's own compile events
+#                    (`/jax/core/compile/jaxpr_trace_duration`,
 #                    `.../jaxpr_to_mlir_module_duration`,
 #                    `.../backend_compile_duration`) into the
 #                    `compile_seconds{fn=,phase=}` histogram and the
@@ -22,9 +22,7 @@
 #                    attributes to the work that paid it.
 #   explicit spans   `compile_span(fn)` wraps our OWN lowering seams
 #                    (the staging-program builders in parallel/mesh.py)
-#                    in a timed trace span + the same histogram — the
-#                    fallback that keeps the numbers flowing on jax
-#                    builds without the monitoring hooks.
+#                    in a timed trace span + the same histogram.
 #
 # Recompiles are always EXPLICIT: `note_recompile(fn, reason)` bumps
 # `recompiles_total{fn=,reason=}` and drops a `recompile[fn]` instant
@@ -129,20 +127,14 @@ def _on_duration(key: str, duration_s: float, **_kw) -> None:
 def install_jax_listener() -> bool:
     """Register the jax.monitoring duration listener (idempotent; jax
     offers no per-listener removal, so it installs once per process).
-    Returns whether the listener is active — False on jax builds
-    without the monitoring API, where only the explicit `compile_span`
-    seams record."""
+    Returns whether the listener is active."""
     global _installed
     with _install_lock:
-        if _installed:
-            return True
-        try:
+        if not _installed:
             from jax import monitoring
 
             monitoring.register_event_duration_secs_listener(_on_duration)
-        except Exception:
-            return False
-        _installed = True
+            _installed = True
         return True
 
 
@@ -154,9 +146,9 @@ def listener_installed() -> bool:
 def compile_span(fn: str) -> Iterator[None]:
     """Time one of OUR lowering seams (a staging-program build, an
     explicit re-lower) as a trace span + a `compile_seconds{fn=,
-    phase="explicit"}` observation — the jax-version-independent path.
-    The monitoring listener (when active) also records the inner jax
-    phases under the same `fn` via the label scope."""
+    phase="explicit"}` observation.  The monitoring listener also
+    records the inner jax phases under the same `fn` via the label
+    scope."""
     import time
 
     from ..tracing import trace

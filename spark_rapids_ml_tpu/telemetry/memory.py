@@ -164,12 +164,20 @@ class SimulatedMemoryProvider(MemoryProvider):
         # otherwise a device whose arrays all freed would keep its stale
         # last gauge value forever
         live: Dict[int, int] = {int(d.id): 0 for d in active_devices()}
+        # a buffer counts once: `addressable_shards` caches, on the array,
+        # single-device arrays over the SAME buffers, and those are live
+        # arrays themselves from the next census on
+        seen = set()
         for arr in jax.live_arrays():
             try:
                 if getattr(arr, "is_deleted", None) and arr.is_deleted():
                     continue
                 for sh in arr.addressable_shards:
                     did = int(sh.device.id)
+                    buf = (did, sh.data.unsafe_buffer_pointer())
+                    if buf in seen:
+                        continue
+                    seen.add(buf)
                     live[did] = live.get(did, 0) + int(sh.data.nbytes)
             except Exception:
                 continue  # a mid-donation array can vanish underneath us
@@ -185,8 +193,9 @@ def get_provider() -> Optional[MemoryProvider]:
     """The provider the `memory_provider` conf selects — resolved once
     and cached (`reset_memory_telemetry()` re-resolves):
     "auto" = real where any device reports `memory_stats()`, else
-    simulated; "real" / "simulated" force one; "off" disables sampling
-    entirely."""
+    simulated — except on a TPU backend, where a device without
+    `memory_stats()` raises; "real" / "simulated" force one; "off"
+    disables sampling entirely."""
     global _provider
     with _lock:
         if _provider is not None:
@@ -200,12 +209,20 @@ def get_provider() -> Optional[MemoryProvider]:
         prov = RealMemoryProvider()
     elif mode == "simulated":
         prov = SimulatedMemoryProvider()
+    elif RealMemoryProvider.available():
+        prov = RealMemoryProvider()
     else:
-        prov = (
-            RealMemoryProvider()
-            if RealMemoryProvider.available()
-            else SimulatedMemoryProvider()
-        )
+        from ..parallel.mesh import active_devices
+
+        if any(d.platform == "tpu" for d in active_devices()):
+            # the census under the same gauge names would pass for the
+            # allocator's numbers exactly where they matter
+            raise RuntimeError(
+                "memory_provider=auto: a TPU device reports no "
+                "memory_stats(); set memory_provider=simulated or off "
+                "to run without the allocator's counters"
+            )
+        prov = SimulatedMemoryProvider()
     with _lock:
         _provider = prov
     return prov if prov.name != "none" else None
@@ -229,13 +246,16 @@ def reset_memory_telemetry() -> None:
 def sample_devices() -> Dict[int, int]:
     """Take one sample: update the registry gauges, feed every active
     fit watermark, and return {device_id: bytes_in_use}.  Returns {} (and
-    touches nothing) when the provider is off/unavailable.  Never raises
-    — memory observability must not fail the work it observes."""
+    touches nothing) when the provider is off.  A failed sample never
+    raises — memory observability must not fail the work it observes."""
     global _last_sample_t
+    # resolved outside the guard: a provider that cannot be resolved (a
+    # TPU without allocator counters under "auto") is a configuration
+    # error the fit must see, not a failed sample
+    prov = get_provider()
+    if prov is None:
+        return {}
     try:
-        prov = get_provider()
-        if prov is None:
-            return {}
         stats = prov.sample()
     except Exception:
         return {}

@@ -194,6 +194,11 @@ class FitTelemetry:
                 1 for e in instants
                 if e.name.endswith("_resume") or e.name == "elastic_recovery[resumed]"
             ),
+            # a resident fit that ran out of device memory and was refit
+            # by epoch streaming (core._stage_or_stream)
+            "oom_streaming_refits": sum(
+                1 for e in instants if e.name == "oom_streaming_refit"
+            ),
         }
         rec = _view_delta(deltas, "recovery")
         if rec:

@@ -482,9 +482,7 @@ _RANK = textwrap.dedent(
 )
 
 
-def test_two_rank_distributed_retries_sum_exactly(
-    tmp_path, require_multiprocess_cpu
-):
+def test_two_rank_distributed_retries_sum_exactly(tmp_path):
     """The ROADMAP-item-1 CI seam: two real jax.distributed ranks each
     run a fit with one injected retryable fault and dump their
     registries; the merged page's `retries_total` is the EXACT sum of

@@ -834,7 +834,7 @@ def test_jit_audit_donation_consumed(jax_mod):
             warnings.simplefilter("ignore")
             bad(acc2, jnp.ones((1024,), jnp.float32))
 
-    with jax_mod.experimental.enable_x64(), audit_jits(
+    with jax_mod.enable_x64(True), audit_jits(
         modules=(build_and_run.__module__,)
     ) as rep:
         build_and_run()

@@ -820,9 +820,7 @@ def _write_chaos_parquet(tmp_path, n=1000, d=4):
     return ppath
 
 
-def test_two_rank_straggler_table_and_merged_trace(
-    tmp_path, require_coordination_cpu
-):
+def test_two_rank_straggler_table_and_merged_trace(tmp_path):
     """The pod-observatory smoke: a 2-rank fused fit with an injected
     device-side slowdown on rank 1 — the straggler table (same on
     every rank) names rank 1 for device_accumulate, and the merged
@@ -865,9 +863,7 @@ def test_two_rank_straggler_table_and_merged_trace(
     assert rep["pass_id"] in (ids[0] & ids[1])
 
 
-def test_two_rank_chaos_one_incident_bundle(
-    tmp_path, require_coordination_cpu
-):
+def test_two_rank_chaos_one_incident_bundle(tmp_path):
     """SIGKILL chaos variant: rank 1 dies mid-accumulate; the survivor
     writes exactly ONE rank_loss bundle carrying the incident id, its
     merged pod trace parses (Perfetto-loadable), the dead rank's ring
@@ -896,9 +892,7 @@ def test_two_rank_chaos_one_incident_bundle(
     assert out["report"].get("ranks", {}).get("0")
 
 
-def test_two_rank_fleet_drift_parity_and_single_alert(
-    tmp_path, require_coordination_cpu
-):
+def test_two_rank_fleet_drift_parity_and_single_alert(tmp_path):
     """Fleet drift acceptance: shifted traffic split across 2 ranks
     scores EXACTLY like one process over the combined rows (the sketch
     wire merge is exact at these row counts), and the sustained breach

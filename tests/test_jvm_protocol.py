@@ -129,8 +129,8 @@ def test_rf_model_operator_resolution(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Field-by-field golden tests (VERDICT r3 item: cover every wrapper in
-# Wrappers.scala / TpuModels.scala): for each algorithm the Scala
+# Field-by-field golden tests covering every wrapper in Wrappers.scala /
+# TpuModels.scala: for each algorithm the Scala
 # ModelBuilder reconstructs, run the REAL worker fit and assert every
 # `attrs \ "field"` it reads is present and shaped as the builder expects.
 # ---------------------------------------------------------------------------

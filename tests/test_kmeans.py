@@ -148,8 +148,8 @@ def test_single_sample_predict(rng):
 
 
 def test_stepwise_lloyd_matches_fused(rng):
-    # kmeans_fit_stepwise (host-dispatched blocks, the 45s-dispatch-rule
-    # path for huge n*d*k) must reproduce the fused while_loop fit.  The
+    # kmeans_fit_stepwise (host-dispatched blocks, the
+    # `dispatch_flops_limit` path for huge n*d*k) must reproduce the fused while_loop fit.  The
     # contract is "same update math, trajectories match up to f32
     # reduction order" (the stepwise docstring) — asserted in two parts.
     # The old form of this test compared full 50-iteration trajectories

@@ -330,8 +330,9 @@ def test_evaluate_with_features_cols_and_weights(rng):
 
 def test_host_dispatched_lbfgs_matches_fused(rng):
     # forcing a tiny per-program budget routes the dense fit through the
-    # host-driven L-BFGS (one dispatched evaluation per program, the 45s
-    # dispatch rule path); the optimum must match the fused while_loop
+    # host-driven L-BFGS (one dispatched evaluation per program, the
+    # `dispatch_flops_limit` path); the optimum must match the fused
+    # while_loop
     from spark_rapids_ml_tpu.config import reset_config, set_config
 
     n, d = 4000, 12
@@ -375,11 +376,7 @@ def test_host_dispatched_lbfgs_no_constant_capture(rng):
     # call-time jit on the host-dispatch path is re-traced with
     # make_jaxpr and its captured-const bytes bounded at 16 KB — at
     # test scale the dataset alone is 128 KB, so a closure-capture
-    # regression trips the bound loudly.  (The first form of this test
-    # flipped `jax_captured_constants_warn_bytes` and promoted jax's
-    # warning to an error; that config knob does not exist on the jax
-    # 0.4.x line this container ships, so the test died in
-    # AttributeError before asserting anything.)
+    # regression trips the bound loudly.
     from spark_rapids_ml_tpu.analysis.jit_audit import (
         assert_clean,
         audit_jits,

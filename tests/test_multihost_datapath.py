@@ -6,10 +6,9 @@
 # BYTE-identical to a single-process pass over the same parquet file.
 #
 # The 2-rank tests stand only on the jax.distributed coordination
-# service (require_coordination_cpu) — deliberately weaker than the
-# cross-process XLA collective probe, because the wire reduce backend
-# is exactly what lets pods whose XLA backend has no cross-process
-# collectives (0.4.x CPU wheels) still fit with parallel ingest.
+# service: the wire reduce backend is exactly what lets pods whose XLA
+# backend has no cross-process collectives still fit with parallel
+# ingest.
 #
 import json
 import os
@@ -411,7 +410,7 @@ _SEAM_WORKER = textwrap.dedent(
 )
 
 
-def test_two_rank_wire_seam(tmp_path, require_coordination_cpu):
+def test_two_rank_wire_seam(tmp_path):
     out = _launch(_SEAM_WORKER, 2, tmp_path, timeout=420)
     assert out["ok"] is True
 
@@ -558,9 +557,7 @@ _PARITY_WORKER = textwrap.dedent(
 )
 
 
-def test_two_process_fused_parity_byte_identical(
-    tmp_path, require_coordination_cpu
-):
+def test_two_process_fused_parity_byte_identical(tmp_path):
     """THE pod-parity contract: 2-process parallel ingest + wire-reduced
     fused PCA / linreg / describe() must be byte-identical to the
     single-process fit.  Integer-valued float64 data makes every partial

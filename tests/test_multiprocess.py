@@ -210,9 +210,7 @@ def _run_workers(nproc: int, tmp_path, timeout: int = 900) -> dict:
         return json.load(f)
 
 
-def test_two_process_fit_matches_single_process(
-    tmp_path, require_multiprocess_cpu
-):
+def test_two_process_fit_matches_single_process(tmp_path):
     """2 processes x 2 devices vs 1 process x 4 devices: same 4-way mesh,
     same global data split per-process -> same LogReg/KMeans/PCA models."""
     single = _run_workers(1, tmp_path)

@@ -97,8 +97,9 @@ def test_eligibility_guards():
 
 def test_measured_auto_decision(monkeypatch):
     """pallas_knn=auto on a probe backend measures both kernels once per
-    shape bucket, commits to the faster (the 0.38x BENCH_r05 regression
-    class: auto must never pin a fit to the slower kernel), and reuses
+    shape bucket, commits to the faster (the fused kernel has measured
+    0.21-0.38x XLA on chip: auto must never pin a fit to the slower
+    kernel), and reuses
     the cached verdict without re-probing."""
     from spark_rapids_ml_tpu.ops import knn as knn_mod
     from spark_rapids_ml_tpu.ops.knn import knn_topk_single

@@ -690,9 +690,7 @@ def _launch_chaos(script_body, nproc, tmp_path, args=(), timeout=600):
         return json.load(f)
 
 
-def test_two_rank_chaos_kill_mid_pass_survivor_parity(
-    tmp_path, require_coordination_cpu
-):
+def test_two_rank_chaos_kill_mid_pass_survivor_parity(tmp_path):
     """THE acceptance chaos run: 2 ranks fit a fused linear regression,
     rank 1 is SIGKILLed mid-pass; rank 0 must detect the death via
     liveness, shrink to a quorum of one, replay + decode every share,

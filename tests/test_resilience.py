@@ -58,7 +58,7 @@ def test_error_classifiers():
     assert is_oom(RuntimeError("Out of memory allocating 1234 bytes"))
     assert not is_oom(ValueError("bad shape"))
     assert is_transient(DispatchTimeout("fit_kernel", 1.0))
-    assert is_transient(RuntimeError("DEADLINE_EXCEEDED: tunnel stall"))
+    assert is_transient(RuntimeError("DEADLINE_EXCEEDED: transfer stall"))
     assert is_transient(RuntimeError("UNAVAILABLE: Socket closed"))
     assert is_preemption(SimulatedPreemption("fit_kernel"))
     assert is_preemption(RuntimeError("TPU worker preempted by scheduler"))
@@ -98,57 +98,6 @@ def test_preemption_classifier_real_coordinator_strings():
 
     assert is_device_loss(dl)
     assert classify_error(dl) == "device_loss"
-
-
-def test_remote_compile_flake_classifier():
-    """The r05 UMAP bench killer — a compile-service HTTP 500 — must back
-    off and retry (transient), while genuine compiler rejections and
-    unrelated INTERNAL errors stay fatal."""
-    from spark_rapids_ml_tpu.resilience import is_remote_compile_flake
-
-    flake = RuntimeError(
-        "INTERNAL: Mosaic failed ... remote_compile: HTTP 500 Internal "
-        "Server Error"
-    )
-    assert is_remote_compile_flake(flake)
-    assert is_transient(flake)
-    assert classify_error(flake) == "transient"
-    assert classify_error(
-        RuntimeError("UNAVAILABLE ... remote_compile: connection refused")
-    ) == "transient"
-    # compiler REJECTING the program is not a flake: retrying burns
-    # budget.  Real rejections carry the same 'INTERNAL:' status prefix
-    # as flakes (JaxRuntimeError stamps it on everything), so the
-    # classifier must key on the flake markers, not the prefix.
-    rejected = RuntimeError(
-        "JaxRuntimeError: INTERNAL: Mosaic failed ... remote_compile: "
-        "HTTP 400 bad program"
-    )
-    assert not is_remote_compile_flake(rejected)
-    assert classify_error(rejected) == "fatal"
-    # unrelated INTERNAL errors (real lowering bugs) stay fatal too
-    assert classify_error(RuntimeError("INTERNAL: unsupported op")) == "fatal"
-
-
-def test_remote_compile_flake_retries_then_succeeds():
-    _fast_retries()
-    calls = {"n": 0}
-
-    def flaky():
-        calls["n"] += 1
-        if calls["n"] < 3:
-            raise RuntimeError(
-                "JaxRuntimeError: INTERNAL: ... remote_compile: HTTP 500"
-            )
-        return "compiled"
-
-    assert retry_call(flaky, label="compile") == "compiled"
-    assert calls["n"] == 3
-
-
-# ---------------------------------------------------------------------------
-# guarded dispatch
-# ---------------------------------------------------------------------------
 
 
 def test_guarded_passthrough_when_disabled():

@@ -188,8 +188,7 @@ def test_sparse_knn_save_load(sparse_rows, tmp_path):
 @pytest.mark.parametrize("algorithm", ["ivfflat", "cagra"])
 def test_sparse_ann_matches_dense(sparse_rows, algorithm):
     # CSR ANN input fits through the same staging as dense input and
-    # returns identical neighbors (the CHANGELOG "sparse ANN equivalence"
-    # claim, backed here)
+    # returns identical neighbors
     from spark_rapids_ml_tpu.knn import ApproximateNearestNeighbors
 
     csr, X = sparse_rows

@@ -143,7 +143,7 @@ def test_ell_conversion(rng):
 
 
 # ---------------------------------------------------------------------------
-# Sparse breadth beyond LogReg (VERDICT r3 item 6): blocked-densify
+# Sparse breadth beyond LogReg: blocked-densify
 # sufficient statistics for PCA / LinearRegression, chunked sparse
 # transform, sparse kNN, and the int64-index CSR story.
 # ---------------------------------------------------------------------------

@@ -680,9 +680,8 @@ def test_budget_drift_across_cache_insert_evict_cycle(rng):
 
 def test_compile_listener_attributes_to_label_scope():
     """A fresh-shape jit compile inside a `compile_label` scope lands on
-    `compile_seconds{fn=<label>}` and bumps `compiles_total` (jax 0.4.x
-    ships the monitoring hooks this relies on; the explicit
-    `compile_span` path is version-independent)."""
+    `compile_seconds{fn=<label>}` and bumps `compiles_total`; the
+    explicit `compile_span` path records phase=explicit."""
     import jax
     import jax.numpy as jnp
 

@@ -359,7 +359,7 @@ def test_structured_kernel_full_fit_quality(blobs):
 
 def test_umap_kernel_auto_probes_by_measurement(rng):
     """auto mode with enough epochs must time BOTH kernels and commit to
-    the faster one (VERDICT r4: platform heuristics shipped a 1.7x CPU
+    the faster one (platform heuristics once shipped a 1.7x CPU
     slowdown unmeasured) — and the probe's epochs are real fit epochs, so
     the result must equal a forced run of the winning kernel only when
     the kernels agree; here we just pin the decision bookkeeping."""
