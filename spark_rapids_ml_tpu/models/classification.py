@@ -415,13 +415,15 @@ class LogisticRegression(
 
         from ..ops.logistic import logreg_fit, logreg_fit_binary
         from ..ops.stats import standardize, weighted_moments
+        from ..tracing import event, trace
 
         p = fit_input.params
         dtype = np.dtype(fit_input.dtype)
         # label range via two on-device scalar reductions — pulling the full
         # y/w arrays to host would cross HBM->host for the whole dataset;
         # integrality was validated host-side pre-staging (_validate_input)
-        y_min, y_max = _label_range(fit_input.y, fit_input.w)
+        with trace("label_range"):
+            y_min, y_max = _label_range(fit_input.y, fit_input.w)
         y_min, y_max = int(y_min), int(y_max)
 
         # degenerate single-label dataset (Spark semantics: +/-inf intercept,
@@ -597,8 +599,6 @@ class LogisticRegression(
                 f"program's second copy of the features "
                 f"{'fits' if fits else 'does NOT fit'} the device"
             )
-            from ..tracing import event
-
             # which solver ran is a fact of the fit: it goes in the fit
             # report's span tree, not only in the log
             event(
@@ -616,14 +616,19 @@ class LogisticRegression(
                     binomial=binomial, checkpoint_path=ckpt_path,
                     checkpoint_tag=ckpt_tag, **kwargs
                 )
-            elif binomial:
-                coef, b, loss, n_iter, hist = logreg_fit_binary(
-                    X, w, fit_input.y, **kwargs
-                )
             else:
-                coef, b, loss, n_iter, hist = logreg_fit(
-                    X, w, fit_input.y, n_classes=n_classes, **kwargs
-                )
+                # asynchronous: the call returns once the one program is
+                # dispatched (or, the first time, compiled); the wait for
+                # it lands in `solve_fetch`
+                with trace("lbfgs_fused_dispatch"):
+                    if binomial:
+                        coef, b, loss, n_iter, hist = logreg_fit_binary(
+                            X, w, fit_input.y, **kwargs
+                        )
+                    else:
+                        coef, b, loss, n_iter, hist = logreg_fit(
+                            X, w, fit_input.y, n_classes=n_classes, **kwargs
+                        )
         # ONE batched device->host fetch for every output (each separate
         # np.asarray/float() would pay a full host sync)
         fetch = {"coef": coef, "b": b, "loss": loss, "n_iter": n_iter,
@@ -632,7 +637,8 @@ class LogisticRegression(
             fetch["std"] = std
             if mean is not None:
                 fetch["mean"] = mean
-        host = jax.device_get(fetch)
+        with trace("solve_fetch"):
+            host = jax.device_get(fetch)
         loss, n_iter = host["loss"], host["n_iter"]
         if binomial:
             coef = np.asarray(host["coef"], np.float64).reshape(1, -1)

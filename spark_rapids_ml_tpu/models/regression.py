@@ -216,53 +216,60 @@ class LinearRegression(
         return checkpoint_file_for(ckpt_dir, tag), tag
 
     def _fit_array(self, fit_input: FitInput) -> Dict[str, Any]:
+        import jax
+
         from ..ops.linear import linreg_sufficient_stats, solve_linear_host
+        from ..tracing import trace
 
         p = fit_input.params
-        gram, sxy, s1, sw, sy, syy = linreg_sufficient_stats(
-            fit_input.X, fit_input.w, fit_input.y
-        )
-        gram_h, sxy_h = np.asarray(gram), np.asarray(sxy)
-        ckpt_path, ckpt_tag = self._fista_checkpoint(gram_h, sxy_h, float(sw))
-        coef, intercept, diag = solve_linear_host(
-            gram_h,
-            sxy_h,
-            np.asarray(s1),
-            float(sw),
-            float(sy),
-            float(syy),
-            reg_param=float(p["alpha"]),
-            elasticnet_param=float(p["l1_ratio"]),
-            fit_intercept=bool(p["fit_intercept"]),
-            standardization=bool(p.get("standardization", True)),
-            tol=float(p["tol"]),
-            max_iter=int(p["max_iter"]),
-            checkpoint_path=ckpt_path,
-            checkpoint_tag=ckpt_tag,
-        )
+        # the device's pass, the copy to the host and the host's solve each
+        # under a span of its own: the fetch follows the wait at once, so
+        # waiting here serialises nothing that overlapped
+        with trace("linreg_gram"):
+            stats = jax.block_until_ready(
+                linreg_sufficient_stats(fit_input.X, fit_input.w, fit_input.y)
+            )
+        with trace("linreg_fetch"):
+            gram_h, sxy_h, s1_h = (np.asarray(a) for a in stats[:3])
+            sw, sy, syy = (float(a) for a in stats[3:])
+        ckpt_path, ckpt_tag = self._fista_checkpoint(gram_h, sxy_h, sw)
+        with trace("linreg_host_solve"):
+            coef, intercept, diag = solve_linear_host(
+                gram_h,
+                sxy_h,
+                s1_h,
+                sw,
+                sy,
+                syy,
+                reg_param=float(p["alpha"]),
+                elasticnet_param=float(p["l1_ratio"]),
+                fit_intercept=bool(p["fit_intercept"]),
+                standardization=bool(p.get("standardization", True)),
+                tol=float(p["tol"]),
+                max_iter=int(p["max_iter"]),
+                checkpoint_path=ckpt_path,
+                checkpoint_tag=ckpt_tag,
+            )
         # summary metrics via a cancellation-free residual pass over the
         # still-staged data (the one-pass SSE expansion loses ~eps·Σwy²)
-        import jax
         import jax.numpy as jnp
 
         from ..ops.linear import _summary_from_sse, linreg_residual_sse
 
-        sse = float(
-            jax.device_get(
-                linreg_residual_sse(
-                    fit_input.X,
-                    fit_input.w,
-                    fit_input.y,
-                    jnp.asarray(coef, fit_input.X.dtype),
-                    fit_input.X.dtype.type(intercept),
+        with trace("linreg_residual"):
+            sse = float(
+                jax.device_get(
+                    linreg_residual_sse(
+                        fit_input.X,
+                        fit_input.w,
+                        fit_input.y,
+                        jnp.asarray(coef, fit_input.X.dtype),
+                        fit_input.X.dtype.type(intercept),
+                    )
                 )
             )
-        )
         diag.update(
-            _summary_from_sse(
-                sse, float(sw), float(sy), float(syy),
-                bool(p["fit_intercept"]),
-            )
+            _summary_from_sse(sse, sw, sy, syy, bool(p["fit_intercept"]))
         )
         dtype = np.dtype(fit_input.dtype)
         return {

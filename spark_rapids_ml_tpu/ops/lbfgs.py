@@ -14,6 +14,7 @@
 #
 from __future__ import annotations
 
+import time
 from typing import Callable, NamedTuple, Tuple
 
 import jax
@@ -106,7 +107,8 @@ def lbfgs_minimize(
     def body(state):
         w, f, g, S, Y, rho, k, it, _, hist = state
         pg = _pseudo_gradient(w, g, l1, l1_mask)
-        p = direction(pg, S, Y, rho, k)
+        with jax.named_scope("lbfgs_two_loop"):
+            p = direction(pg, S, Y, rho, k)
         # OWL-QN: force descent orthant agreement with -pseudo-gradient
         p = jnp.where(l1 > 0, jnp.where(p * (-pg) > 0, p, 0.0), p)
         # orthant for projection: sign(w), or sign(-pg) where w == 0
@@ -240,11 +242,26 @@ def lbfgs_minimize_host(
         save_checkpoint,
     )
 
+    from ..tracing import record_span
+
     n = w0.shape[0]
     m = history
     l1 = float(l1)
     if l1_mask is None:
         l1_mask = np.ones((n,), np.float64)
+
+    # the host's own share of the solve, one `lbfgs_host_step` span per
+    # stretch between evaluations: the preamble before the first, the
+    # two-loop / line-search bookkeeping / checkpoint write between two,
+    # the tail after the last.  The oracle's spans are the oracle's own.
+    host_since = time.time()
+
+    def evaluate(w_t):
+        nonlocal host_since
+        record_span("lbfgs_host_step", host_since, time.time())
+        out = value_and_grad(w_t)
+        host_since = time.time()
+        return out
 
     def full_term(w):
         return (l1 * l1_mask * np.abs(w)).sum()
@@ -311,7 +328,7 @@ def lbfgs_minimize_host(
         event("lbfgs_resume", detail=f"it={it}")
     else:
         w = np.asarray(w0, np.float64).copy()
-        f, g = value_and_grad(w)
+        f, g = evaluate(w)
         hist = [float(f + full_term(w))]
         converged = False
         it = 0
@@ -334,7 +351,7 @@ def lbfgs_minimize_host(
         w_new, f_new, g_new = w, f, g
         for _ in range(ls_max + 1):
             w_t = project(w + t * p)
-            f_t, g_t = value_and_grad(w_t)
+            f_t, g_t = evaluate(w_t)
             w_new, f_new, g_new = w_t, f_t, g_t
             if f_t + full_term(w_t) <= fw_full + 1e-4 * (pg @ (w_t - w)):
                 break
@@ -373,4 +390,5 @@ def lbfgs_minimize_host(
     hb.close()
     if checkpoint_path:
         clear_checkpoint(checkpoint_path)
+    record_span("lbfgs_host_step", host_since, time.time())
     return w, it, converged, hist

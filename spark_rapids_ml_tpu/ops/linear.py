@@ -42,16 +42,18 @@ def linreg_sufficient_stats(X: jax.Array, w: jax.Array, y: jax.Array):
     row-sharded, w validity*sample weights, y labels (0 on padding)."""
     from .precision import stats_precision
 
-    Xw = X * w[:, None]
-    # the normal equations invert this Gram: f32-exact products by
-    # default (cuML parity; see ops/precision.py stats_precision)
-    hi = stats_precision()
-    gram = jnp.matmul(Xw.T, X, precision=hi)  # (d,d) — MXU, psum over shards
-    sxy = jnp.matmul(Xw.T, y, precision=hi)  # (d,)
-    s1 = Xw.sum(axis=0)  # (d,)
-    sw = w.sum()
-    sy = (y * w).sum()
-    syy = (y * y * w).sum()
+    # the scope names the kernels in a profile (metadata only)
+    with jax.named_scope("linreg_gram"):
+        Xw = X * w[:, None]
+        # the normal equations invert this Gram: f32-exact products by
+        # default (cuML parity; see ops/precision.py stats_precision)
+        hi = stats_precision()
+        gram = jnp.matmul(Xw.T, X, precision=hi)  # (d,d) — MXU, psum over shards
+        sxy = jnp.matmul(Xw.T, y, precision=hi)  # (d,)
+        s1 = Xw.sum(axis=0)  # (d,)
+        sw = w.sum()
+        sy = (y * w).sum()
+        syy = (y * y * w).sum()
     return gram, sxy, s1, sw, sy, syy
 
 
@@ -230,8 +232,9 @@ def linreg_residual_sse(X: jax.Array, w: jax.Array, y: jax.Array,
     data.  Residuals are computed directly, so precision tracks the
     residual magnitude instead of eps·Σw·y² (the one-pass expansion's
     floor)."""
-    r = y - (X @ coef + intercept)
-    return (w * r * r).sum()
+    with jax.named_scope("linreg_residual"):
+        r = y - (X @ coef + intercept)
+        return (w * r * r).sum()
 
 
 @jax.jit

@@ -293,6 +293,7 @@ def assemble_rows_chunked(shape, dtype, pieces, out_shardings=None,
 
 from ..telemetry.locks import named_lock
 from ..telemetry.registry import dict_view as _dict_view
+from ..tracing import record_span, trace
 
 # last staging-engine run: bytes, seconds, mb_per_s, host_prep_s,
 # device_put_s, overlap_ratio, pieces, depth, label (read by bench.py's
@@ -407,7 +408,9 @@ def _dus_rows_done(b, c, lo):
     """`_dus_rows` plus a scalar that becomes ready only when the update
     has run: the buffer itself is donated into the next update, so it
     cannot be waited on."""
-    return _dus_rows(b, c, lo), lo + 1
+    # the scope names the staged chunk's write in a profile (metadata only)
+    with jax.named_scope("stage_chunk"):
+        return _dus_rows(b, c, lo), lo + 1
 
 
 @functools.lru_cache(maxsize=256)
@@ -459,11 +462,12 @@ class ShardedRowWriter:
         pid = jax.process_index()
         # shard index -> live buffer, addressable shards only
         self._bufs = {}
-        for d, dev in enumerate(devices):
-            if getattr(dev, "process_index", pid) != pid:
-                continue
-            mk, _ = _shard_update_fns(shard_shape, self.dtype.str, dev)
-            self._bufs[d] = mk()
+        with trace("stage_alloc"):
+            for d, dev in enumerate(devices):
+                if getattr(dev, "process_index", pid) != pid:
+                    continue
+                mk, _ = _shard_update_fns(shard_shape, self.dtype.str, dev)
+                self._bufs[d] = mk()
         if not self._bufs:
             raise ValueError(
                 "ShardedRowWriter: this process owns none of the target's "
@@ -535,7 +539,8 @@ class ShardedRowWriter:
         # serialization.  Waiting for an older piece to be applied IS
         # transfer time and counts.
         prep_s = time.perf_counter() - t0
-        with self._dev_locks[d]:
+        # one `stage_put` span per piece: the interval `put_s` times
+        with self._dev_locks[d], trace("stage_put"):
             t1 = time.perf_counter()
             done = self._done[d]
             if len(done) >= _MAX_INFLIGHT_PIECES:
@@ -577,6 +582,7 @@ def timed_iter(producer: Iterable, prep: dict) -> Iterator:
     it = iter(producer)
     iv = prep.get("iv")
     while True:
+        t_abs = time.time()
         t = time.perf_counter()
         try:
             item = next(it)
@@ -586,6 +592,10 @@ def timed_iter(producer: Iterable, prep: dict) -> Iterator:
         prep["s"] += t1 - t
         if iv is not None:
             iv.append((t, t1))
+        # the same interval as a `stage_prep` span of the run: on the
+        # prefetch thread where there is one (it adopted the caller's
+        # trace context), so prep shows beside the puts it overlaps
+        record_span("stage_prep", t_abs, t_abs + (t1 - t))
         yield item
 
 
@@ -597,8 +607,10 @@ def run_staging_pipeline(
     `__next__` — through `writer`, with the prep running `depth` items
     ahead on a background thread (`staging_pipeline_depth`; depth 1 =
     serial, no thread).  All jax calls stay on the calling thread.
-    Records throughput + overlap in `STAGE_METRICS` and as a trace
-    event."""
+    Records throughput + overlap in `STAGE_METRICS`; the run's trace
+    holds one `stage_prep` and one `stage_put` span per piece and a
+    `stage_finish` span for the assembly and the bookkeeping after the
+    last put."""
     depth = _staging_depth()
     t0 = time.perf_counter()
     prep = {"s": 0.0, "iv": []}
@@ -617,6 +629,7 @@ def run_staging_pipeline(
                 writer.write(int(lo), rows)
             else:
                 writer.write_shard(int(dev), int(lo), rows)
+        t_finish = time.time()
         out = writer.finish()
     wall = time.perf_counter() - t0
     mb = writer.bytes_written / 1e6
@@ -651,15 +664,7 @@ def run_staging_pipeline(
 
     utilization.note_intervals("host_prep", prep["iv"], cause="stage_prep")
     utilization.note_interval("stage", t0, t0 + wall, cause=label)
-    from ..tracing import event
-
-    event(
-        f"stage_pipeline[{label}]",
-        detail=(
-            f"{mb:.1f}MB {STAGE_METRICS['mb_per_s']}MB/s "
-            f"overlap={overlap:.2f} pieces={writer.pieces} depth={depth}"
-        ),
-    )
+    record_span("stage_finish", t_finish, time.time())
     return out
 
 

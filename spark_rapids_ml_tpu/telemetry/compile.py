@@ -20,6 +20,9 @@
 #                    fit with its estimator name; the staging engine
 #                    labels its program builds), so compile time
 #                    attributes to the work that paid it.
+#                    The same events land in the active run's trace as
+#                    `compile[trace|lower|backend_compile|cache_read]`
+#                    spans, placed where they happened.
 #   explicit spans   `compile_span(fn)` wraps our OWN lowering seams
 #                    (the staging-program builders in parallel/mesh.py)
 #                    in a timed trace span + the same histogram.
@@ -38,6 +41,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 
 from .locks import named_lock
 from typing import Iterator
@@ -71,6 +75,10 @@ _PHASE_BY_KEY = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
     "/jax/core/compile/backend_compile_duration": "backend_compile",
+    # a persistent-cache hit's read lies INSIDE the backend_compile
+    # duration jax reports for the same program: a span, and no further
+    # sample of the histogram
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
 }
 
 _tls = threading.local()
@@ -114,9 +122,32 @@ def compile_label(name: str) -> Iterator[None]:
         stack.pop()
 
 
+def _at_top_level() -> bool:
+    """Whether no jax trace is in progress on this thread."""
+    import jax
+
+    try:
+        return bool(jax.core.trace_ctx.is_top_level())
+    except AttributeError:  # another jax: every trace event gets a span
+        return True
+
+
 def _on_duration(key: str, duration_s: float, **_kw) -> None:
+    from ..tracing import record_span
+
     phase = _PHASE_BY_KEY.get(key)
     if phase is None:
+        return
+    # jax reports a phase as it ends, on the thread that ran it: the span
+    # `compile[<phase>]` lands in the active run at (now - duration, now),
+    # so a fit that re-jits shows it and an idle gap spent compiling is
+    # named so.  A jitted function traced inside another's trace (every
+    # jnp call of a solver body: hundreds in a cold fit) lies inside its
+    # caller's span and gets none of its own
+    if phase != "trace" or _at_top_level():
+        now = time.time()
+        record_span(f"compile[{phase}]", now - float(duration_s), now)
+    if phase == "cache_read":
         return
     label = current_label()
     compile_seconds.observe(float(duration_s), fn=label, phase=phase)
@@ -149,8 +180,6 @@ def compile_span(fn: str) -> Iterator[None]:
     phase="explicit"}` observation.  The monitoring listener also
     records the inner jax phases under the same `fn` via the label
     scope."""
-    import time
-
     from ..tracing import trace
 
     t0 = time.perf_counter()

@@ -30,13 +30,24 @@ _LOSS_CURVE_KEYS = ("objective_history", "loss_curve", "hist")
 _FINAL_LOSS_KEYS = ("objective", "inertia_", "cost", "loss")
 
 
+# slack when asking whether one span lies inside another: a span's end is
+# its epoch start plus a perf_counter duration, two clocks that agree to
+# well under this
+_INSIDE_SLACK_S = 1e-3
+
+
 def span_tree(events: List[Any]) -> List[Dict[str, Any]]:
-    """Nest the run's events into a start-ordered tree keyed off each
-    span's recorded depth (instant markers attach as zero-duration
-    leaves).  Events arrive start-sorted from
-    `tracing.get_all_trace_events`."""
+    """Nest the run's events into a start-ordered tree (instant markers
+    attach as zero-duration leaves).  An event's parent is the deepest
+    span recorded at a lesser depth that holds it IN TIME, on its own
+    thread before any other: a worker thread that adopted the caller's
+    trace context (the staging prefetch thread, a guarded dispatch)
+    records at the caller's depth, concurrently with the caller's own
+    spans, so depth alone would hang a span under a neighbour that merely
+    started earlier.  A child lies inside its parent, or it is a sibling.
+    Events arrive start-sorted from `tracing.get_all_trace_events`."""
     root: List[Dict[str, Any]] = []
-    stack: List[tuple] = []  # (depth, node)
+    open_spans: List[tuple] = []  # (event, node) that may still hold a later one
     for e in sorted(events, key=lambda e: (e.t0, -e.t1)):
         node: Dict[str, Any] = {
             "name": e.name,
@@ -48,10 +59,18 @@ def span_tree(events: List[Any]) -> List[Dict[str, Any]]:
         if getattr(e, "kind", "span") == "instant":
             node["instant"] = True
         node["children"] = []
-        while stack and stack[-1][0] >= e.depth:
-            stack.pop()
-        (stack[-1][1]["children"] if stack else root).append(node)
-        stack.append((e.depth, node))
+        open_spans = [
+            (p, n) for p, n in open_spans if p.t1 + _INSIDE_SLACK_S >= e.t0
+        ]
+        holders = [
+            (p.depth, p.thread_id == e.thread_id, p.t0, n)
+            for p, n in open_spans
+            if p.depth < e.depth and e.t1 <= p.t1 + _INSIDE_SLACK_S
+        ]
+        parent = max(holders, key=lambda h: h[:3])[3] if holders else None
+        (parent["children"] if parent else root).append(node)
+        if "instant" not in node:
+            open_spans.append((e, node))
     # drop empty children arrays for a compact artifact
     def _prune(nodes: List[Dict[str, Any]]) -> None:
         for n in nodes:

@@ -21,6 +21,7 @@
 from __future__ import annotations
 
 import contextlib
+import sys
 import threading
 import time
 import uuid
@@ -283,14 +284,27 @@ def event(name: str, detail: str = "", log: Optional[object] = None) -> None:
 def trace(name: str, log: Optional[object] = None) -> Iterator[None]:
     """Time a stage.  Nested stages indent; `verbose >= 1` logs on exit.
     The recorded span carries absolute t0/t1, the recording thread id and
-    the active run id (see `run_context`)."""
+    the active run id (see `run_context`).
+
+    Where jax is already imported the stage is also a
+    `jax.profiler.TraceAnnotation`: nothing without a profiler session;
+    with one (`profile_dir`, or a caller's own `start_trace`) a host event
+    of the same name in the same trace as the device operations, on the
+    profiler's clock."""
     depth = getattr(_tls, "depth", 0)
     _tls.depth = depth + 1
+    # no jax import at module scope, and none caused here either
+    jax = sys.modules.get("jax")
+    annotation = jax.profiler.TraceAnnotation(name) if jax is not None else None
     t0_abs = time.time()
     t0 = time.perf_counter()
+    if annotation is not None:
+        annotation.__enter__()
     try:
         yield
     finally:
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
         dt = time.perf_counter() - t0
         _tls.depth = depth
         _append(

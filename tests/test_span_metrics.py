@@ -1,0 +1,129 @@
+#
+# The per-layer metrics that read the spans inside `fit_kernel` and `stage`
+# (chipbench/layer_metrics/): each reader on a hand-made window of two fits
+# with known spans and a table of device programs, its value by hand, and
+# None where the program records no such span (the parent commit does not).
+#
+import types
+
+import pytest
+
+from chipbench import manifest as mf
+
+MANIFEST = mf.load_manifest()
+READERS = ["stage_prep_s", "stage_put_s", "lbfgs_eval_gap_ms", "lbfgs_host_step_s",
+           "lbfgs_evals_per_fit", "compile_s", "linreg_fetch_s", "linreg_host_solve_s"]
+
+
+def ctx_of(fits, modules=None, compiles=0.0, programs=None):
+    adapter = types.SimpleNamespace(
+        PROGRAMS={"lbfgs_eval": ("vg_fn", "logreg_fit")} if programs is None else programs)
+    trace = None if modules is None else {"modules": modules}
+    return {"adapter": adapter, "fits": [{"spans": s} for s in fits],
+            "trace": trace, "compiles_in_window": compiles}
+
+
+# two fits of the host-dispatched logistic route from host rows.  Fit 1 re-jits
+# (its first evaluation holds the compile), fit 2 compiles nothing.
+FIT_1 = [
+    ("fit[LogisticRegression]", 0.0, 10.0),
+    ("stage", 0.0, 4.0),
+    ("stage_prep", 0.1, 1.1), ("stage_put", 0.6, 2.1),      # 1.0 and 1.5
+    ("stage_prep", 1.2, 2.2), ("stage_put", 2.2, 3.7),      # 1.0 and 1.5
+    ("stage_finish", 3.7, 3.9),
+    ("fit_kernel", 4.0, 10.0),
+    ("lbfgs_host_step", 4.0, 4.1),
+    ("lbfgs_eval", 4.1, 6.1),                                # the re-jit: 2.0
+    ("compile[trace]", 4.2, 4.5), ("compile[lower]", 4.5, 4.6),
+    ("compile[backend_compile]", 4.6, 5.6), ("compile[cache_read]", 4.7, 5.5),
+    ("lbfgs_host_step", 6.1, 6.3),
+    ("lbfgs_eval", 6.3, 6.8),                                # 0.5
+    ("lbfgs_host_step", 6.8, 7.1),
+    ("lbfgs_eval", 7.1, 7.8),                                # 0.7
+    ("lbfgs_host_step", 7.8, 7.9),
+    ("solve_fetch", 7.9, 8.0),
+]
+FIT_2 = [
+    ("stage", 20.0, 23.0),
+    ("stage_prep", 20.0, 20.5), ("stage_put", 20.5, 22.5),  # 0.5 and 2.0
+    ("fit_kernel", 23.0, 26.0),
+    ("lbfgs_host_step", 23.0, 23.2),
+    ("lbfgs_eval", 23.2, 24.2),                              # first: left out
+    ("lbfgs_host_step", 24.2, 24.3),
+    ("lbfgs_eval", 24.3, 24.9),                              # 0.6
+    ("lbfgs_host_step", 24.9, 25.0),
+    ("lbfgs_eval", 25.0, 25.6),                              # 0.6
+]
+RIDGE = [
+    ("fit_kernel", 0.0, 2.0), ("linreg_gram", 0.0, 0.6), ("linreg_fetch", 0.6, 0.7),
+    ("linreg_host_solve", 0.7, 1.9), ("linreg_residual", 1.9, 2.0),
+]
+# device seconds and runs by program, as trace_reduce.reduce gives them
+MODULES = {"jit_vg_fn": (2.4, 6), "jit__dus_rows_done": (0.3, 3)}
+
+
+def read(name, ctx):
+    return mf.reader(name)(ctx)
+
+
+def test_the_manifest_lists_the_readers_last_and_finds_them():
+    assert [m["name"] for m in MANIFEST["per_layer"][-len(READERS):]] == READERS
+    assert mf.problems(MANIFEST) == []
+    for m in MANIFEST["per_layer"][-len(READERS):]:
+        assert m["moves"] == "fit_s" and m["better"] == "lower" and m["workloads"]
+
+
+@pytest.mark.parametrize("name, by_hand", [
+    ("stage_prep_s", (2.0 + 0.5) / 2),
+    ("stage_put_s", (3.0 + 2.0) / 2),
+    ("lbfgs_host_step_s", (0.7 + 0.4) / 2),
+    ("lbfgs_evals_per_fit", 3.0),
+    # the later evaluations: 0.5, 0.7, 0.6, 0.6 -> 0.6 s; the program: 2.4 s / 6
+    ("lbfgs_eval_gap_ms", 1e3 * (0.6 - 0.4)),
+    # fit 1: trace, lower and backend_compile end to end, the cache read
+    # inside the last counted once; fit 2 compiled nothing
+    ("compile_s", (1.4 + 0.0) / 2),
+])
+def test_logistic_readers_by_hand(name, by_hand):
+    ctx = ctx_of([FIT_1, FIT_2], MODULES, compiles=1.0)
+    assert read(name, ctx) == pytest.approx(by_hand, abs=1e-12)
+
+
+@pytest.mark.parametrize("name, by_hand", [
+    ("linreg_fetch_s", 0.1), ("linreg_host_solve_s", 1.2), ("compile_s", 0.0)])
+def test_ridge_readers_by_hand(name, by_hand):
+    assert read(name, ctx_of([RIDGE, RIDGE], {})) == pytest.approx(by_hand, abs=1e-12)
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_a_program_without_the_spans_reads_nothing(name):
+    """The parent commit: the spans the benchmark already read, none of the
+    new ones, and a counter that says the window compiled."""
+    old = [("fit[LogisticRegression]", 0.0, 5.0), ("stage", 0.0, 4.0),
+           ("fit_kernel", 4.0, 5.0), ("lbfgs_route[host_dispatch]", 4.0, 4.0)]
+    assert read(name, ctx_of([old, old], MODULES, compiles=2.0)) is None
+
+
+def test_compile_s_is_zero_where_nothing_compiled_and_none_without_fits():
+    assert read("compile_s", ctx_of([RIDGE])) == 0.0
+    assert read("compile_s", ctx_of([])) is None
+
+
+def test_eval_gap_needs_the_device_trace_and_a_later_evaluation():
+    assert read("lbfgs_eval_gap_ms", ctx_of([FIT_1, FIT_2])) is None          # untraced
+    assert read("lbfgs_eval_gap_ms", ctx_of([FIT_1], {"jit_other": (1.0, 2)})) is None
+    only_first = [s for s in FIT_2 if s[0] != "lbfgs_eval"] + [("lbfgs_eval", 23.2, 24.2)]
+    assert read("lbfgs_eval_gap_ms", ctx_of([only_first], MODULES)) is None
+    assert read("lbfgs_eval_gap_ms", ctx_of([FIT_1], MODULES, programs={})) is None
+
+
+def test_evaluations_that_differ_between_fits_show():
+    uneven = FIT_2 + [("lbfgs_eval", 25.7, 25.9)]
+    assert read("lbfgs_evals_per_fit", ctx_of([FIT_1, uneven])) == 3.5
+
+
+def test_compile_s_counts_an_overlap_once():
+    nested = [("compile[trace]", 0.0, 2.0), ("compile[lower]", 1.0, 3.0),
+              ("compile[backend_compile]", 5.0, 6.0), ("compile[cache_read]", 5.2, 5.3),
+              ("lbfgs_eval", 0.0, 9.0)]
+    assert read("compile_s", ctx_of([nested], compiles=1.0)) == pytest.approx(4.0)

@@ -1,0 +1,301 @@
+#
+# The spans inside `fit_kernel` and `stage` (docs/observability.md, "Span
+# vocabulary"): each route of a fit records the spans it is documented to
+# record, they tile their parent, their counts are the program's counts,
+# the jitted programs carry their named scopes under the module names the
+# benchmark matches, and a span costs microseconds and opens a profiler
+# annotation only where jax is loaded.
+#
+import re
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import spark_rapids_ml_tpu.ops.lbfgs as lbfgs_mod
+import spark_rapids_ml_tpu.ops.logistic as logistic_mod
+from spark_rapids_ml_tpu import tracing
+from spark_rapids_ml_tpu.classification import LogisticRegression
+from spark_rapids_ml_tpu.config import reset_config, set_config
+from spark_rapids_ml_tpu.regression import LinearRegression
+from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
+from spark_rapids_ml_tpu.telemetry.report import span_tree
+
+
+@pytest.fixture(autouse=True)
+def _clean_config():
+    reset_config()
+    yield
+    reset_config()
+
+
+def _rows(n, d, seed=0):
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n, d)).astype(np.float32)
+    y = (X @ rng.standard_normal(d) > 0).astype(np.float32)
+    return X, y
+
+
+def _logistic(**kw):
+    return LogisticRegression(
+        maxIter=5, regParam=1e-5, standardization=False, tol=1e-30, **kw
+    )
+
+
+# route -> (estimator, rows, the span that holds the route's work, the spans
+# the route must record under it)
+ROUTES = {
+    "logistic_host_dispatch": (
+        lambda: _logistic(num_workers=1), (65_536, 64), "fit_kernel",
+        {"label_range", "lbfgs_eval", "lbfgs_host_step", "solve_fetch",
+         "compile[trace]", "compile[lower]", "compile[backend_compile]"},
+    ),
+    "logistic_fused": (
+        lambda: _logistic(num_workers=2), (65_536, 64), "fit_kernel",
+        {"label_range", "lbfgs_fused_dispatch", "solve_fetch"},
+    ),
+    "ridge": (
+        lambda: LinearRegression(regParam=1e-5, num_workers=1),
+        (131_072, 64), "fit_kernel",
+        {"linreg_gram", "linreg_fetch", "linreg_host_solve", "linreg_residual"},
+    ),
+    # 256 MB of rows, far over `_PIPELINED_MIN_BYTES`: the staging engine
+    "pipelined_stage": (
+        lambda: _logistic(num_workers=1), (1_048_576, 64), "stage",
+        {"stage_alloc", "stage_prep", "stage_put", "stage_finish"},
+    ),
+}
+
+
+def _walk(nodes, parent=None):
+    for n in nodes:
+        yield n, parent
+        yield from _walk(n.get("children", []), n)
+
+
+def _find(report, name):
+    found = [n for n, _ in _walk(report["spans"]) if n["name"] == name]
+    assert len(found) == 1, f"{len(found)} spans called {name}"
+    return found[0]
+
+
+def _covered(parent):
+    """Share of `parent` that the union of its child spans covers."""
+    covered, end = 0.0, parent["t0"]
+    for c in sorted(parent.get("children", []), key=lambda c: c["t0"]):
+        t1 = c["t0"] + c["seconds"]
+        if t1 > end:
+            covered += t1 - max(c["t0"], end)
+            end = t1
+    return covered / parent["seconds"]
+
+
+def _backend_compiles():
+    return sum(REGISTRY.snapshot().get("compiles_total", {}).values())
+
+
+@pytest.mark.parametrize("route", sorted(ROUTES))
+def test_route_records_its_spans(route, monkeypatch):
+    build, (n, d), holder, names = ROUTES[route]
+    if route == "logistic_host_dispatch":
+        # the per-program budget sends a toy fit down the route the one-chip
+        # cells take
+        set_config(dispatch_flops_limit=1.0)
+    X, y = _rows(n, d)
+
+    evaluations = []
+    real = lbfgs_mod.lbfgs_minimize_host
+
+    def counting(value_and_grad, *a, **kw):
+        def counted(w):
+            evaluations[-1] += 1
+            return value_and_grad(w)
+
+        evaluations.append(0)
+        return real(counted, *a, **kw)
+
+    monkeypatch.setattr(lbfgs_mod, "lbfgs_minimize_host", counting)
+
+    est = build()
+    first = est.fit((X, y)).fit_report()
+    under = {n["name"] for n, _ in _walk(_find(first, holder).get("children", []))}
+    assert names - {n for n in names if n.startswith("compile[")} <= under, under
+    if route == "logistic_host_dispatch":
+        # this route jits its evaluation anew in every fit
+        assert names <= under, under
+
+    # a child lies inside its parent in time
+    for node, parent in _walk(first["spans"]):
+        if parent is not None:
+            assert node["t0"] >= parent["t0"] - 2e-3, (node, parent)
+            assert (node["t0"] + node["seconds"]
+                    <= parent["t0"] + parent["seconds"] + 2e-3), (node, parent)
+
+    # the spans tile their parent: a load spike on a shared CPU only ever
+    # lowers the share, so the best of a few fits is the one that counts
+    best, reports = 0.0, [first]
+    for _ in range(4):
+        if best >= 0.9:
+            break
+        before = _backend_compiles()
+        report = est.fit((X, y)).fit_report()
+        compiled = _backend_compiles() - before
+        reports.append(report)
+        best = max(best, _covered(_find(report, holder)))
+        # a later fit of the same estimator shows backend compiles exactly
+        # where the counter moved
+        spans = [n for n, _ in _walk(report["spans"])
+                 if n["name"] == "compile[backend_compile]"]
+        assert len(spans) == compiled
+    assert best >= 0.9, f"{holder} covered to {best:.2f}"
+
+    # the evaluation spans ARE the evaluations
+    if route == "logistic_host_dispatch":
+        assert len(evaluations) == len(reports)
+        for report, made in zip(reports, evaluations):
+            evals = [n for n, _ in _walk(report["spans"]) if n["name"] == "lbfgs_eval"]
+            steps = [n for n, _ in _walk(report["spans"])
+                     if n["name"] == "lbfgs_host_step"]
+            assert len(evals) == made >= 6
+            assert len(steps) == made + 1  # before, between and after
+    else:
+        assert not any(n["name"] == "lbfgs_eval"
+                       for r in reports for n, _ in _walk(r["spans"]))
+
+
+def test_span_tree_keeps_concurrent_threads_apart():
+    """A worker that adopted the caller's trace context records at the
+    caller's depth while the caller records too: a span hangs under the
+    span that holds it in time, on its own thread first, never under a
+    neighbour that merely started earlier."""
+    E = tracing.TraceEvent
+
+    def ev(name, t0, t1, depth, thread):
+        return E(name, t1 - t0, depth, t0=t0, t1=t1, thread_id=thread)
+
+    events = [
+        ev("stage", 0.0, 10.0, 0, 1),
+        ev("stage_put", 1.0, 4.0, 1, 1),
+        ev("stage_prep", 2.0, 6.0, 1, 2),       # the prefetch thread
+        ev("compile[lower]", 3.0, 3.5, 2, 1),   # inside the put, not the prep
+        ev("stage_put", 6.5, 7.0, 1, 1),
+        ev("late", 9.0, 11.0, 1, 1),            # outlives `stage`: no child of it
+    ]
+    tree = span_tree(events)
+    assert [n["name"] for n in tree] == ["stage", "late"]
+    stage = tree[0]
+    assert [c["name"] for c in stage["children"]] == [
+        "stage_put", "stage_prep", "stage_put"]
+    assert [c["name"] for c in stage["children"][0]["children"]] == ["compile[lower]"]
+    assert "children" not in stage["children"][1]
+
+
+def _lowered_logistic_vg(monkeypatch):
+    """The evaluation program of the host-dispatched route, as that route
+    jits it: the function is local to a call, so catch it at `jax.jit`."""
+    caught = []
+
+    class Jax:
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+        @staticmethod
+        def jit(fn, *a, **kw):
+            jitted = jax.jit(fn, *a, **kw)
+            caught.append(jitted)
+            return jitted
+
+    monkeypatch.setattr(logistic_mod, "jax", Jax())
+    X, y = _rows(256, 8)
+    Xd, yd, w = jnp.asarray(X), jnp.asarray(y), jnp.ones(256, jnp.float32)
+    logistic_mod.logreg_fit_host_dispatch(
+        Xd, w, yd, n_classes=2, l2=1e-5, l1=0.0, max_iter=1, binomial=True)
+    (vg_fn,) = caught
+    return vg_fn.lower(jnp.zeros(9, jnp.float32), Xd, w, yd)
+
+
+def test_programs_keep_their_scopes_and_module_names(monkeypatch):
+    from chipbench import manifest as mf
+
+    from spark_rapids_ml_tpu.ops import linear
+    from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
+
+    X, y = _rows(256, 8)
+    Xd, yd, w = jnp.asarray(X), jnp.asarray(y), jnp.ones(256, jnp.float32)
+    lowered = {
+        "jit_vg_fn": (_lowered_logistic_vg(monkeypatch), {"lbfgs_eval"}),
+        "jit_logreg_fit_binary": (
+            logistic_mod.logreg_fit_binary.lower(Xd, w, yd, 1e-5, 0.0, max_iter=2),
+            {"lbfgs_eval", "lbfgs_two_loop"}),
+        "jit_logreg_fit": (
+            logistic_mod.logreg_fit.lower(
+                Xd, w, yd.astype(jnp.int32), n_classes=3, l2=1e-5, l1=0.0,
+                max_iter=2),
+            {"lbfgs_eval", "lbfgs_two_loop"}),
+        "jit_linreg_sufficient_stats": (
+            linear.linreg_sufficient_stats.lower(Xd, w, yd), {"linreg_gram"}),
+        "jit_linreg_residual_sse": (
+            linear.linreg_residual_sse.lower(Xd, w, yd, jnp.zeros(8), 0.0),
+            {"linreg_residual"}),
+        "jit__dus_rows_done": (
+            jax.jit(mesh_mod._dus_rows_done).lower(
+                Xd, Xd[:16], jnp.asarray(0, jnp.int32)),
+            {"stage_chunk"}),
+    }
+    for module, (low, scopes) in lowered.items():
+        # the name XLA gives the program, which the profiler's "XLA Modules"
+        # line and chipbench/estimators/*.PROGRAMS go by
+        assert re.search(r"module @(\S+)", low.as_text()).group(1) == module
+        located = set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True)))
+        for scope in scopes:
+            assert any(re.search(rf"[/(]{scope}[/)]", loc) for loc in located), (
+                module, scope)
+    # every pattern the benchmark's adapters match finds its program
+    for adapter in ("logreg", "ridge"):
+        for patterns in mf.adapter(adapter).PROGRAMS.values():
+            for pattern in patterns:
+                assert any(pattern in module for module in lowered), pattern
+
+
+def test_trace_opens_an_annotation_only_where_jax_is_loaded(monkeypatch):
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    with tracing.trace("with_jax"):
+        pass
+    assert opened == ["with_jax"]
+
+    monkeypatch.delitem(sys.modules, "jax")
+    with tracing.trace("without_jax"):
+        pass
+    assert opened == ["with_jax"] and "jax" not in sys.modules
+    assert tracing.get_trace_events()[-1].name == "without_jax"
+
+
+def test_a_span_costs_microseconds_without_a_profiler_session():
+    """A loose ceiling, not a speed claim: a fit records 50-150 of these."""
+    def spans(k):
+        t0 = time.perf_counter()
+        for _ in range(k):
+            with tracing.trace("cost"):
+                pass
+        return (time.perf_counter() - t0) / k
+
+    spans(200)
+    assert min(spans(2000) for _ in range(5)) < 20e-6
+    assert tracing.get_trace_events()[-1].thread_id == threading.get_ident()
