@@ -21,6 +21,7 @@
 #
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -61,6 +62,50 @@ def _soft_threshold(v: np.ndarray, t: float) -> np.ndarray:
     return np.sign(v) * np.maximum(np.abs(v) - t, 0.0)
 
 
+def _fortran_view(A: np.ndarray) -> np.ndarray:
+    """What BLAS and LAPACK update in place: for a C-ordered buffer the
+    transposed view, the same matrix where it is symmetric."""
+    return A if A.flags.f_contiguous else A.T
+
+
+def _normal_system(gram, sw: float, mean, scale, ridge: float) -> np.ndarray:
+    """The Gram centred by `mean` and divided by `scale` x `scale` (each
+    skipped where None) plus `ridge` on the diagonal, in float64, built in
+    ONE new buffer: the caller's array is never written, and no other
+    (d,d) array is made."""
+    from scipy.linalg.blas import dger
+
+    A = np.array(gram, dtype=np.float64)
+    if mean is not None:
+        # A -= sw·mean·meanᵀ, a rank-1 update in place
+        dger(-sw, mean, mean, a=_fortran_view(A), overwrite_a=1)
+    if scale is not None:
+        A /= scale[:, None]
+        A /= scale
+    if ridge:
+        A.flat[:: A.shape[0] + 1] += ridge
+    return A
+
+
+def _quadratic_form(gram: np.ndarray, v: np.ndarray) -> float:
+    """vᵀ·gram·v in float64, the Gram widened a block of columns (~2 MB,
+    which stays in cache) at a time: a whole float64 copy of a float32 Gram
+    is one more (d,d) array to first-touch, ~90 ms against ~4 at d = 3000.
+    Through scipy's BLAS, as the factorisation before it: numpy links an
+    OpenBLAS of its own, and the workers of two pools, spinning after
+    their last call, are more than the host's cores; the residual pass
+    that follows then waited 25-35 ms for a core (PERF.md §6, PR 27)."""
+    from scipy.linalg.blas import dgemv
+
+    G = _fortran_view(gram)  # vᵀGv = vᵀGᵀv: either view serves
+    cols = max(1, (1 << 18) // G.shape[0])
+    return float(sum(
+        dgemv(1.0, np.asarray(G[:, j : j + cols], np.float64), v, trans=1)
+        @ v[j : j + cols]
+        for j in range(0, G.shape[1], cols)
+    ))
+
+
 def solve_linear_host(
     gram: np.ndarray,
     sxy: np.ndarray,
@@ -86,7 +131,11 @@ def solve_linear_host(
 
     Returns (coefficients (d,), intercept, diagnostics).
     """
-    gram = np.asarray(gram, np.float64)
+    # only read, in the dtype it came in: at d = 3000 a (d,d) float64 is
+    # 72 MB, first-touched page by page, and the ten temporaries of a build
+    # by whole-array expressions took longer than the factorisation.  The
+    # one such array made here is the system's buffer (`_normal_system`).
+    gram = np.asarray(gram)
     sxy = np.asarray(sxy, np.float64)
     s1 = np.asarray(s1, np.float64)
     sw = float(sw)
@@ -95,32 +144,52 @@ def solve_linear_host(
 
     mean = s1 / sw
     ymean = sy / sw
-    if fit_intercept:
-        gram_c = gram - sw * np.outer(mean, mean)
-        sxy_c = sxy - sw * mean * ymean
-    else:
-        gram_c = gram
-        sxy_c = sxy
+    sxy_c = sxy - sw * mean * ymean if fit_intercept else sxy
 
     # Spark summarizer std (ddof=1) over the *centered* second moments
-    var = np.maximum(np.diag(gram) / sw - mean**2, 0.0) * (sw / max(sw - 1.0, 1.0))
+    gram_diag = np.asarray(np.diag(gram), np.float64)
+    var = np.maximum(gram_diag / sw - mean**2, 0.0) * (sw / max(sw - 1.0, 1.0))
     std = np.sqrt(var)
     std = np.where(std == 0.0, 1.0, std)
     scale = std if standardization else np.ones(d)
-
-    gram_s = gram_c / np.outer(scale, scale)
     sxy_s = sxy_c / scale
+    system = functools.partial(
+        _normal_system, gram, sw,
+        mean if fit_intercept else None, std if standardization else None,
+    )
 
     l1 = reg_param * elasticnet_param
     l2 = reg_param * (1.0 - elasticnet_param)
     n_iter = 0
 
     if reg_param == 0.0:
-        coef_s = np.linalg.lstsq(gram_s, sxy_s, rcond=None)[0]
+        coef_s = np.linalg.lstsq(system(0.0), sxy_s, rcond=None)[0]
     elif l1 == 0.0:
         # ridge closed form; penalty in 1/(2n) objective units -> n·λ₂ on
-        # the un-normalized Gram (the reference's alpha×=m, regression.py:575-580)
-        coef_s = np.linalg.solve(gram_s + sw * l2 * np.eye(d), sxy_s)
+        # the un-normalized Gram (the reference's alpha×=m, regression.py:575-580).
+        # Symmetric positive definite by construction, so a Cholesky
+        # factorisation solves it (half an LU's work, no pivoting).  Where
+        # it is not numerically (a float32-accumulated Gram whose
+        # near-collinear columns the penalty does not lift) the
+        # factorisation says so and the pivoted LU takes it, as before.
+        # Cholesky reads one triangle: a Gram whose [i,j] and [j,i] were
+        # rounded apart (off the chip: 5e-9 of its norm) moves the
+        # coefficients against the LU's by about as much.
+        from scipy.linalg import LinAlgError, cho_factor, cho_solve
+
+        from ..tracing import event
+
+        try:
+            factor = cho_factor(
+                _fortran_view(system(sw * l2)),
+                lower=True, overwrite_a=True, check_finite=False,
+            )
+        except LinAlgError:
+            event("linreg_solver[lu_fallback]")
+            coef_s = np.linalg.solve(system(sw * l2), sxy_s)
+        else:
+            event("linreg_solver[cholesky]")
+            coef_s = cho_solve(factor, sxy_s, check_finite=False)
     else:
         # FISTA on f(β)=1/(2n)(βᵀGβ - 2bᵀβ) + λ₂/2‖β‖², prox for λ₁‖β‖₁
         from ..resilience import maybe_inject
@@ -130,7 +199,8 @@ def solve_linear_host(
             save_checkpoint,
         )
 
-        G = gram_s / sw
+        G = system(0.0)
+        G /= sw
         b = sxy_s / sw
         L = float(np.linalg.eigvalsh(G)[-1]) + l2
         L = max(L, 1e-12)
@@ -195,7 +265,7 @@ def solve_linear_host(
     sse = (
         syy
         - 2.0 * (coef @ sxy + intercept * sy)
-        + coef @ gram @ coef
+        + _quadratic_form(gram, coef)
         + 2.0 * intercept * (s1 @ coef)
         + intercept * intercept * sw
     )

@@ -235,3 +235,135 @@ def test_evaluate_r2_through_origin(rng):
     m = LinearRegression(fitIntercept=False).fit(df)
     s = m.evaluate(df)
     assert abs(s.r2 - m.summary.r2) < 1e-3, (s.r2, m.summary.r2)
+
+
+# ---- solve_linear_host's closed-form ridge branch, from statistics alone ----
+
+_REG = 0.3
+
+
+def _ridge_stats(dtype, weighted, n=500, d=24, seed=3):
+    """Sufficient statistics of a weighted regression problem, summed in
+    float64 and handed over in `dtype` as a caller would hold them."""
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, d)) * rng.uniform(0.5, 3.0, d) + rng.normal(size=d)
+    y = X @ rng.normal(size=d) + 1.7 + rng.normal(size=n)
+    w = rng.uniform(0.5, 2.0, n) if weighted else np.ones(n)
+    Xw = X * w[:, None]
+    gram = Xw.T @ X
+    # symmetric to the bit: the factorisation reads one triangle, the LU of
+    # the reference both, and the two are held to 1e-10 here
+    gram = ((gram + gram.T) / 2).astype(dtype)
+    sxy, s1 = (Xw.T @ y).astype(dtype), Xw.sum(axis=0).astype(dtype)
+    return gram, sxy, s1, float(w.sum()), float(w @ y), float(w @ (y * y))
+
+
+def _textbook_ridge(gram, sxy, s1, sw, sy, syy, fit_intercept, standardization):
+    """The plain float64 solve: centre, scale, add the penalty, pivoted LU;
+    then the summary from the same expansion of the weighted SSE."""
+    gram, sxy, s1 = (np.asarray(a, np.float64) for a in (gram, sxy, s1))
+    d = gram.shape[0]
+    mean, ymean = s1 / sw, sy / sw
+    A, b = gram, sxy
+    if fit_intercept:
+        A, b = gram - sw * np.outer(mean, mean), sxy - sw * mean * ymean
+    scale = np.ones(d)
+    if standardization:
+        var = (np.diag(gram) / sw - mean**2) * (sw / (sw - 1.0))
+        scale = np.sqrt(var)
+    A, b = A / np.outer(scale, scale), b / scale
+    coef = np.linalg.solve(A + sw * _REG * np.eye(d), b) / scale
+    intercept = float(ymean - mean @ coef) if fit_intercept else 0.0
+    sse = (syy - 2.0 * (coef @ sxy + intercept * sy) + coef @ gram @ coef
+           + 2.0 * intercept * (s1 @ coef) + intercept * intercept * sw)
+    sst = syy - sy * sy / sw if fit_intercept else syy
+    return coef, intercept, {"mse": sse / sw, "rmse": np.sqrt(sse / sw),
+                             "r2": 1.0 - sse / sst}
+
+
+def _solve_and_events(stats, fit_intercept, standardization):
+    from spark_rapids_ml_tpu import tracing
+    from spark_rapids_ml_tpu.ops.linear import solve_linear_host
+
+    tracing.reset_trace()
+    out = solve_linear_host(
+        *stats, reg_param=_REG, elasticnet_param=0.0,
+        fit_intercept=fit_intercept, standardization=standardization,
+        tol=1e-30, max_iter=10,
+    )
+    events = [e.name for e in tracing.get_trace_events()
+              if e.name.startswith("linreg_solver[")]
+    return out, events
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["unit_w", "weighted"])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64], ids=["f32", "f64"])
+@pytest.mark.parametrize("standardization", [False, True], ids=["raw", "std"])
+@pytest.mark.parametrize("fit_intercept", [False, True], ids=["origin", "intercept"])
+def test_closed_form_ridge_solve_matches_textbook(
+    fit_intercept, standardization, dtype, weighted
+):
+    stats = _ridge_stats(dtype, weighted)
+    for a in stats[:3]:
+        a.setflags(write=False)  # as a fetched device array is
+    before = [a.tobytes() for a in stats[:3]]
+    (coef, intercept, diag), events = _solve_and_events(
+        stats, fit_intercept, standardization)
+    ref_coef, ref_intercept, ref_diag = _textbook_ridge(
+        *stats, fit_intercept, standardization)
+
+    assert coef.dtype == np.float64
+    np.testing.assert_allclose(coef, ref_coef, rtol=0, atol=1e-10 * np.abs(ref_coef).max())
+    assert intercept == pytest.approx(ref_intercept, rel=1e-10, abs=1e-10)
+    for k in ("mse", "rmse", "r2"):
+        assert diag[k] == pytest.approx(ref_diag[k], rel=1e-12), k
+    assert diag["n_iter"] == 0.0
+    # the caller's statistics are never the buffer the system is built in
+    assert [a.tobytes() for a in stats[:3]] == before
+    assert events == ["linreg_solver[cholesky]"]
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64], ids=["f32", "f64"])
+def test_closed_form_ridge_indefinite_system_takes_the_lu(dtype):
+    """A Gram whose centred part has an eigenvalue under -sw*l2 (what a
+    float32 accumulation over near-collinear columns can hand over) has no
+    Cholesky factor: the solve answers what the pivoted LU answers."""
+    gram, sxy, s1, sw, sy, syy = _ridge_stats(np.float64, weighted=True)
+    mean = s1 / sw
+    lam, V = np.linalg.eigh(gram - sw * np.outer(mean, mean))
+    gram = gram - (lam[0] + 2.0 * sw * _REG) * np.outer(V[:, 0], V[:, 0])
+    stats = (gram.astype(dtype), sxy.astype(dtype), s1.astype(dtype), sw, sy, syy)
+    g64, m64 = np.float64(stats[0]), np.float64(stats[2]) / sw
+    system = g64 - sw * np.outer(m64, m64) + sw * _REG * np.eye(len(s1))
+    assert np.linalg.eigvalsh(system)[0] < 0.0
+
+    (coef, intercept, diag), events = _solve_and_events(stats, True, False)
+    ref_coef, ref_intercept, ref_diag = _textbook_ridge(*stats, True, False)
+    np.testing.assert_allclose(coef, ref_coef, rtol=0, atol=1e-10 * np.abs(ref_coef).max())
+    assert intercept == pytest.approx(ref_intercept, rel=1e-10, abs=1e-10)
+    for k in ("mse", "rmse", "r2"):
+        assert diag[k] == pytest.approx(ref_diag[k], rel=1e-9), k
+    assert events == ["linreg_solver[lu_fallback]"]
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64], ids=["f32", "f64"])
+def test_closed_form_ridge_solve_makes_one_matrix(dtype):
+    """At d = 3000 a (d,d) float64 is 72 MB, first-touched page by page:
+    the ten temporaries of the earlier build cost more than the
+    factorisation (its peak by this probe: 5.0x d^2 x 8 bytes for a float32
+    Gram, 4.0x for a float64 one).  Now: the one system buffer, factored in
+    place, beside blocks of the Gram for the summary."""
+    import tracemalloc
+
+    d = 1024  # the summary's blocks are ~2 MB: a quarter of a matrix here
+    stats = _ridge_stats(dtype, weighted=False, n=2 * d, d=d)
+    _solve_and_events(stats, True, True)  # imports and lazy set-up, untraced
+    tracemalloc.start()
+    try:
+        tracemalloc.reset_peak()
+        _, events = _solve_and_events(stats, True, True)
+        peak = tracemalloc.get_traced_memory()[1]
+    finally:
+        tracemalloc.stop()
+    assert events == ["linreg_solver[cholesky]"]
+    assert peak < 1.5 * d * d * 8, peak / (d * d * 8)

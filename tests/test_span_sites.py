@@ -62,7 +62,8 @@ ROUTES = {
     "ridge": (
         lambda: LinearRegression(regParam=1e-5, num_workers=1),
         (131_072, 64), "fit_kernel",
-        {"linreg_gram", "linreg_fetch", "linreg_host_solve", "linreg_residual"},
+        {"linreg_gram", "linreg_fetch", "linreg_host_solve", "linreg_residual",
+         "linreg_solver[cholesky]"},
     ),
     # 256 MB of rows, far over `_PIPELINED_MIN_BYTES`: the staging engine
     "pipelined_stage": (
@@ -128,6 +129,10 @@ def test_route_records_its_spans(route, monkeypatch):
     if route == "logistic_host_dispatch":
         # this route jits its evaluation anew in every fit
         assert names <= under, under
+    if route == "ridge":
+        # which factorisation solved the system is a fact of the host solve
+        solve = _find(first, "linreg_host_solve")
+        assert [c["name"] for c in solve["children"]] == ["linreg_solver[cholesky]"]
 
     # a child lies inside its parent in time
     for node, parent in _walk(first["spans"]):
