@@ -40,7 +40,7 @@ def readings(cell: dict, seed: int, devices, program: bool) -> dict:
     chips, params = int(cell["chips"]), cfg["params"]
     out = {"seed": seed, "limits": cfg["limits"]}
     fit_input, reference_rows = run.make_input(
-        traffic, get_mesh(chips), cfg, seed, adapter.LABELS)
+        traffic, get_mesh(chips), cfg, seed, mf.data_of(cfg, adapter))
     if program:
         fit = run.one_fit(adapter, adapter.build(params, chips), fit_input,
                           devices[0].platform == "tpu")

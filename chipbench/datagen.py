@@ -2,18 +2,18 @@
 # chipbench/datagen.py: the cell's rows, made ON the devices from --seed in
 # one jitted call (the benchmark's "weights" are its data).
 #
-# The data model is the repo's own seeded stream (benchmark/gen_data.py
-# `classification_slab`): standard-normal f32 features and a hidden
-# direction `true_w`; labels are [x . true_w > 0] ("sign") for a
-# classifier, x . true_w + N(0, 1) ("linear") for a regressor.  The draw
-# differs from numpy's (jax's threefry, one key per device and row block),
-# the distribution is the same.
+# WHAT is drawn belongs to the configuration: its file's `data` block names a
+# data model, a file of its own under chipbench/data_models/ found by that
+# name (`hidden_direction`, `blobs`), and holds the model's parameters.  A
+# model says only what differs between models: what is drawn once from the
+# key, and one block of rows.  HOW rows are drawn is here and shared: one
+# key per device and row block (jax's threefry), each device filling its own
+# shard in row blocks written in place (one jax.random.normal of a whole
+# 6-12 GB shard would need its random bits beside its output).
 #
 # Traffic that fits from host memory draws the same model with numpy
-# (`host_rows`); the comparison then puts those rows on the devices itself.
-#
-# Each device fills its own shard in row blocks: one jax.random.normal of a
-# whole 6-12 GB shard would need its random bits beside its output.
+# (`host_rows`: another stream, the same distribution); the comparison then
+# puts those rows on the devices itself (`put_rows`).
 #
 from __future__ import annotations
 
@@ -21,15 +21,19 @@ import os
 
 import numpy as np
 
+from chipbench import manifest as mf
 from chipbench.blocks import block_rows_for
 
 # rows drawn, or sent to a device, at a time: 0.3 GB of f32 at 3,000 columns
 BLOCK_ROWS = 25_000
 
 
-def _check_labels(labels: str) -> None:
-    if labels not in ("sign", "linear"):
-        raise ValueError(f"labels must be 'sign' or 'linear', got {labels!r}")
+def _model(data: dict):
+    """The data model `data` names, once its block is seen to be whole."""
+    found = mf.data_problems(data)
+    if found:
+        raise ValueError("; ".join(found))
+    return mf.data_model(data["model"])
 
 
 def _key(seed: int):
@@ -40,7 +44,7 @@ def _key(seed: int):
     return jax.random.fold_in(jax.random.key(seed % (1 << 32)), seed >> 32)
 
 
-def _generator(mesh, rows: int, cols: int, labels: str, block_rows: int):
+def _generator(mesh, rows: int, cols: int, model, data: dict, block_rows: int):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -53,17 +57,11 @@ def _generator(mesh, rows: int, cols: int, labels: str, block_rows: int):
     def shard(key):
         # this device's rows: blocks written in place into one buffer
         mine = jax.random.fold_in(key, 1 + jax.lax.axis_index(axis))
-        true_w = jax.random.normal(jax.random.fold_in(key, 0), (cols,), jnp.float32)
+        drawn_once = model.shared(jax.random.fold_in(key, 0), cols, data)
 
         def fill(i, carry):
             X, y = carry
-            kx, kn = jax.random.split(jax.random.fold_in(mine, i))
-            xb = jax.random.normal(kx, (block_rows, cols), jnp.float32)
-            score = jnp.matmul(xb, true_w, precision=jax.lax.Precision.HIGHEST)
-            if labels == "sign":
-                yb = (score > 0).astype(jnp.float32)
-            else:
-                yb = score + jax.random.normal(kn, (block_rows,), jnp.float32)
+            xb, yb = model.block(jax.random.fold_in(mine, i), drawn_once, block_rows, cols, data)
             at = i * block_rows
             X = jax.lax.dynamic_update_slice(X, xb, (at, 0))
             y = jax.lax.dynamic_update_slice(y, yb, (at,))
@@ -81,19 +79,20 @@ def _generator(mesh, rows: int, cols: int, labels: str, block_rows: int):
     return jax.jit(sharded)
 
 
-def make_rows(mesh, rows: int, cols: int, seed: int, labels: str,
+def make_rows(mesh, rows: int, cols: int, seed: int, data: dict,
               block_rows: int | None = None):
     """(X (rows, cols) f32, y (rows,) f32, ones (rows,) f32), rows sharded
-    over the mesh's one axis, every value a function of `seed` alone."""
-    _check_labels(labels)
+    over the mesh's one axis, every value a function of `seed` and of the
+    configuration's `data` block alone."""
+    model = _model(data)
     n_dev = mesh.devices.size
     if rows % n_dev:
         raise ValueError(f"{rows} rows do not divide over {n_dev} devices")
     b = block_rows_for(rows // n_dev, block_rows or BLOCK_ROWS)
-    return _generator(mesh, rows, cols, labels, b)(_key(seed))
+    return _generator(mesh, rows, cols, model, data, b)(_key(seed))
 
 
-def host_rows(rows: int, cols: int, seed: int, labels: str, workers: int = 8):
+def host_rows(rows: int, cols: int, seed: int, data: dict, workers: int = 8):
     """The same data model drawn on the host with numpy, for traffic that
     fits from host memory: (X (rows, cols) f32 C-order, y (rows,) f64), each
     row block from its own child of `seed` on a few threads.  (A device
@@ -101,22 +100,17 @@ def host_rows(rows: int, cols: int, seed: int, labels: str, workers: int = 8):
     would spend 37 s of every fit making it row-major: my chip run, PR 25.)"""
     from concurrent.futures import ThreadPoolExecutor
 
-    _check_labels(labels)
+    model = _model(data)
     seed, block_rows = int(seed), BLOCK_ROWS
-    true_w = np.random.default_rng([seed, 0]).standard_normal(cols).astype(np.float32)
+    drawn_once = model.host_shared(np.random.default_rng([seed, 0]), cols, data)
     X = np.empty((rows, cols), np.float32)
     y = np.empty((rows,), np.float64)
 
     def fill(i: int) -> None:
         at = i * block_rows
-        rng = np.random.default_rng([seed, 1 + i])
         xb = X[at:at + block_rows]
-        rng.standard_normal(dtype=np.float32, out=xb)
-        score = xb @ true_w
-        if labels == "sign":
-            y[at:at + len(xb)] = score > 0
-        else:
-            y[at:at + len(xb)] = score + rng.standard_normal(len(xb), dtype=np.float32)
+        y[at:at + len(xb)] = model.host_block(
+            np.random.default_rng([seed, 1 + i]), drawn_once, xb, data)
 
     with ThreadPoolExecutor(max(1, min(workers, os.cpu_count() or 1))) as pool:
         list(pool.map(fill, range(-(-rows // block_rows))))

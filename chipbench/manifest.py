@@ -1,8 +1,8 @@
 #
 # chipbench/manifest.py: BENCHMARK.json and the files it names.  Whatever
-# belongs to one configuration, one traffic mix, one estimator family or one
-# per-layer metric is a file of its own, found here by its name, so a later
-# PR adds files and entries and edits none.
+# belongs to one configuration, one traffic mix, one estimator family, one
+# data model or one per-layer metric is a file of its own, found here by its
+# name, so a later PR adds files and entries and edits none.
 #
 from __future__ import annotations
 
@@ -39,6 +39,36 @@ def _load_module(kind: str, name: str):
 def adapter(name: str):
     """chipbench/estimators/<name>.py"""
     return _load_module("estimators", name)
+
+
+def data_model(name: str):
+    """chipbench/data_models/<name>.py: what a cell's rows are drawn from."""
+    return _load_module("data_models", name)
+
+
+def data_of(cfg: dict, adapter) -> dict:
+    """The configuration file's `data` block: its data model's name and
+    parameters.  A file without one draws the first cells' rows, a hidden
+    direction labelled as its estimator family reads labels."""
+    return cfg.get("data") or {"model": "hidden_direction", "labels": adapter.LABELS}
+
+
+def data_problems(data: dict) -> list:
+    """Why no rows can be drawn from `data`: no file for its model, a key
+    the model needs is missing, a value it cannot draw.  [] when sound."""
+    name = data.get("model")
+    try:
+        model = data_model(str(name))
+    except FileNotFoundError as e:
+        return [f"data model {name!r} has no file ({e})"]
+    missing = [k for k in model.NEEDS if k not in data]
+    if missing:
+        return [f"data model {name}: the data block lacks {missing}"]
+    try:
+        model.check(data)
+    except ValueError as e:
+        return [f"data model {name}: {e}"]
+    return []
 
 
 def reader(metric: str):
@@ -175,9 +205,12 @@ def problems(manifest: dict) -> list:
             continue
         held = _load_json(full)
         try:
-            adapter(held["adapter"])
+            family = adapter(held["adapter"])
         except (FileNotFoundError, KeyError) as e:
             out.append(f"configuration {name}: no estimator file ({e})")
+        else:
+            out += [f"configuration {name}: {p}"
+                    for p in data_problems(data_of(held, family))]
         if sorted(held.get("reduced", [])) != sorted(c.get("reduced", [])):
             out.append(f"configuration {name}: `reduced` differs from its file's")
         for key in ("why", "source"):

@@ -54,10 +54,13 @@ def require_chips(devices, chips: int):
     return list(devices[:chips])
 
 
-def _flatten(spans, out):
+def _flatten(spans, out, parents, parent=-1):
+    """The report's span tree as a list of (name, start, end), with each
+    span's parent (its index in the list, -1 for none) in `parents`."""
     for s in spans:
         out.append((s["name"], float(s["t0"]), float(s["t0"]) + float(s["seconds"])))
-        _flatten(s.get("children", []), out)
+        parents.append(parent)
+        _flatten(s.get("children", []), out, parents, len(out) - 1)
     return out
 
 
@@ -86,28 +89,30 @@ def one_fit(adapter, est, fit_input, on_tpu: bool) -> dict:
     ans = adapter.answer(model)
     wall = time.perf_counter() - t0
     report = model.fit_report()
-    spans = _flatten(report["spans"], [])
+    parents: list = []
+    spans = _flatten(report["spans"], [], parents)
     return {
         "wall_s": wall, "answer": ans, "fault": fit_fault(report, on_tpu),
         "stage_s": sum(t1 - t0 for n, t0, t1 in spans if n == "stage"),
-        "spans": spans,
+        "spans": spans, "span_parents": parents,
         "routes": [n for n, _, _ in spans if n.startswith("lbfgs_route[")],
     }
 
 
-def make_input(traffic: dict, mesh, cfg: dict, seed: int, labels: str):
+def make_input(traffic: dict, mesh, cfg: dict, seed: int, data: dict):
     """(what the window's fits are given, as the traffic file says; a call
-    that gives the reference those same rows on the devices)."""
+    that gives the reference those same rows on the devices).  `data` is the
+    configuration's data block (`manifest.data_of`)."""
     from chipbench import datagen
     from spark_rapids_ml_tpu.data import DeviceDataset
 
     rows, cols = int(cfg["rows"]), int(cfg["cols"])
     kind = traffic["input"]
     if kind == "device_dataset":
-        X, y, w = datagen.make_rows(mesh, rows, cols, seed, labels)
+        X, y, w = datagen.make_rows(mesh, rows, cols, seed, data)
         return DeviceDataset(mesh, X, rows, y=y, weight=w), lambda: (X, y)
     if kind == "host_arrays":
-        Xh, yh = datagen.host_rows(rows, cols, seed, labels)
+        Xh, yh = datagen.host_rows(rows, cols, seed, data)
         return (Xh, yh), lambda: datagen.put_rows(mesh, Xh, yh)
     raise ValueError(f"traffic input {kind!r}: 'device_dataset' or 'host_arrays'")
 
@@ -152,7 +157,7 @@ def run_cell(manifest: dict, workload: str, seed: int, seconds: float,
 
     # -- set-up --------------------------------------------------------------
     mesh = get_mesh(chips)
-    fit_input, reference_rows = make_input(traffic, mesh, cfg, seed, adapter.LABELS)
+    fit_input, reference_rows = make_input(traffic, mesh, cfg, seed, mf.data_of(cfg, adapter))
     est = adapter.build(params, chips)
     warm = one_fit(adapter, est, fit_input, on_tpu)  # compiles or loads every program
     if warm["fault"]:
@@ -205,10 +210,13 @@ def run_cell(manifest: dict, workload: str, seed: int, seconds: float,
         start = t0_epoch + shift
         summary = trace_reduce.reduce(events, (start, start + window_s))
         if summary is not None:
-            host = [(n, a + shift, b + shift) for f in fits for n, a, b in f["spans"]]
+            host, parents = [], []
+            for f in fits:  # one list over the window, the parents' indices moved along
+                parents += [p + len(host) if p >= 0 else -1 for p in f["span_parents"]]
+                host += [(n, a + shift, b + shift) for n, a, b in f["spans"]]
             breakdown = {
                 "device_ops": trace_reduce.top_ops(summary),
-                "idle_gaps": trace_reduce.attribute_gaps(summary["gaps"], host),
+                "idle_gaps": trace_reduce.attribute_gaps(summary["gaps"], host, parents),
             }
     kind = devices[0].device_kind
     ctx = {

@@ -197,25 +197,54 @@ def top_ops(summary: dict, k: int = 10) -> list:
 
 
 def attribute_gaps(gaps: List[Interval], host_spans: List[Tuple[str, float, float]],
-                   k: int = 10) -> list:
-    """Idle seconds by what the host was doing: each gap goes to the
-    shortest host span (name, start, end; trace seconds) that holds its
-    middle, or to 'between_fits'.  One pass over the spans with the gaps as
-    arrays: a fused solve leaves some 10^5 gaps between its leaf operations."""
+                   parents: List[int], k: int = 10) -> list:
+    """Idle seconds by what the host was doing.  Every instant of a gap goes
+    to the innermost host spans that cover it: a span (name, start, end;
+    trace seconds) owns its time less its children's (`parents[i]` is the
+    index of span i's parent in the fit report's tree, -1 for none), and
+    spans of two threads that own one instant (the staging prefetch thread
+    beside its caller) share it equally.  So a gap longer than the spans
+    around it is split over them in proportion to the overlap, where giving
+    it whole to the span that held its middle named the wrong one; what no
+    span covers is 'between_fits'.  The gaps are arrays throughout: a fused
+    solve leaves some 10^5 of them between its leaf operations."""
     import numpy as np
 
     if not gaps:
         return []
-    lo, hi = np.asarray(gaps, dtype=np.float64).T
-    mid = (lo + hi) / 2
-    owner = np.full(len(mid), -1)
-    length = np.full(len(mid), np.inf)
+    glo, ghi = np.asarray(gaps, dtype=np.float64).T
+    children: dict = {}
+    for i, p in enumerate(parents):
+        if p >= 0:
+            children.setdefault(p, []).append(host_spans[i][1:])
+    owner, lo, hi = [], [], []
     for i, (_, s0, s1) in enumerate(host_spans):
-        inside = (s0 <= mid) & (mid <= s1) & (s1 - s0 < length)
-        owner[inside], length[inside] = i, s1 - s0
+        for a, b in subtract(union([(s0, s1)]), union(children.get(i, []))):
+            # the gaps that this stretch of the span's own time meets
+            j0, j1 = np.searchsorted(ghi, a, "right"), np.searchsorted(glo, b, "left")
+            if j1 > j0:
+                owner.append(np.full(j1 - j0, i))
+                lo.append(np.maximum(glo[j0:j1], a))
+                hi.append(np.minimum(ghi[j0:j1], b))
     by_name: dict = {}
-    for i in np.unique(owner):
-        name = host_spans[i][0] if i >= 0 else "between_fits"
-        by_name[name] = by_name.get(name, 0.0) + float((hi - lo)[owner == i].sum())
+    if owner:
+        owner, lo, hi = (np.concatenate(x) for x in (owner, lo, hi))
+        # between two neighbouring edges the owners do not change: their
+        # number there, and the seconds up to each edge with every stretch
+        # divided by it
+        edges = np.unique(np.concatenate([lo, hi]))
+        at_lo, at_hi = np.searchsorted(edges, lo), np.searchsorted(edges, hi)
+        owners = np.cumsum(np.bincount(at_lo, minlength=len(edges))
+                           - np.bincount(at_hi, minlength=len(edges)))[:-1]
+        upto = np.concatenate([[0.0], np.cumsum(np.diff(edges) / np.maximum(owners, 1))])
+        per_span = np.bincount(owner, weights=upto[at_hi] - upto[at_lo],
+                               minlength=len(host_spans))
+        for i in np.flatnonzero(per_span):
+            name = host_spans[i][0]
+            by_name[name] = by_name.get(name, 0.0) + float(per_span[i])
+    idle = float((ghi - glo).sum())
+    uncovered = idle - sum(by_name.values())
+    if uncovered > 1e-9 * idle:
+        by_name["between_fits"] = uncovered
     ranked = sorted(by_name.items(), key=lambda kv: -kv[1])
     return [[name, seconds] for name, seconds in ranked[:k]]
