@@ -237,14 +237,16 @@ _DEFAULTS: Dict[str, Any] = {
     # measured CAGRA recall 0.996 -> 0.58), "high" = 3-pass bf16,
     # "default" = fastest.  Read at trace time.
     "distance_precision": "highest",
-    # Per-dispatched-program FLOP budget for solvers that can split their
-    # work across host-dispatched programs (KMeans Lloyd, L-BFGS).
-    # Solvers whose total fitted work exceeds this switch from the fused
-    # single-program fit to stepwise host dispatch.  2e12 FLOPs (~40 s at
-    # v5e f32 matmul throughput) was sized for a development link that
-    # failed transfers behind long programs; the link is gone and the
-    # value is inherited, to be re-justified on the chip or deleted
-    # (ROADMAP Design 3).
+    # Per-dispatched-program FLOP budget of the L-BFGS solvers (dense and
+    # sparse logistic regression): a solve whose total fitted work
+    # exceeds this switches from the fused single-program fit to one
+    # host-dispatched program per evaluation.  KMeans Lloyd no longer
+    # reads it: its route and its block size read device memory
+    # (ops/kmeans.py kmeans_fit_auto).  2e12 FLOPs (~40 s at v5e f32
+    # matmul throughput) was sized for a development link that failed
+    # transfers behind long programs; the link is gone and the value is
+    # inherited, to be re-justified on the chip or deleted (ROADMAP
+    # Design 3).
     "dispatch_flops_limit": 2e12,
     # MXU precision for sufficient-statistics matmuls feeding a matrix
     # inversion/eigendecomposition (PCA covariance, LinReg Gram) —

@@ -500,6 +500,11 @@ class _TpuCaller(_TpuParams, _ReadWriteMixin):
             st = RowStager(X_host.shape[0], mesh)
             Xs = st.stage(X_host, dtype)
         n_padded = Xs.shape[0]
+        if st._interleave:
+            # dataset row r lies at staged position (r % n_dev) * shard +
+            # r // n_dev: kernels that rank rows in dataset order (the
+            # KMeans `random` init) undo it
+            extra["interleaved_over"] = n_dev
         w = st.mask(dtype, weights=batch.weight)
         y = None
         if batch.y is not None:

@@ -73,22 +73,6 @@ _label_range_jit = None
 _label_check_jit = None
 
 
-def _fused_solve_fits(X) -> bool:
-    """Whether one device can hold its shard of `X` TWICE, which the
-    single-program solver needs: XLA copies the loop-invariant operands
-    of a `while_loop` out of the read-only entry parameters into the
-    loop's own state, so the fused L-BFGS program carries a second
-    resident copy of the features as a temp.  Measured on a v5e at the
-    reference's 1M x 3000: 11.78 GB of HLO temp beside 11.51 GB of
-    arguments — 23.3 GB asked of a 15.75 GB chip, a compile-time
-    RESOURCE_EXHAUSTED.  The host-dispatched value+gradient program has
-    no loop and no temp."""
-    from ..parallel.device_cache import device_hbm_bytes
-
-    shard = X.addressable_shards[0]
-    return 2 * shard.data.nbytes <= device_hbm_bytes(shard.device)
-
-
 class LogisticRegressionClass:
     """Param mapping (reference LogisticRegressionClass
     classification.py:679-747, incl. the regParam -> C inversion
@@ -584,14 +568,17 @@ class LogisticRegression(
             # exceed the per-program budget (`dispatch_flops_limit`; the
             # reference 1M x 3000 maxIter=200 config crosses it) or the
             # program's copy of the features would not fit the device
-            # (`_fused_solve_fits`) — then host-driven L-BFGS, one
+            # (`device_cache.fused_program_fits`, the memory test KMeans'
+            # router reads too) — then host-driven L-BFGS, one
             # evaluation per program.  The FLOP budget is inherited from
             # a development link that is gone (ROADMAP Design 3)
             C_eff = 1 if binomial else n_classes
             per_eval = 4.0 * X.shape[0] * X.shape[1] * C_eff
             fused_flops = per_eval * max_iter * 2.0  # ~2 evals/iter
             budget = float(get_config("dispatch_flops_limit"))
-            fits = _fused_solve_fits(X)
+            from ..parallel.device_cache import fused_program_fits
+
+            fits = fused_program_fits(X)
             host_dispatch = fused_flops > budget or bool(ckpt_path) or not fits
             why = (
                 f"{fused_flops:.2e} fused FLOPs vs budget {budget:.0e}, "

@@ -153,8 +153,35 @@ class KMeans(KMeansClass, _TpuEstimator, _KMeansTpuParams):
     clustering.py:185-498).
 
     Seeding runs on-device (Gumbel-max k-means++, the quality analog of
-    cuML's scalable-k-means++); Lloyd iterations are one compiled
-    while_loop whose centroid partial sums psum over the mesh.
+    cuML's scalable-k-means++).  Lloyd iterations are one compiled
+    while_loop whose centroid partial sums psum over the mesh while a
+    device holds its rows twice beside the (rows, k) temporaries; past
+    that, one host-dispatched program per row block, the block sized by
+    the memory left beside the rows (`ops/kmeans.kmeans_fit_auto`; the
+    fit report's `kmeans_route[...]` says which ran).  Both products of a
+    step, the cost and `transform` compute in true float32 (the
+    `distance_precision` conf).
+
+    Stopping rule (Spark's): an iteration after which every center has
+    moved less than `tol` is the last; `maxIter` bounds them.  A cluster
+    that no row is assigned to keeps its center.  The model carries the
+    centers, `summary.trainingCost` (the weighted cost under the FINAL
+    centers) and `summary.numIter`.
+
+    `initMode="random"`, the rule: the k initial centers are k distinct
+    rows of positive weight, drawn uniformly (weights do not bias the
+    draw), a function of `seed`, `k` and the count m of such rows alone.
+    Rank the rows of positive weight 0..m-1 in dataset order; draw
+    `g = jax.random.gumbel(jax.random.PRNGKey(seed), (m,), jnp.float32)`
+    (jax's default threefry, `jax_threefry_partitionable` on, jax's
+    default since 0.5); center i is the row whose rank holds the i-th
+    largest `g`, ties to the lower rank:
+    `numpy.argsort(-g, kind="stable")[:k]`.  Zero-weight rows (padding,
+    wherever it lies) and the number of devices do not enter.  "Dataset
+    order" is the order of a `DeviceDataset`'s rows, and of host rows
+    staged contiguously; host rows staged on several devices WITH bucket
+    padding are dealt round-robin (`parallel/mesh.RowStager`), and the
+    rule then ranks them in that staged order.
 
     Examples
     --------
@@ -219,10 +246,10 @@ class KMeans(KMeansClass, _TpuEstimator, _KMeansTpuParams):
         seed = p.get("random_state")
         seed = int(seed) if seed is not None else int(self.getOrDefault("seed"))
         max_iter = int(p["max_iter"])
-        # fused single-program Lloyd until the whole solve (init
-        # included) could exceed the per-program budget
-        # (`dispatch_flops_limit`); then host-dispatched per-block
-        # iterations.  The gate itself lives in ops/kmeans.py
+        # fused single-program Lloyd while a device holds its rows twice
+        # beside the program's (rows, k) temporaries; then
+        # host-dispatched iterations in row blocks sized by the memory
+        # left.  The gate itself lives in ops/kmeans.py
         # kmeans_fit_auto, shared with the IVF quantizer training.
         # `checkpoint_dir` set -> the stepwise (checkpointable) solver
         # runs regardless of size and the fit resumes after a crash.
@@ -257,11 +284,20 @@ class KMeans(KMeansClass, _TpuEstimator, _KMeansTpuParams):
             init=str(p["init"]),
             init_steps=int(p.get("init_steps") or 2),
             oversample=float(p.get("oversampling_factor") or 2.0),
+            interleaved_over=int(fit_input.extra.get("interleaved_over", 1)),
             checkpoint_path=ckpt_path,
             checkpoint_tag=ckpt_tag,
         )
         if stepwise:
             self.logger.info("KMeans: stepwise host-dispatched Lloyd")
+        import jax
+
+        from ..tracing import trace
+
+        with trace("kmeans_fetch"):
+            # ONE batched device->host fetch (on the fused route also the
+            # wait for the one program)
+            centers, cost, n_iter = jax.device_get((centers, cost, n_iter))
         return {
             "cluster_centers_": np.asarray(centers),
             "inertia_": float(cost),

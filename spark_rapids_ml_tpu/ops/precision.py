@@ -1,6 +1,7 @@
 #
 # Matmul precision for distance kernels whose OUTPUT IS A RANKING or a
-# threshold decision (kNN / ANN neighbor ids, DBSCAN eps tests).
+# threshold decision (kNN / ANN neighbor ids, DBSCAN eps tests, the
+# nearest-center assignment of a KMeans Lloyd step).
 #
 # TPU MXU "default" precision feeds f32 operands through bf16 passes:
 # relative product error ~2^-8, i.e. up to ~0.8% of |x||y|.  Squared
@@ -14,8 +15,12 @@
 # `distance_precision()` is read at TRACE time — set the config before
 # the first fit/search.  "highest" = true f32 (6-pass); "high" = 3-pass
 # bf16 (~2^-14 relative, usually rank-safe at small dims); "default" =
-# fastest, rank-unsafe.  Iterative solvers that merely CONVERGE through
-# distances (KMeans Lloyd) keep XLA's default and are not routed here.
+# fastest, rank-unsafe.  KMeans Lloyd is routed here too
+# (`lloyd_precision`): it was thought to merely converge through its
+# distances, but an assignment IS a ranking, and on clusterable rows one
+# bf16 pass ends 14-32x further from the float64 trajectory than f32
+# does (PERF.md §4).  The D2-sampling inits, which only draw from their
+# distances, keep XLA's default.
 #
 from __future__ import annotations
 
@@ -39,6 +44,15 @@ def distance_precision() -> jax.lax.Precision:
             f"distance_precision must be one of {sorted(_LEVELS)}, got {name!r}"
         )
     return _LEVELS[name]
+
+
+def lloyd_precision() -> jax.lax.Precision:
+    """Precision of a KMeans Lloyd step's two products (x.c of the
+    assignment, the one-hot cluster sums of the update) and of the cost
+    and predict passes: the `distance_precision` key, so true f32 by
+    default, as cuML computes them.  Also what `ops/ivf.py` trains its
+    quantizer with."""
+    return distance_precision()
 
 
 # "high_compensated" runs the chunk matmuls at HIGH (3-pass bf16) and

@@ -107,6 +107,29 @@ def device_hbm_bytes(device) -> int:
     return int(get_config("hbm_bytes"))
 
 
+def bytes_beside(X) -> int:
+    """Bytes one device has left beside its shard of the resident rows
+    `X` (`device_hbm_bytes` less the shard): what a solver's temporaries
+    may be sized against."""
+    shard = X.addressable_shards[0]
+    return max(0, device_hbm_bytes(shard.device) - shard.data.nbytes)
+
+
+def fused_program_fits(X, temp_bytes: int = 0) -> bool:
+    """Whether one device can hold its shard of `X` TWICE beside
+    `temp_bytes` of the program's own temporaries, which a solver fused
+    into one `while_loop` program needs: XLA copies the loop-invariant
+    operands of a `while_loop` out of the read-only entry parameters into
+    the loop's own state, so the program carries a second resident copy
+    of the features as a temp.  Measured on a v5e at the reference's
+    1M x 3000 (the fused L-BFGS): 11.78 GB of HLO temp beside 11.51 GB
+    of arguments — 23.3 GB asked of a 15.75 GB chip, a compile-time
+    RESOURCE_EXHAUSTED.  A host-dispatched program has no loop and no
+    copy.  The ONE memory test every fused-vs-host-dispatched router
+    reads (logistic L-BFGS, KMeans Lloyd)."""
+    return X.addressable_shards[0].data.nbytes + temp_bytes <= bytes_beside(X)
+
+
 def device_data_budget_bytes() -> float:
     """The device-memory budget staged training data is accounted
     against: mem_ratio_for_data x the sum of `device_hbm_bytes` over the

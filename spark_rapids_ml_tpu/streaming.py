@@ -1611,9 +1611,9 @@ def kmeans_streaming_fit(
     import jax.numpy as jnp
 
     from .ops.kmeans import (
-        _pairwise_sqdist,
         kmeans_init,
         kmeans_parallel_init,
+        lloyd_partials,
         seed_sample_stride,
     )
 
@@ -1702,11 +1702,8 @@ def kmeans_streaming_fit(
     @partial_jit_donate
     def assign_step(acc, counts, C, X, w):
         sums, cost = acc
-        d2 = _pairwise_sqdist(X, C)
-        labels = jnp.argmin(d2, axis=1)
-        md2 = jnp.min(d2, axis=1)
-        oh = jax.nn.one_hot(labels, k, dtype=X.dtype) * w[:, None]
-        return (sums + oh.T @ X, cost + (md2 * w).sum()), counts + oh.sum(axis=0)
+        part = lloyd_partials(C, X, w, k)
+        return (sums + part[0], cost + part[2]), counts + part[1]
 
     duhl = chunk_sampling_mode() == "duhl"
     sampler = None
@@ -1723,14 +1720,7 @@ def kmeans_streaming_fit(
         # per-chunk assign stats (NOT accumulated): the sampled Lloyd
         # passes need each chunk's own (sums, counts, cost) so
         # unvisited chunks can contribute their last-computed stats
-        def _chunk_stats_fn(C, X, w):
-            d2 = _pairwise_sqdist(X, C)
-            labels = jnp.argmin(d2, axis=1)
-            md2 = jnp.min(d2, axis=1)
-            oh = jax.nn.one_hot(labels, k, dtype=X.dtype) * w[:, None]
-            return oh.T @ X, oh.sum(axis=0), (md2 * w).sum()
-
-        chunk_stats = jax.jit(_chunk_stats_fn)
+        chunk_stats = jax.jit(lambda C, X, w: lloyd_partials(C, X, w, k))
     stream_key = chunk_stream_key(
         path, features_col, features_cols, None, weight_col,
         chunk_rows, dtype, (lo, hi),

@@ -148,8 +148,8 @@ def test_single_sample_predict(rng):
 
 
 def test_stepwise_lloyd_matches_fused(rng):
-    # kmeans_fit_stepwise (host-dispatched blocks, the
-    # `dispatch_flops_limit` path for huge n*d*k) must reproduce the fused while_loop fit.  The
+    # kmeans_fit_stepwise (host-dispatched blocks, the route of rows a
+    # device cannot hold twice) must reproduce the fused while_loop fit.  The
     # contract is "same update math, trajectories match up to f32
     # reduction order" (the stepwise docstring) — asserted in two parts.
     # The old form of this test compared full 50-iteration trajectories
@@ -201,14 +201,14 @@ def test_stepwise_lloyd_matches_fused(rng):
 
     # (2) end to end on clusterable data: both fits CONVERGE (the old
     # noise dataset never did) and land on the same centers and cost;
-    # the tiny budget forces multiple Lloyd blocks per pass while the
-    # "random" init (no D2 passes) keeps the seeding identical
+    # the small block forces multiple Lloyd blocks per pass (uneven
+    # tail) while the "random" init (no D2 passes) keeps the seeding identical
     c_f, cost_f, it_f = kmeans_fit(
         X, w, k=5, seed=0, max_iter=50, tol=1e-4, init="random"
     )
     c_s, cost_s, it_s = kmeans_fit_stepwise(
         X, w, k=5, seed=0, max_iter=50, tol=1e-4, init="random",
-        flops_budget=2e5,
+        block_rows=1250,
     )
     assert int(it_f) < 50 and int(it_s) < 50, (it_f, it_s)
     np.testing.assert_allclose(
@@ -218,19 +218,32 @@ def test_stepwise_lloyd_matches_fused(rng):
     np.testing.assert_allclose(float(cost_s), float(cost_f), rtol=1e-4)
 
 
+def _routes(model):
+    def walk(nodes):
+        for n in nodes:
+            yield n
+            yield from walk(n.get("children", []))
+
+    return [n["name"] for n in walk(model.fit_report()["spans"])
+            if n["name"].startswith("kmeans_route[")]
+
+
 def test_stepwise_dispatch_through_estimator(rng):
-    # force the estimator's stepwise path via a tiny dispatch budget and
-    # check it agrees with the fused path end to end
+    # force the estimator's stepwise path via a device budget that cannot
+    # hold the rows twice and check it agrees with the fused path end to end
     from spark_rapids_ml_tpu.config import reset_config, set_config
     from spark_rapids_ml_tpu.models.clustering import KMeans
 
     X = rng.normal(size=(2000, 6)).astype(np.float32)
     m_fused = KMeans(k=4, seed=1, maxIter=40, initMode="random").fit(X)
-    set_config(dispatch_flops_limit=1e5)
+    assert _routes(m_fused) == ["kmeans_route[fused]"]
+    # a device holds 256 rows: 6 KB, twice, beside 17 KB of temporaries
+    set_config(hbm_bytes=20_000)
     try:
         m_step = KMeans(k=4, seed=1, maxIter=40, initMode="random").fit(X)
     finally:
         reset_config()
+    assert _routes(m_step) == ["kmeans_route[stepwise]"]
     np.testing.assert_allclose(
         np.sort(m_step.cluster_centers_, axis=0),
         np.sort(m_fused.cluster_centers_, axis=0),

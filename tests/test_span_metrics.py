@@ -66,11 +66,19 @@ def read(name, ctx):
     return mf.reader(name)(ctx)
 
 
+# PR 29's, appended after them: the readers of the KMeans Lloyd spans
+KMEANS_READERS = ["lloyd_step_roofline", "lloyd_iter_gap_ms", "lloyd_iters_per_fit",
+                  "kmeans_init_s"]
+
+
 def test_the_manifest_lists_the_readers_last_and_finds_them():
-    assert [m["name"] for m in MANIFEST["per_layer"][-len(READERS):]] == READERS
+    # each PR appends: PR 26's eight readers in their order, then PR 29's four
+    names = [m["name"] for m in MANIFEST["per_layer"]]
+    assert names[-len(READERS + KMEANS_READERS):] == READERS + KMEANS_READERS
     assert mf.problems(MANIFEST) == []
-    for m in MANIFEST["per_layer"][-len(READERS):]:
-        assert m["moves"] == "fit_s" and m["better"] == "lower" and m["workloads"]
+    for m in MANIFEST["per_layer"][-len(READERS + KMEANS_READERS):]:
+        assert m["moves"] == "fit_s" and m["workloads"]
+        assert m["better"] == ("higher" if m["name"].endswith("_roofline") else "lower")
 
 
 @pytest.mark.parametrize("name, by_hand", [
@@ -102,6 +110,65 @@ def test_a_program_without_the_spans_reads_nothing(name):
     old = [("fit[LogisticRegression]", 0.0, 5.0), ("stage", 0.0, 4.0),
            ("fit_kernel", 4.0, 5.0), ("lbfgs_route[host_dispatch]", 4.0, 4.0)]
     assert read(name, ctx_of([old, old], MODULES, compiles=2.0)) is None
+
+
+# two fits of the host-dispatched Lloyd: an init, three iterations, the cost
+# pass and the fetch each; the second fit's iterations run a little longer
+KMEANS_1 = [
+    ("fit_kernel", 0.0, 5.0), ("kmeans_route[stepwise]", 0.0, 0.0),
+    ("kmeans_init", 0.0, 0.2),
+    ("kmeans_lloyd_iter", 0.2, 1.2), ("kmeans_lloyd_iter", 1.2, 2.2),
+    ("kmeans_lloyd_iter", 2.2, 3.2),
+    ("kmeans_cost", 3.2, 3.8), ("kmeans_fetch", 3.8, 3.9),
+]
+KMEANS_2 = [
+    ("fit_kernel", 10.0, 15.5),
+    ("kmeans_init", 10.0, 10.4),
+    ("kmeans_lloyd_iter", 10.4, 11.6), ("kmeans_lloyd_iter", 11.6, 12.8),
+    ("kmeans_lloyd_iter", 12.8, 14.0),
+    ("kmeans_cost", 14.0, 14.6), ("kmeans_fetch", 14.6, 14.7),
+]
+KMEANS_PROGRAMS = mf.adapter("kmeans").PROGRAMS
+# 6 iterations of 2 blocks and an update, 2 cost passes of 2 blocks; the init's
+# programs are no part of a step
+KMEANS_MODULES = {"jit__lloyd_block_step": (5.4, 12), "jit__lloyd_center_update": (0.06, 6),
+                  "jit__lloyd_block_cost": (1.0, 4), "jit__slice_rows": (0.2, 2),
+                  "jit_random_init_rows": (0.1, 2)}
+
+
+def kmeans_ctx(fits, modules=KMEANS_MODULES, programs=KMEANS_PROGRAMS):
+    from chipbench import roofline
+
+    ctx = ctx_of(fits, modules, programs=programs)
+    ctx.update(
+        work=mf.adapter("kmeans").work(1_000_000, 3_000, 1, {"k": 1000, "maxIter": 3}),
+        reference={"n_iter": 3}, traced_fits=len(fits) if modules is not None else 0,
+        peaks=roofline.peaks_for("TPU v5 lite"))
+    return ctx
+
+
+@pytest.mark.parametrize("name, by_hand", [
+    ("kmeans_init_s", (0.2 + 0.4) / 2),
+    ("lloyd_iters_per_fit", 3.0),
+    # six spans of 6.6 s in all, less 5.46 s of the device in them, over six
+    ("lloyd_iter_gap_ms", 1e3 * (6.6 - 5.46) / 6),
+    # three steps of 6.003e12 FLOP and an assignment of 6e12 at 197e12 a second,
+    # over (5.4 + 0.06 + 1.0) / 2 device seconds a fit
+    ("lloyd_step_roofline", 100 * ((3 * 6.003e12 + 6e12) / 197e12) / 3.23),
+])
+def test_kmeans_readers_by_hand(name, by_hand):
+    assert read(name, kmeans_ctx([KMEANS_1, KMEANS_2])) == pytest.approx(by_hand, rel=1e-12)
+
+
+@pytest.mark.parametrize("name", KMEANS_READERS)
+def test_kmeans_readers_read_nothing_where_there_is_nothing(name):
+    """Another family's fits, a program without the spans or the programs (the
+    parent), an untraced run: None, never a raise."""
+    assert read(name, kmeans_ctx([FIT_1, FIT_2], MODULES, programs={"lbfgs_eval": ("vg_fn",)})) is None
+    bare = [("fit_kernel", 0.0, 5.0)]
+    assert read(name, kmeans_ctx([bare, bare], {"jit_kmeans_init": (1.0, 2)})) is None
+    if name in ("lloyd_step_roofline", "lloyd_iter_gap_ms"):
+        assert read(name, kmeans_ctx([KMEANS_1, KMEANS_2], None)) is None  # untraced
 
 
 def test_compile_s_is_zero_where_nothing_compiled_and_none_without_fits():

@@ -21,6 +21,7 @@ import spark_rapids_ml_tpu.ops.lbfgs as lbfgs_mod
 import spark_rapids_ml_tpu.ops.logistic as logistic_mod
 from spark_rapids_ml_tpu import tracing
 from spark_rapids_ml_tpu.classification import LogisticRegression
+from spark_rapids_ml_tpu.clustering import KMeans
 from spark_rapids_ml_tpu.config import reset_config, set_config
 from spark_rapids_ml_tpu.regression import LinearRegression
 from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
@@ -65,6 +66,14 @@ ROUTES = {
         {"linreg_gram", "linreg_fetch", "linreg_host_solve", "linreg_residual",
          "linreg_solver[cholesky]"},
     ),
+    # the host-dispatched Lloyd, the route of rows a device cannot hold twice
+    "kmeans_stepwise": (
+        lambda: KMeans(k=16, maxIter=4, tol=1e-20, initMode="random", seed=2,
+                       num_workers=1),
+        (65_536, 64), "fit_kernel",
+        {"kmeans_route[stepwise]", "kmeans_init", "kmeans_lloyd_iter",
+         "kmeans_cost", "kmeans_fetch"},
+    ),
     # 256 MB of rows, far over `_PIPELINED_MIN_BYTES`: the staging engine
     "pipelined_stage": (
         lambda: _logistic(num_workers=1), (1_048_576, 64), "stage",
@@ -107,6 +116,9 @@ def test_route_records_its_spans(route, monkeypatch):
         # the per-program budget sends a toy fit down the route the one-chip
         # cells take
         set_config(dispatch_flops_limit=1.0)
+    if route == "kmeans_stepwise":
+        # 16.8 MB of rows, and a device that cannot hold them twice
+        set_config(hbm_bytes=30_000_000)
     X, y = _rows(n, d)
 
     evaluations = []
@@ -158,6 +170,15 @@ def test_route_records_its_spans(route, monkeypatch):
                  if n["name"] == "compile[backend_compile]"]
         assert len(spans) == compiled
     assert best >= 0.9, f"{holder} covered to {best:.2f}"
+
+    # one iteration span an iteration, the rest of the vocabulary once a fit
+    if route == "kmeans_stepwise":
+        for report in reports:
+            names = [n["name"] for n, _ in _walk(report["spans"])]
+            assert names.count("kmeans_lloyd_iter") == 4
+            for once in ("kmeans_init", "kmeans_cost", "kmeans_fetch",
+                         "kmeans_route[stepwise]"):
+                assert names.count(once) == 1, once
 
     # the evaluation spans ARE the evaluations
     if route == "logistic_host_dispatch":
