@@ -249,14 +249,17 @@ def pad_cast(arr: np.ndarray, n_pad: int, dtype: np.dtype) -> np.ndarray:
 
 
 def gather_rows_strided(
-    arr: np.ndarray, start: int, step: int, count: int, dtype: np.dtype
+    arr: np.ndarray, start: int, step: int, count: int, dtype: np.dtype,
+    out: Optional[np.ndarray] = None,
 ) -> np.ndarray:
     """Contiguous, dtype-cast copy of rows `arr[start + i*step]` for
     i in [0, count) — the fused interleave-permutation slice of the
     pipelined staging engine (mesh.RowStager round-robin layout),
     parallelized when large.  `step=1` is the plain contiguous chunk
     slice (still fusing the cast), so the engine has ONE producer
-    primitive for both layouts."""
+    primitive for both layouts.  `out`, a C-contiguous (count, ...)
+    array of `dtype`, receives the rows where given (the engine's reused
+    piece buffers); a new array otherwise."""
     dtype = np.dtype(dtype)
     d = int(np.prod(arr.shape[1:], dtype=np.int64)) if arr.ndim > 1 else 1
     out_bytes = count * d * dtype.itemsize
@@ -282,13 +285,17 @@ def gather_rows_strided(
             dst_ct = (
                 ctypes.c_float if dtype == np.float32 else ctypes.c_double
             )
-            out = np.empty((count, d), dtype)
+            if out is None:
+                out = np.empty((count, d), dtype)
             getattr(lib, name)(
                 _ptr(arr, src_ct), start, step, count, d, _ptr(out, dst_ct)
             )
             return out
     stop = start + count * step
-    return np.ascontiguousarray(arr[start:stop:step], dtype=dtype)
+    if out is None:
+        return np.ascontiguousarray(arr[start:stop:step], dtype=dtype)
+    np.copyto(out, arr[start:stop:step], casting="unsafe")
+    return out
 
 
 def pack_rows(rows: np.ndarray, n_pad: int, dtype: np.dtype) -> np.ndarray:

@@ -296,7 +296,8 @@ from ..telemetry.registry import dict_view as _dict_view
 from ..tracing import record_span, trace
 
 # last staging-engine run: bytes, seconds, mb_per_s, host_prep_s,
-# device_put_s, overlap_ratio, pieces, depth, label (read by bench.py's
+# device_put_s, overlap_ratio, pieces, pieces_viewed (those put straight
+# from the caller's rows), depth, label (read by bench.py's
 # `staging` workload and the parity tests).  Since the telemetry PR this
 # is a VIEW over the process-global metrics registry
 # (telemetry/registry.py) — same mapping surface, but `dump_prometheus`
@@ -476,6 +477,9 @@ class ShardedRowWriter:
         self.bytes_written = 0
         self.put_seconds = 0.0  # dispatch-side time (transfers are async)
         self.pieces = 0
+        # pieces a producer handed over as views of the caller's rows (no
+        # host copy was made for them); the producer counts, on its thread
+        self.pieces_viewed = 0
         self.rows_skipped_remote = 0
         # the parallel parquet range readers (streaming.stage_parquet)
         # call write() from their own threads at disjoint row offsets.
@@ -513,10 +517,12 @@ class ShardedRowWriter:
                     self.rows_skipped_remote += int(take)
             pos += take
 
-    def write_shard(self, d: int, lo: int, rows: np.ndarray) -> None:
+    def write_shard(self, d: int, lo: int, rows: np.ndarray):
         """Write host `rows` at offset `lo` WITHIN device `d`'s shard.
         Thread-safe: concurrent range readers writing disjoint offsets
-        serialize only the (fast) update dispatch."""
+        serialize only the (fast) update dispatch.  Returns the piece's
+        `applied` token: once it is ready the transfer has read `rows`
+        for the last time."""
         import jax.numpy as jnp
 
         if d not in self._bufs:
@@ -554,8 +560,15 @@ class ShardedRowWriter:
             self.put_seconds += prep_s + put_s
             self.bytes_written += piece.nbytes
             self.pieces += 1
+        return applied
 
     def finish(self) -> "jax.Array":
+        # a piece may be a view of the caller's rows, and the transfer
+        # reads them until its update has run: no piece is in flight once
+        # this returns, so the caller may overwrite its array
+        for done in self._done.values():
+            while done:
+                done.popleft().block_until_ready()
         if self.sharding is None:
             out = self._bufs[0]
         else:
@@ -568,6 +581,59 @@ class ShardedRowWriter:
             )
         self._bufs = {}  # the writer must not pin the shard buffers
         return out
+
+
+class _PiecePool:
+    """Host buffers for the pieces a producer has to COPY (a cast, the
+    interleave, rows that are no contiguous block), reused in turn
+    instead of allocated anew for each piece.  On a v5e host a staging
+    through new 256 MB pieces left 3-7 GB of host memory in use after it,
+    outside the process and for good, and paid the first touch of every
+    page (4.0 s of gathering for 12 GB where reused buffers take 0.55 s;
+    PERF.md, PR 30): what the TPU runtime keeps for a host address it
+    has transferred from is found again only when the address comes
+    back.
+
+    One producer thread calls `gather`; the thread that puts calls
+    `note_put`.  A buffer is written again only once the token of the
+    piece last put from it is ready.  The producer runs at most `depth`
+    pieces ahead of the puts, so with `depth` + `_MAX_INFLIGHT_PIECES` +
+    2 buffers that token is always known by then; should it ever not
+    be, the piece gets an array of its own."""
+
+    _OUT = object()  # handed to the producer's consumer, not yet put
+
+    def __init__(self, depth: int, shape, dtype) -> None:
+        self.shape, self.dtype = tuple(shape), np.dtype(dtype)
+        self._bufs: list = [None] * (depth + _MAX_INFLIGHT_PIECES + 2)
+        self._state: list = [None] * len(self._bufs)
+        self._turn = 0
+
+    def gather(
+        self, arr: np.ndarray, start: int, step: int, count: int
+    ) -> np.ndarray:
+        """`native.gather_rows_strided` into the buffer whose turn it is."""
+        from ..native import gather_rows_strided
+
+        i = self._turn % len(self._bufs)
+        self._turn += 1
+        state = self._state[i]
+        if state is self._OUT:
+            return gather_rows_strided(arr, start, step, count, self.dtype)
+        if state is not None:
+            state.block_until_ready()
+        if self._bufs[i] is None:
+            self._bufs[i] = np.empty(self.shape, self.dtype)
+        self._state[i] = self._OUT
+        return gather_rows_strided(
+            arr, start, step, count, self.dtype, out=self._bufs[i][:count]
+        )
+
+    def note_put(self, piece: np.ndarray, applied) -> None:
+        for i, buf in enumerate(self._bufs):
+            if buf is not None and piece.base is buf:
+                self._state[i] = applied
+                return
 
 
 def timed_iter(producer: Iterable, prep: dict) -> Iterator:
@@ -600,13 +666,16 @@ def timed_iter(producer: Iterable, prep: dict) -> Iterator:
 
 
 def run_staging_pipeline(
-    writer: ShardedRowWriter, producer: Iterable, label: str = "stage"
+    writer: ShardedRowWriter, producer: Iterable, label: str = "stage",
+    on_put=None,
 ) -> "jax.Array":
     """Drive `producer` — an iterator of `(dev_or_None, lo, host_rows)`
     whose per-item PREP work (slice/cast/densify) happens inside its
     `__next__` — through `writer`, with the prep running `depth` items
     ahead on a background thread (`staging_pipeline_depth`; depth 1 =
-    serial, no thread).  All jax calls stay on the calling thread.
+    serial, no thread).  Every put is dispatched from the calling
+    thread; `on_put(host_rows, applied)` tells a producer that reuses
+    its buffers (`_PiecePool`) which token frees which.
     Records throughput + overlap in `STAGE_METRICS`; the run's trace
     holds one `stage_prep` and one `stage_put` span per piece and a
     `stage_finish` span for the assembly and the bookkeeping after the
@@ -627,8 +696,10 @@ def run_staging_pipeline(
         for dev, lo, rows in prefetch_iter(timed(), depth):
             if dev is None:
                 writer.write(int(lo), rows)
-            else:
-                writer.write_shard(int(dev), int(lo), rows)
+                continue
+            applied = writer.write_shard(int(dev), int(lo), rows)
+            if on_put is not None:
+                on_put(rows, applied)
         t_finish = time.time()
         out = writer.finish()
     wall = time.perf_counter() - t0
@@ -654,6 +725,7 @@ def run_staging_pipeline(
         device_put_s=round(writer.put_seconds, 4),
         overlap_ratio=round(overlap, 4),
         pieces=writer.pieces,
+        pieces_viewed=writer.pieces_viewed,
         depth=depth,
         n_dev=writer.n_dev,
     )
@@ -949,8 +1021,9 @@ class RowStager:
             # the byte model's prediction for this staging (padded rows x
             # row bytes) — the measured-peak watermark checks it
             # (telemetry/memory.py budget_drift_ratio)
-            from ..telemetry.memory import record_prediction
+            from ..telemetry.memory import note_host_staging, record_prediction
 
+            note_host_staging()
             record_prediction(
                 "staged",
                 float(self.local_padded)
@@ -1068,15 +1141,17 @@ class RowStager:
     def _stage_pipelined(
         self, arr: np.ndarray, dtype: np.dtype, sharding
     ) -> jax.Array:
-        """Pipelined per-device staging: each device shard's rows are
-        gathered straight from `arr` (the interleave permutation fused
-        into a strided slice — no full-array host copy), cast, and
-        written to exactly ONE device, with the next piece prepared on a
-        background thread while the current one transfers.  Padding rows
+        """Pipelined per-device staging: each device shard's rows go
+        straight from `arr` to exactly ONE device, with the next piece
+        prepared on a background thread while the current one transfers.
+        Where the rows of a piece already lie in `arr` as the device
+        needs them (C-contiguous, the target dtype, consecutive rows) the
+        piece is a VIEW of them and nothing is copied on the host;
+        otherwise they are gathered and cast into one of a few reused
+        piece buffers (`_PiecePool`; the interleave permutation fused
+        into a strided slice — no full-array host copy).  Padding rows
         are never transferred (the shard buffers start zero).
         Byte-identical to `_stage_serial` for every layout."""
-        from ..native import gather_rows_strided
-
         writer = ShardedRowWriter(
             (self.local_padded,) + arr.shape[1:], dtype, sharding
         )
@@ -1088,6 +1163,13 @@ class RowStager:
         chunk = _staging_chunk_rows(row_bytes)
         interleave = self._interleave
         n_local = self.n_local
+        # a device's rows are consecutive (step 1) unless they interleave
+        views = (
+            arr.flags.c_contiguous and arr.dtype == dtype and not interleave
+        )
+        pool = None if views else _PiecePool(
+            _staging_depth(), (min(chunk, s),) + arr.shape[1:], dtype
+        )
 
         def producer() -> Iterator:
             for d_i in range(n_dev):
@@ -1100,12 +1182,17 @@ class RowStager:
                     total = min(max(n_local - d_i * s, 0), s)
                 for lo in range(0, total, chunk):
                     cnt = min(chunk, total - lo)
-                    piece = gather_rows_strided(
-                        arr, start + lo * step, step, cnt, dtype
-                    )
+                    if views:
+                        piece = arr[start + lo : start + lo + cnt]
+                        writer.pieces_viewed += 1
+                    else:
+                        piece = pool.gather(arr, start + lo * step, step, cnt)
                     yield d_i, lo, piece
 
-        return run_staging_pipeline(writer, producer(), label="stage")
+        return run_staging_pipeline(
+            writer, producer(), label="stage",
+            on_put=None if views else pool.note_put,
+        )
 
     def _stage_pipelined_multi(
         self, arr: np.ndarray, dtype: np.dtype, sharding
@@ -1136,7 +1223,9 @@ class RowStager:
             # pieces are plain slices; the writer routes each to its shard
             for lo in range(0, n_local, chunk):
                 cnt = min(chunk, n_local - lo)
-                piece = np.ascontiguousarray(arr[lo : lo + cnt], dtype=dtype)
+                rows = arr[lo : lo + cnt]
+                piece = np.ascontiguousarray(rows, dtype=dtype)
+                writer.pieces_viewed += piece is rows
                 yield None, block_lo + lo, piece
 
         return run_staging_pipeline(writer, producer(), label="stage_mp")

@@ -359,17 +359,31 @@ def record_prediction(label: str, nbytes: float) -> None:
     if nbytes <= 0:
         return
     _pred_g.set(nbytes, est=label)
+    for wm in _watermarks_of_this_run():
+        wm._predict(label, nbytes)
+
+
+def _watermarks_of_this_run() -> list:
     from ..tracing import current_run_id
 
     rid = current_run_id()
     if not rid:
-        # no run on this thread -> no watermark owns the prediction; a
+        # no run on this thread -> no watermark owns the event; a
         # broadcast to every active fit would cross-contaminate reports
-        return
+        return []
     with _lock:
-        wms = [w for r, w in _active.items() if r == rid]
-    for wm in wms:
-        wm._predict(label, nbytes)
+        return [w for r, w in _active.items() if r == rid]
+
+
+def note_host_staging() -> None:
+    """A staging from host memory begins in this thread's run: its fit
+    reads the host's `MemAvailable` now, if it has not yet, and once
+    more when it ends.  Fits that stage nothing from the host read
+    nothing: one read of /proc/meminfo costs 0.3 ms on a one-chip v5e
+    host and 3 ms on a four-chip one (PERF.md, PR 30)."""
+    for wm in _watermarks_of_this_run():
+        if "start" not in wm.host_available:
+            wm.host_available["start"] = host_available_bytes()
 
 
 def record_budget_decision(label: str, need_bytes: float, over: bool) -> None:
@@ -408,6 +422,20 @@ def note_measured_drift(
 # ---------------------------------------------------------------------------
 
 
+def host_available_bytes() -> Optional[int]:
+    """The host's `MemAvailable` (/proc/meminfo), None where the kernel
+    gives none.  Memory a fit leaves in use OUTSIDE the process (pages a
+    driver keeps) shows here and not in the process's own counters."""
+    try:
+        with open("/proc/meminfo") as f:
+            for line in f:
+                if line.startswith("MemAvailable:"):
+                    return int(line.split()[1]) * 1024
+    except (OSError, ValueError):
+        pass
+    return None
+
+
 class FitMemoryWatermark:
     """Peak-byte watermark for one fit: opened/closed by `FitTelemetry`
     around the fit span.  Collects the per-device peak over every sample
@@ -431,6 +459,9 @@ class FitMemoryWatermark:
         # keeps the ratio comparable to a peak)
         self.predictions: Dict[str, float] = {}
         self._samples = 0
+        # the host's MemAvailable when the fit's first staging from host
+        # memory began (`note_host_staging`) and when the fit ended
+        self.host_available: Dict[str, Optional[int]] = {}
 
     # -- lifecycle (FitTelemetry) -------------------------------------------
 
@@ -442,6 +473,8 @@ class FitMemoryWatermark:
 
     def close(self) -> None:
         sample_devices()
+        if "start" in self.host_available:
+            self.host_available["end"] = host_available_bytes()
         with _lock:
             _active.pop(self.run_id, None)
 
@@ -501,6 +534,8 @@ class FitMemoryWatermark:
             "start_total_bytes": int(sum(self.start.values())),
             "grew_bytes": int(self.grew_bytes()),
         }
+        if self.host_available and None not in self.host_available.values():
+            sec["host_available_bytes"] = dict(self.host_available)
         if self.predictions:
             sec["predicted_bytes"] = {
                 k: int(v) for k, v in sorted(self.predictions.items())
@@ -520,7 +555,9 @@ __all__ = [
     "RealMemoryProvider",
     "SimulatedMemoryProvider",
     "get_provider",
+    "host_available_bytes",
     "maybe_sample",
+    "note_host_staging",
     "note_measured_drift",
     "record_budget_decision",
     "record_prediction",

@@ -332,7 +332,8 @@ class FitTelemetry:
                 not self._overlapped
                 and STAGE_METRICS.get("stamp", 0) >= self._t0
             ):
-                for k in ("bytes", "mb_per_s", "overlap_ratio", "pieces"):
+                for k in ("bytes", "mb_per_s", "overlap_ratio", "pieces",
+                          "pieces_viewed"):
                     v = STAGE_METRICS.get(k)
                     if v is not None:
                         staging[k] = v

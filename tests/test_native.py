@@ -61,6 +61,26 @@ def test_gather_rows_strided_matches_numpy(force_native, rng):
             )
             assert got.dtype == np.dtype(dst_dt)
             np.testing.assert_array_equal(got, want)
+            # into a buffer the caller reuses: the same rows, in place
+            buf = np.full((count + 2, 7), -1.0, dst_dt)
+            into = native.gather_rows_strided(
+                arr, start, step, count, np.dtype(dst_dt), out=buf[:count]
+            )
+            assert np.shares_memory(into, buf) or count == 0
+            np.testing.assert_array_equal(into, want)
+            assert (buf[count:] == -1.0).all()
+
+
+@pytest.mark.parametrize("stored", ["F", "strided"])
+def test_gather_rows_strided_into_buffer_numpy_fallback(stored, rng):
+    """Rows the native kernels do not take (F order, a strided view) go
+    through numpy, into the caller's buffer too."""
+    arr = rng.normal(size=(60, 5))
+    arr = np.asfortranarray(arr) if stored == "F" else np.tile(arr, (2, 2))[::2, ::2]
+    buf = np.zeros((10, 5), np.float32)
+    into = native.gather_rows_strided(arr, 3, 4, 10, np.dtype(np.float32), out=buf)
+    assert into is buf
+    np.testing.assert_array_equal(buf, arr[3:43:4].astype(np.float32))
 
 
 def test_pack_rows_matches_stack(force_native, rng):

@@ -46,6 +46,30 @@ def _host(arr) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
+class _Applied:
+    """A piece's `applied` token that remembers having been waited for."""
+
+    def __init__(self, token, waited: list) -> None:
+        self._token, self._waited = token, waited
+
+    def block_until_ready(self):
+        self._waited.append(self)
+        return self._token.block_until_ready()
+
+
+def _rows_as(X: np.ndarray, stored: str) -> np.ndarray:
+    """The same rows as the caller might hold them: C-ordered, F-ordered,
+    or every other row and column of a wider array (a strided view)."""
+    if stored == "F":
+        return np.asfortranarray(X)
+    if stored == "strided":
+        wide = np.zeros((2 * X.shape[0], 2 * X.shape[1]), X.dtype)
+        wide[::2, ::2] = X
+        return wide[::2, ::2]
+    return np.ascontiguousarray(X)
+
+
+@pytest.mark.parametrize("stored", ["C", "F", "strided"])
 @pytest.mark.parametrize("n,d,src_dt,out_dt", [
     (10_000, 37, np.float64, np.float32),   # cast fused into the gather
     (10_000, 37, np.float32, np.float32),
@@ -53,12 +77,14 @@ def _host(arr) -> np.ndarray:
     (999, 5, np.float32, np.float32),       # ragged tail vs shard grid
     (256, 3, np.float32, np.float32),       # minimum bucket
 ])
-def test_stage_parity_all_layouts(n, d, src_dt, out_dt, num_workers,
+def test_stage_parity_all_layouts(n, d, src_dt, out_dt, stored, num_workers,
                                   force_pipelined):
     """Pipelined staging is byte-identical to the serial path for the
-    interleaved AND contiguous layouts at every mesh size."""
+    interleaved AND contiguous layouts at every mesh size, whether a
+    piece is a view of the caller's rows (C order, the target dtype,
+    consecutive rows) or a gathered copy (everything else)."""
     rng = np.random.default_rng(n + d)
-    X = rng.standard_normal((n, d)).astype(src_dt)
+    X = _rows_as(rng.standard_normal((n, d)).astype(src_dt), stored)
     m = get_mesh(num_workers)
     for interleave in (None, False):
         st = RowStager(n, m, interleave=interleave)
@@ -76,6 +102,130 @@ def test_stage_parity_all_layouts(n, d, src_dt, out_dt, num_workers,
         assert np.array_equal(
             st.fetch(staged), X.astype(out_dt)[: st.n_valid]
         )
+
+
+@pytest.mark.parametrize("interleave", [None, False])
+@pytest.mark.parametrize("stored", ["C", "F", "strided"])
+@pytest.mark.parametrize("src_dt", [np.float32, np.float64])
+@pytest.mark.parametrize("n_dev", [1, 8])
+def test_piece_is_a_view_exactly_when_stored_as_needed(
+    n_dev, src_dt, stored, interleave, force_pipelined, monkeypatch
+):
+    """The producer hands over a view of the caller's rows exactly when
+    they are C-contiguous, of the target dtype and consecutive (one
+    device, or the contiguous layout), and a new array otherwise;
+    `pieces_viewed` counts them."""
+    set_config(staging_chunk_bytes=16 * 1024)  # several pieces a device
+    n, d = 6_000, 16
+    X = _rows_as(
+        np.random.default_rng(7).standard_normal((n, d)).astype(src_dt),
+        stored,
+    )
+    st = RowStager(n, get_mesh(n_dev), interleave=interleave)
+    pieces = []
+    real = mesh_mod.run_staging_pipeline
+
+    def recording(writer, producer, **kw):
+        def tee():
+            for item in producer:
+                pieces.append(item[2])
+                yield item
+
+        return real(writer, tee(), **kw)
+
+    monkeypatch.setattr(mesh_mod, "run_staging_pipeline", recording)
+    staged = st.stage(X, np.float32)
+    consecutive = n_dev == 1 or not st._interleave
+    viewed = src_dt == np.float32 and stored == "C" and consecutive
+    assert len(pieces) == STAGE_METRICS["pieces"] > n_dev
+    assert [np.shares_memory(p, X) for p in pieces] == [viewed] * len(pieces)
+    assert STAGE_METRICS["pieces_viewed"] == (len(pieces) if viewed else 0)
+    assert np.array_equal(st.fetch(staged), X.astype(np.float32))
+    if not viewed:
+        # copied pieces share a few buffers, however many pieces there are
+        assert all(p.base is not None for p in pieces)
+        buffers = {id(p.base) for p in pieces}
+        assert len(buffers) <= 2 + mesh_mod._MAX_INFLIGHT_PIECES + 2
+        assert len(buffers) < len(pieces)
+
+
+def test_piece_pool_waits_for_the_piece_last_put_from_a_buffer():
+    """A pooled buffer is written again only after the token of the
+    piece last put from it was waited for; a buffer handed out and never
+    reported put is not reused (the piece gets an array of its own)."""
+    waited = []
+    pool = mesh_mod._PiecePool(2, (4, 3), np.float32)
+    n = len(pool._bufs)
+    assert n == 2 + mesh_mod._MAX_INFLIGHT_PIECES + 2
+    X = np.arange(300, dtype=np.float64).reshape(100, 3)
+    first = []
+    for k in range(n):
+        piece = pool.gather(X, 4 * k, 1, 4)
+        assert np.array_equal(piece, X[4 * k : 4 * k + 4])
+        first.append(piece)
+        pool.note_put(piece, _Applied(jax.numpy.zeros(()), waited))
+    assert not waited  # every buffer was new: nothing to wait for
+    again = pool.gather(X, 50, 2, 3)  # the first buffer's turn, 3 rows
+    assert len(waited) == 1 and np.shares_memory(again, first[0])
+    assert np.array_equal(again, X[50:56:2])
+    assert again.dtype == np.float32 and again.flags.c_contiguous
+    # the second buffer's piece is reported put; the third's is not
+    for k in range(1, n):
+        pool.note_put(first[k], _Applied(jax.numpy.zeros(()), waited))
+    pool._state[2] = pool._OUT
+    second = pool.gather(X, 0, 1, 4)
+    own = pool.gather(X, 8, 1, 4)
+    assert np.shares_memory(second, first[1]) and len(waited) == 2
+    assert not any(np.shares_memory(own, b) for b in pool._bufs)
+    assert np.array_equal(own, X[8:12])
+
+
+def _as_one_of_two_processes(st: RowStager) -> RowStager:
+    """Send `st.stage` down the multi-process path in this one process:
+    rank 0 owning every shard of the mesh (one block, no bucketing)."""
+    st.n_proc = 2
+    st.block_sizes = np.array([st.local_padded], np.int64)
+    return st
+
+
+@pytest.mark.parametrize("multi_process", [False, True])
+def test_caller_may_overwrite_rows_once_stage_returns(
+    multi_process, force_pipelined, monkeypatch
+):
+    """Pieces are views of the caller's array, which the transfers read:
+    `stage` returns only after the last piece has been applied, so NaNs
+    written over `X` the moment it returns reach no staged row.  (On the
+    CPU the last transfer loses that race once in a few runs, so the
+    wait itself is checked too: every piece's token was waited for.)"""
+    set_config(staging_chunk_bytes=256 * 1024)
+    n, d = 40_000, 32
+    X = np.random.default_rng(11).standard_normal((n, d)).astype(np.float32)
+    want = X.copy()
+    st = RowStager(n, get_mesh(1), bucketing=False)
+    if multi_process:
+        st = _as_one_of_two_processes(st)
+    tokens, waited = [], []
+    real = mesh_mod._shard_update_fns
+
+    def spied(shape, dtype_str, device):
+        mk, upd = real(shape, dtype_str, device)
+
+        def upd_spied(buf, piece, lo):
+            buf, applied = upd(buf, piece, lo)
+            tokens.append(_Applied(applied, waited))
+            return buf, tokens[-1]
+
+        return mk, upd_spied
+
+    monkeypatch.setattr(mesh_mod, "_shard_update_fns", spied)
+    staged = st.stage(X, np.float32)
+    X[:] = np.nan
+    assert STAGE_METRICS["label"] == ("stage_mp" if multi_process else "stage")
+    assert STAGE_METRICS["pieces"] > 4 * mesh_mod._MAX_INFLIGHT_PIECES
+    assert STAGE_METRICS["pieces_viewed"] == STAGE_METRICS["pieces"]
+    assert len(tokens) == STAGE_METRICS["pieces"]
+    assert all(any(t is w for w in waited) for t in tokens)
+    assert np.array_equal(_host(staged)[:n], want)
 
 
 def test_stage_parity_1d_labels_f64(num_workers, force_pipelined):
@@ -158,8 +308,8 @@ def test_stage_metrics_populated(force_pipelined):
     st = RowStager(8_192, get_mesh(8))
     st.stage(X, np.float32)
     for key in ("bytes", "seconds", "mb_per_s", "host_prep_s",
-                "device_put_s", "overlap_ratio", "pieces", "depth",
-                "n_dev"):
+                "device_put_s", "overlap_ratio", "pieces", "pieces_viewed",
+                "depth", "n_dev"):
         assert key in STAGE_METRICS, key
     # padding never travels: transferred bytes == valid rows only
     assert STAGE_METRICS["bytes"] == X.size * 4
@@ -366,3 +516,34 @@ def test_pipelined_beats_serial_on_multi_device_mesh():
     )
     assert {e.thread_id for e in put} == {threading.get_ident()}
     assert len(put) == len(prep) == STAGE_METRICS["pieces"] >= 8
+
+
+@pytest.mark.parametrize("src_dt", [np.float32, np.float64])
+def test_fit_report_says_how_many_pieces_were_views(src_dt):
+    """A fit from host rows on one device reports `pieces_viewed` beside
+    `pieces` (all of them for float32 rows, none where the cast to
+    float32 has to copy) and the host's MemAvailable at its two ends;
+    a fit from a DeviceDataset reads none."""
+    from spark_rapids_ml_tpu.classification import LogisticRegression
+
+    set_config(staging_chunk_bytes=1024 * 1024)
+    rng = np.random.default_rng(13)
+    X = rng.standard_normal((40_000, 32)).astype(src_dt)
+    assert X.nbytes >= mesh_mod._PIPELINED_MIN_BYTES  # the labels stay serial
+    y = (X[:, 0] > 0).astype(np.float64)
+    model = LogisticRegression(maxIter=3, num_workers=1).fit((X, y))
+    rep = model.fit_report()
+    staging = rep["staging"]
+    assert staging["pieces"] >= 4
+    assert staging["pieces_viewed"] == (
+        staging["pieces"] if src_dt == np.float32 else 0
+    )
+    host = rep["memory"]["host_available_bytes"]
+    assert host["start"] > 0 and host["end"] > 0
+    # a fit from rows already on the devices reads no host memory
+    from spark_rapids_ml_tpu.data import DeviceDataset
+
+    cached = LogisticRegression(maxIter=3, num_workers=1).fit(
+        DeviceDataset.from_host(X, y, num_workers=1)
+    )
+    assert "host_available_bytes" not in cached.fit_report()["memory"]
