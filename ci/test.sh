@@ -32,7 +32,7 @@ export WEDGE_GUARD_S="${WEDGE_GUARD_S:-2400}"
 export PYTHONPATH="$(pwd)/ci/wedge${PYTHONPATH:+:$PYTHONPATH}"
 
 echo "== lint: byte-compile all sources =="
-python -m compileall -q spark_rapids_ml_tpu benchmark tests bench.py __graft_entry__.py
+python -m compileall -q spark_rapids_ml_tpu benchmark tests __graft_entry__.py
 
 echo "== lint: graft-lint static checks (full rule set) =="
 # the project-specific analyzer (spark_rapids_ml_tpu/analysis/): builtin
@@ -136,7 +136,7 @@ run_batch tests/test_umap.py tests/test_streaming.py \
     tests/test_drift_monitor.py \
     tests/test_flight_recorder.py tests/test_aggregate.py \
     tests/test_locks_utilization.py tests/test_hang_doctor.py \
-    tests/test_bench_history.py tests/test_analysis.py \
+    tests/test_analysis.py tests/test_run_facts.py \
     tests/test_no_import_change.py \
     tests/test_pyspark_interop.py \
     tests/test_slow_scale.py tests/test_multiprocess.py \
@@ -819,9 +819,9 @@ from spark_rapids_ml_tpu.config import set_config
 from spark_rapids_ml_tpu.parallel.mesh import STAGE_COUNTS
 from spark_rapids_ml_tpu.resilience import fault_inject
 from spark_rapids_ml_tpu.stats import summarize
-from spark_rapids_ml_tpu.stats.engine import STAT_METRICS
 from spark_rapids_ml_tpu.telemetry import REGISTRY
 from spark_rapids_ml_tpu.telemetry.exporters import dump_prometheus
+from spark_rapids_ml_tpu.tracing import last_fact
 
 rng = np.random.default_rng(0)
 X = rng.standard_normal((60_000, 16)).astype(np.float32)
@@ -831,7 +831,8 @@ set_config(retry_backoff_s=0.01, retry_jitter=0.0)
 stagings0 = STAGE_COUNTS["dataset_stagings"]
 clean = summarize(X, metrics=metrics)
 assert STAGE_COUNTS["dataset_stagings"] == stagings0, "staged the batch"
-assert STAT_METRICS["passes"] == 1 and STAT_METRICS["chunks"] >= 2
+stats = last_fact("stats")  # the pass's own record, on this thread
+assert stats["passes"] == 1 and stats["chunks"] >= 2
 with fault_inject("stat_program_step", "oom", times=1, skip=2):
     faulted = summarize(X, metrics=metrics)
 assert faulted["count"] == clean["count"]
@@ -845,8 +846,8 @@ assert "stat_program_runs_total" in text, "family not scrapeable"
 sentinel = object()
 assert REGISTRY.get("solver_iteration").value(
     default=sentinel, solver="stat_programs") is sentinel, "live gauge leak"
-print(f"stats smoke OK: {STAT_METRICS['programs']} programs, "
-      f"{STAT_METRICS['chunks']} chunks, one pass, OOM restart "
+print(f"stats smoke OK: {stats['programs']} programs, "
+      f"{stats['chunks']} chunks, one pass, OOM restart "
       "bit-identical, families scrapeable, gauges end-marked")
 EOF
 
@@ -902,141 +903,6 @@ with tempfile.TemporaryDirectory() as td:
     assert "pass-a" in stacks and "pass-b" in stacks
     print("hang-doctor smoke OK:", wf["cycles"][0]["description"])
 EOF
-
-echo "== benchmark smoke =="
-BENCH_ROWS=20000 BENCH_COLS=16 BENCH_CPU_SAMPLE=5000 BENCH_WORKLOADS=none \
-    JAX_PLATFORMS=cpu python bench.py
-
-echo "== perf smoke: bench history + regression gate =="
-# two consecutive tiny-shape runs (logreg headline + staging +
-# fused_pca sections) must (a) append exactly one normalized record per
-# section per run to the history file, (b) pass the comparator within
-# noise, and (c) fail it nonzero on an injected 2x slowdown AND on an
-# injected SERIALIZATION of the fused stage-and-solve path.
-# benchmark/{history,compare}.py are the units under test; unit
-# coverage is in tests/test_bench_history.py.
-PERF_DIR=$(mktemp -d)
-for i in 1 2; do
-    BENCH_ROWS=20000 BENCH_COLS=16 BENCH_CPU_SAMPLE=5000 BENCH_MAX_ITER=10 \
-    BENCH_WORKLOADS=staging,fused_pca,pod_observatory \
-    BENCH_STAGING_ROWS=40000 \
-    BENCH_FUSED_ROWS=48000 BENCH_FUSED_COLS=64 BENCH_FUSED_SOLVER_ROWS=2000 \
-    BENCH_ISOLATE=0 \
-    BENCH_PROBE_TIMEOUT=0 BENCH_RUN_ID="perf-smoke-$i" \
-    BENCH_HISTORY_PATH="$PERF_DIR/history.jsonl" \
-    JAX_PLATFORMS=cpu python bench.py > /dev/null
-done
-# within-noise gate: wide band + 50 ms absolute floor for a 2-core
-# shared CI box (a 20 ms metric doubling is scheduler jitter), scoped to
-# the logreg section — the staging section's sub-100ms timings and
-# pipelined-vs-serial ratio are pure scheduler noise at smoke scale
-# (their records still land in the history, asserted below); the
-# cold-fit improvement from run 1 warming the compile cache must not gate
-python -m benchmark.compare --history "$PERF_DIR/history.jsonl" \
-    --sections logreg --tolerance 0.75 --abs-floor 0.05
-# fused-path gate: the overlap fraction is the deterministic signal
-# (interval intersection of chunk prep and device-busy windows —
-# 0.85-0.92 at this shape, run to run); timings at smoke scale are
-# jitter and get an effectively-infinite band
-python -m benchmark.compare --history "$PERF_DIR/history.jsonl" \
-    --sections fused_pca --tolerance 10 \
-    --band fused_pca_overlap_fraction=0.75 --abs-floor 0.05
-# pod-observatory gate: the trace merge and per-pass report costs are
-# pure-python microbenchmarks — wide band + the 50 ms absolute floor
-# absorbs shared-box scheduler jitter while still catching an
-# order-of-magnitude regression in the merge or pass-complete path
-python -m benchmark.compare --history "$PERF_DIR/history.jsonl" \
-    --sections pod_observatory --tolerance 2.0 --abs-floor 0.05
-# injected serialization: staging_pipeline_depth=1 strips the producer
-# thread, the prep and accumulate windows stop co-occurring, and the
-# recorded overlap_fraction collapses to 0.0 — the comparator must trip
-BENCH_ROWS=20000 BENCH_COLS=16 BENCH_CPU_SAMPLE=5000 BENCH_MAX_ITER=10 \
-    BENCH_WORKLOADS=fused_pca \
-    BENCH_FUSED_ROWS=48000 BENCH_FUSED_COLS=64 BENCH_FUSED_SOLVER_ROWS=2000 \
-    BENCH_ISOLATE=0 BENCH_PROBE_TIMEOUT=0 \
-    BENCH_RUN_ID="perf-smoke-serialized" \
-    BENCH_HISTORY_PATH="$PERF_DIR/history.jsonl" \
-    SPARK_RAPIDS_ML_TPU_STAGING_PIPELINE_DEPTH=1 \
-    JAX_PLATFORMS=cpu python bench.py > /dev/null
-if python -m benchmark.compare --history "$PERF_DIR/history.jsonl" \
-    --run-id perf-smoke-serialized --sections fused_pca --tolerance 10 \
-    --band fused_pca_overlap_fraction=0.5 --abs-floor 0.05; then
-    echo "comparator must fail when the fused path serializes"; exit 1
-fi
-# record-count contract + the injected-slowdown gate
-python - "$PERF_DIR/history.jsonl" << 'EOF'
-import json, subprocess, sys
-
-path = sys.argv[1]
-records = [json.loads(l) for l in open(path) if l.strip()]
-per_run = {}
-for r in records:
-    per_run.setdefault(r["run_id"], []).append(r["section"])
-assert set(per_run) == {
-    "perf-smoke-1", "perf-smoke-2", "perf-smoke-serialized"
-}, per_run
-for rid, secs in per_run.items():
-    assert len(secs) == len(set(secs)), f"duplicate section records: {rid}"
-    want = (
-        {"logreg", "fused_pca"}
-        if rid == "perf-smoke-serialized"
-        else {"logreg", "staging", "fused_pca", "pod_observatory"}
-    )
-    assert want <= set(secs), (rid, secs)
-# inject a synthetic 2x slowdown of run 2 and expect the gate to trip
-from benchmark.compare import metric_direction
-
-slow = [json.loads(l) for l in open(path) if l.strip()]
-for r in slow:
-    if r["run_id"] != "perf-smoke-2":
-        continue
-    r2 = dict(r, run_id="perf-smoke-slow", metrics={
-        k: (v * 2 if metric_direction(k) == "lower" else v)
-        for k, v in r["metrics"].items()
-    })
-    with open(path, "a") as f:
-        f.write(json.dumps(r2) + "\n")
-# --k 1 pins the baseline to run 2 itself (the run that was doubled):
-# the slowdown is then exactly +100% on every gated time metric, immune
-# to the run-1-vs-run-2 compile-cache asymmetry
-rc = subprocess.call([sys.executable, "-m", "benchmark.compare",
-                      "--history", path, "--sections", "logreg",
-                      "--k", "1", "--tolerance", "0.75",
-                      "--abs-floor", "0.05"])
-assert rc != 0, "comparator must fail on a 2x slowdown"
-print("perf smoke OK: history records per section per run, gate trips "
-      "on 2x slowdown")
-EOF
-rm -rf "$PERF_DIR"
-
-echo "== observatory overhead gate: serving QPS ON within 5% of OFF =="
-# the progress observatory (named locks + flight recorder + hang
-# doctor) must stay cheap enough to leave on: bench.py's `utilization`
-# section measures serving QPS with the full observatory ON vs OFF and
-# the ON/OFF ratio must hold >= 0.95 (a 2-core CI box is noisy, so the
-# ratio — both sides on the same box in the same process — is the
-# stable signal, not the absolute QPS).  Lock overhead and doctor tick
-# cost land in the same section for the history trend.
-UTIL_DIR=$(mktemp -d)
-BENCH_WORKLOADS=utilization BENCH_UTILIZATION_REQUESTS=200 \
-    BENCH_ISOLATE=0 BENCH_PROBE_TIMEOUT=0 \
-    BENCH_RUN_ID="util-gate" BENCH_HISTORY_PATH="$UTIL_DIR/history.jsonl" \
-    JAX_PLATFORMS=cpu python bench.py > "$UTIL_DIR/bench.json"
-python - "$UTIL_DIR/bench.json" << 'EOF'
-import json, sys
-
-extra = json.load(open(sys.argv[1]))["extra"]
-ratio = extra["utilization_observatory_speedup_x"]
-lock_us = extra["utilization_lock_overhead_us_per_acquire"]
-tick_us = extra["utilization_doctor_tick_us"]
-assert ratio >= 0.95, (
-    f"observatory ON costs more than 5% serving QPS: ON/OFF={ratio}")
-assert lock_us < 25.0, f"named-lock overhead {lock_us} us/acquire"
-assert tick_us < 50_000.0, f"hang-doctor tick {tick_us} us"
-print(f"observatory gate OK: ON/OFF={ratio}, lock +{lock_us} us/acquire, "
-      f"doctor tick {tick_us} us")
-EOF
-rm -rf "$UTIL_DIR"
 
 echo "== pod benchmark smoke (2-process jax.distributed) =="
 python benchmark/pod/launch.py --num_processes 2 --devices_per_process 2 \

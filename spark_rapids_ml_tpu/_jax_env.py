@@ -1,7 +1,7 @@
 #
 # Where the persistent XLA compilation cache lives — the one place in the
-# repo that names it.  Entry scripts (chip_smoke.py, bench.py,
-# benchmark/*.py) call `configure_compile_cache()` before their first use
+# repo that names it.  Entry scripts (chip_smoke.py, benchmark/*.py)
+# call `configure_compile_cache()` before their first use
 # of jax; the library itself sets nothing on import.
 #
 # jax reads JAX_COMPILATION_CACHE_DIR itself at import.  When the caller

@@ -42,7 +42,6 @@ PY_ROOTS = (
     "tests",
     "ci",
     "docs",
-    "bench.py",
     "__graft_entry__.py",
 )
 DOC_FILES = (

@@ -383,12 +383,6 @@ _DEFAULTS: Dict[str, Any] = {
     # heartbeats); > 0 adds a daemon-thread sampler so long device-bound
     # stretches can't hide an HBM peak between explicit samples.
     "memory_sample_interval_s": 0.0,
-    # Bench-history file (benchmark/history.py): when set, bench.py
-    # appends one normalized flat-metric record per completed section
-    # per run, and `python -m benchmark.compare` gates regressions
-    # against the median of the last k runs.  Overridable per run with
-    # the BENCH_HISTORY_PATH env var; empty disables appending.
-    "bench_history_path": "",
     # Small-batch direct staging fast path (parallel/mesh.py): a 2-D
     # host array below the pipelined-engine threshold stages as plain
     # per-device slices + one device_put per shard — no full padded host

@@ -28,27 +28,14 @@ from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 import numpy as np
 
 from .config import get_config
-from .telemetry.registry import dict_view as _dict_view
 from .telemetry.utilization import (
     interval_overlap_s as _interval_overlap_s,
     merge_intervals as _merge_intervals,
 )
+from .tracing import current_run_id, event, fact
 from .utils import get_logger
 
 logger = get_logger("spark_rapids_ml_tpu.fused")
-
-# last fused run (read by bench.py's `fused_pca` section, the refconfig
-# stage/solve split, and the per-fit telemetry report — the report copies
-# these keys only when `stamp` lands inside the fit's window):
-#   host_prep_s   chunk decode/cast/slice time on the reader thread(s)
-#   device_acc_s  device_put + accumulate time on the consumer thread
-#   overlap_s     measured wall-clock INTERSECTION of the prep intervals
-#                 with the device-busy intervals (_interval_overlap_s)
-#   overlap_fraction  overlap_s / min(prep_s, acc_s) in [0, 1]
-FUSED_METRICS = _dict_view(
-    "fused_last",
-    "Last fused stage-and-solve run (prep/accumulate/overlap seconds)",
-)
 
 # `fused_stage_solve="auto"` fuses once the estimated staged bytes reach
 # this floor: below it one plain staging beats the per-chunk dispatch
@@ -209,11 +196,6 @@ def iter_host_chunks(
         yield cX, cy, cw
 
 
-# last resolve_parquet_readers decision (stamped), copied into the fit
-# report's solver_decision section by telemetry/report.py — "why did
-# this fit decode with N readers" must be answerable from the artifact
-LAST_READER_DECISION: dict = {}
-
 # measured single-reader decode throughput (updated by `_range_chunks`
 # after every un-cached single-reader pass): the `auto` reader count is
 # sink-bounded by it — decode only needs to outrun the device transfer
@@ -229,8 +211,9 @@ def resolve_parquet_readers(path: Optional[str] = None) -> int:
     the measured decode-vs-sink rates when both are on record (readers
     beyond sink_rate/decode_rate + 1 only contend for memory
     bandwidth).  Row-group availability clamps later, in
-    `_partition_row_groups`.  The decision (mode, count, reason) lands
-    in `LAST_READER_DECISION` for the fit report."""
+    `_partition_row_groups`.  The decision (mode, count, reason) is the
+    run's `parquet_readers` fact: "why did this fit decode with N
+    readers" is answered by the fit report's `solver_decision`."""
     import os
 
     raw = get_config("fused_parquet_readers")
@@ -242,9 +225,9 @@ def resolve_parquet_readers(path: Optional[str] = None) -> int:
         decode_mbs = _DECODE_RATE.get("mb_per_s")
         if decode_mbs:
             reason += f", measured_decode={decode_mbs:.0f}MB/s"
-            from .parallel.mesh import STAGE_METRICS
+            from .parallel.mesh import last_put_rate_mb_per_s
 
-            sink_mbs = STAGE_METRICS.get("mb_per_s")
+            sink_mbs = last_put_rate_mb_per_s()
             if sink_mbs:
                 need = int(np.ceil(
                     float(sink_mbs) / max(float(decode_mbs), 1e-9)
@@ -256,9 +239,8 @@ def resolve_parquet_readers(path: Optional[str] = None) -> int:
         readers = max(1, int(raw))
         mode = "explicit"
         reason = "pinned by conf"
-    LAST_READER_DECISION.clear()
-    LAST_READER_DECISION.update(
-        stamp=round(time.time(), 3),
+    fact(
+        "parquet_readers",
         parquet_readers=int(readers),
         parquet_readers_mode=mode,
         parquet_readers_reason=reason,
@@ -903,8 +885,6 @@ def accumulate_chunks(
     # is computed from the timeline, and its reduce_blob_list exchange
     # is the pass's last SPMD site (every rank reaches it after the
     # fold above succeeded)
-    from .tracing import current_run_id
-
     _fleet.complete_pod_pass(run_id=current_run_id())
     return host, {
         "wall_s": wall,
@@ -920,10 +900,12 @@ def _record_metrics(
     label: str, kind: str, passes: int, totals: Dict[str, float],
     solver: Optional[str] = None,
 ) -> None:
-    """Fold one fused fit's (possibly multi-pass) totals into
-    `FUSED_METRICS` + a trace event.  overlap_s is the measured
-    wall-clock intersection of the chunk-prep intervals (producer
-    thread) with the device-busy intervals (`_interval_overlap_s`);
+    """Fold one fused fit's (possibly multi-pass) totals into the run's
+    `fused` fact + a trace event.  host_prep_s is the chunk
+    decode/cast/slice time on the reader thread(s), device_acc_s the
+    device_put + accumulate time on the consumer thread; overlap_s is
+    the measured wall-clock intersection of the chunk-prep intervals
+    with the device-busy intervals (`_interval_overlap_s`);
     overlap_fraction normalizes it by the smaller phase (1.0 = the
     cheaper phase ran entirely inside the other's window)."""
     wall = totals.get("wall_s", 0.0)
@@ -933,11 +915,11 @@ def _record_metrics(
     overlap = 0.0
     if min(prep_s, acc_s) > 1e-9:
         overlap = max(0.0, min(overlap_s / min(prep_s, acc_s), 1.0))
-    FUSED_METRICS.clear()
-    FUSED_METRICS.update(
-        stamp=round(time.time(), 3),
+    fact(
+        "fused",
         label=label,
         kind=kind,
+        **({"solver": solver} if solver is not None else {}),
         passes=int(passes),
         chunks=int(totals.get("chunks", 0)),
         bytes=int(totals.get("bytes", 0)),
@@ -947,10 +929,6 @@ def _record_metrics(
         overlap_s=round(overlap_s, 4),
         overlap_fraction=round(overlap, 4),
     )
-    if solver is not None:
-        FUSED_METRICS["solver"] = solver
-    from .tracing import event
-
     event(
         f"fused_stats[{label}]",
         detail=(

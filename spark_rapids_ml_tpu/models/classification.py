@@ -389,8 +389,7 @@ class LogisticRegression(
             "objective": float(hist[-1]) if hist else 0.0,
             "objective_history": hist,
             "converged": bool(res.get("converged", False)),
-            # true dataset passes incl. line-search backtracks (bench.py
-            # computes rows/sec/epoch from this)
+            # true dataset passes incl. line-search backtracks
             "streaming_epochs": int(res.get("epochs", 0)),
         }
 

@@ -59,7 +59,7 @@ BUILDER_VERSION = 1
 
 # rows buffered before the sketches fold: per-row serving requests must
 # not pay a per-row np.unique per column — buffered folds amortize the
-# sketch cost to ~1-2 us/row (the bench `drift` section measures it)
+# sketch cost over the buffer
 _FOLD_BATCH_ROWS = 2048
 
 # decile edges the PSI comparison bins on (monitor/compare.py)

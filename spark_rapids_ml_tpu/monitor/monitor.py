@@ -9,8 +9,7 @@
 #                              batches fold into the model's current
 #                              tumbling window (host tier only — the
 #                              device hot path pays nothing; the fold is
-#                              buffered-amortized, measured us/row in
-#                              the bench `drift` section)
+#                              buffered-amortized)
 #   observe_output(model, outs) prediction-side drift: output columns
 #                              (predicted classes, regression outputs)
 #                              fold into per-column windows whose

@@ -122,7 +122,7 @@ _BLOCKED_TILE_LIMIT_BYTES = 2 << 30
 
 
 # observability for the pallas_knn=auto measured probe (the kNN analog of
-# ops/umap.py LAST_KERNEL_DECISION, read by bench.py and tests): which
+# ops/umap.py LAST_KERNEL_DECISION, read by the tests): which
 # kernel the last knn_topk_single dispatch used and the probe timings
 # that decided it (None timings = no probe ran)
 LAST_KERNEL_DECISION: dict = {
@@ -389,7 +389,7 @@ def knn_topk_coltiled(items, item_valid, item_ids, queries, k: int,
     top_k was measured as the dominant cost of `knn_topk_blocked` on the
     v5e (the Pallas experiment's conclusion, ops/pallas_knn.py); this is
     the sort-narrowing alternative at the XLA level — candidate default
-    pending an on-chip comparison (bench.py knn workload records both).
+    pending an on-chip comparison.
     Exact-equivalent to `knn_topk_blocked`."""
     q, d = queries.shape
     n = items.shape[0]

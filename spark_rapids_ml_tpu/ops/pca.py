@@ -63,15 +63,6 @@ def pca_fit(X: jax.Array, w: jax.Array, k: int):
 # (auto|full|randomized) + `pca_oversamples` + `pca_power_iters`.
 # ---------------------------------------------------------------------------
 
-from ..telemetry.registry import dict_view as _dict_view
-
-# last solver decision (read by bench.py's fused_pca section and copied
-# into the per-fit telemetry report when stamped inside the fit window)
-LAST_SOLVER_DECISION = _dict_view(
-    "pca_solver_last", "Last PCA solver decision (solver/reason/d/k/l)"
-)
-
-
 def resolve_pca_solver(d: int, k: int, streamed: bool = False):
     """(solver, l, power_iters, reason) from the `pca_solver` conf.
 
@@ -81,12 +72,10 @@ def resolve_pca_solver(d: int, k: int, streamed: bool = False):
     otherwise the exact full solver (identical to cuML PCAMG).
     `streamed=True` (the fused/streaming paths, where every randomized
     pass RE-READS the source — chunk decode is not free like a resident
-    array) demands a 16x margin before auto switches.  The decision
-    lands in `LAST_SOLVER_DECISION` with a stamp so fit reports and the
-    bench can attribute it."""
-    import time
-
+    array) demands a 16x margin before auto switches.  The decision is
+    the run's `pca_solver` fact, the fit report's `solver_decision`."""
     from ..config import get_config
+    from ..tracing import fact
 
     mode = str(get_config("pca_solver")).lower()
     if mode not in ("auto", "full", "randomized"):
@@ -106,9 +95,8 @@ def resolve_pca_solver(d: int, k: int, streamed: bool = False):
         solver, reason = "randomized", f"auto:d>={threshold}"
     else:
         solver, reason = "full", f"auto:d<{threshold}"
-    LAST_SOLVER_DECISION.clear()
-    LAST_SOLVER_DECISION.update(
-        stamp=round(time.time(), 3), solver=solver, reason=reason,
+    fact(
+        "pca_solver", solver=solver, reason=reason,
         d=int(d), k=int(k), l=int(l), power_iters=int(power_iters),
     )
     return solver, l, power_iters, reason

@@ -272,7 +272,7 @@ def _optimize_epoch_chunk(
 
 # observability for the umap_kernel=auto measured probe: the last
 # optimize_embedding call's kernel choice and its per-epoch timings
-# (read by bench.py and tests; None timings = no probe ran)
+# (read by the tests; None timings = no probe ran)
 LAST_KERNEL_DECISION: dict = {
     "kernel": None,
     "decided_by": None,

@@ -49,7 +49,7 @@ from .mesh import (
 )
 
 # cumulative cache metrics (also mirrored into mesh.STAGE_COUNTS): read
-# by tests, bench.py `cv_cached`, and operators debugging residency.
+# by tests and operators debugging residency.
 # Now a VIEW over the telemetry registry (the `device_cache{key=...}`
 # Prometheus family) — the mapping surface is unchanged.
 from ..telemetry.registry import dict_view as _dict_view

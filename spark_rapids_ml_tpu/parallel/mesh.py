@@ -220,8 +220,8 @@ def assemble_rows_serial(shape, dtype, pieces, out_shardings=None):
     replicates it to every device of a row-sharded target — n_dev x the
     minimal traffic (the factor the pipelined per-device engine below
     removes).  Kept as the fallback for shardings the per-device writer
-    cannot decompose, and as the parity/benchmark reference for the
-    engine (tests/test_staging_pipeline.py, bench.py `staging`)."""
+    cannot decompose, and as the byte-parity reference for the engine
+    (tests/test_staging_pipeline.py)."""
     import jax.numpy as jnp
 
     dtype = np.dtype(dtype)
@@ -293,29 +293,29 @@ def assemble_rows_chunked(shape, dtype, pieces, out_shardings=None,
 
 from ..telemetry.locks import named_lock
 from ..telemetry.registry import dict_view as _dict_view
-from ..tracing import record_span, trace
+from ..tracing import fact, record_span, trace
 
-# last staging-engine run: bytes, seconds, mb_per_s, host_prep_s,
-# device_put_s, overlap_ratio, pieces, pieces_viewed (those put straight
-# from the caller's rows), depth, label (read by bench.py's
-# `staging` workload and the parity tests).  Since the telemetry PR this
-# is a VIEW over the process-global metrics registry
-# (telemetry/registry.py) — same mapping surface, but `dump_prometheus`
-# and `snapshot()` export it as the `staging_last{key=...}` family.
-STAGE_METRICS = _dict_view(
-    "staging_last", "Last staging-engine run (bytes/seconds/MB-s/overlap)"
-)
+# the put rate (MB/s) of the last staging-engine run: a measured value
+# its owner keeps for a decision, as `fused._DECODE_RATE` is kept
+# (`fused.resolve_parquet_readers` sizes its reader pool so decode just
+# outruns it).  One accessor, `last_put_rate_mb_per_s`.
+_PUT_RATE: dict = {}
+
+
+def last_put_rate_mb_per_s() -> Optional[float]:
+    """MB/s the last staging-engine run moved, None before the first."""
+    return _PUT_RATE.get("mb_per_s")
+
 
 # CUMULATIVE process-wide staging/cache counters (never cleared by a
-# staging run, unlike STAGE_METRICS): `dataset_stagings` counts EVERY
+# staging run): `dataset_stagings` counts EVERY
 # 2-D host->device staging through RowStager.stage/stage_sparse — fit
 # feature matrices AND per-chunk transform/eval inputs (which is why a
 # legacy k-fold CV measures >= 2k+1: k train stagings + one eval staging
 # per (fold, model) + the refit).  The `cache_*` keys mirror the
 # device-cache registry's hit/miss/evict events
-# (parallel/device_cache.py).  bench.py's `cv_cached` section and the
-# cache tests read deltas of these to assert the stagings-per-CV-run
-# contract (2k+1-and-more -> 1).
+# (parallel/device_cache.py).  The cache tests read deltas of these to
+# assert the stagings-per-CV-run contract (2k+1-and-more -> 1).
 STAGE_COUNTS = _dict_view(
     "staging_counts",
     "Cumulative staging/cache counters (dataset_stagings, cache_*)",
@@ -676,10 +676,11 @@ def run_staging_pipeline(
     serial, no thread).  Every put is dispatched from the calling
     thread; `on_put(host_rows, applied)` tells a producer that reuses
     its buffers (`_PiecePool`) which token frees which.
-    Records throughput + overlap in `STAGE_METRICS`; the run's trace
-    holds one `stage_prep` and one `stage_put` span per piece and a
-    `stage_finish` span for the assembly and the bookkeeping after the
-    last put."""
+    Records throughput + overlap as the run's `staging` fact
+    (`tracing.fact`); the run's trace holds one `stage_prep` and one
+    `stage_put` span per piece (their sums are the prep and put seconds)
+    and a `stage_finish` span for the assembly and the bookkeeping after
+    the last put."""
     depth = _staging_depth()
     t0 = time.perf_counter()
     prep = {"s": 0.0, "iv": []}
@@ -710,19 +711,13 @@ def run_staging_pipeline(
         overlap = max(0.0, min(
             (busy - wall) / min(prep["s"], writer.put_seconds), 1.0
         ))
-    STAGE_METRICS.clear()
-    STAGE_METRICS.update(
-        # absolute completion time: per-fit reports copy these engine
-        # numbers only when the run happened INSIDE the fit's window
-        # (STAGE_METRICS is process-wide last-run state, so without the
-        # stamp a cache-served fit would inherit the previous fit's MB/s)
-        stamp=round(time.time(), 3),
+    _PUT_RATE["mb_per_s"] = round(mb / max(wall, 1e-9), 1)
+    fact(
+        "staging",
         label=label,
         bytes=writer.bytes_written,
         seconds=round(wall, 4),
-        mb_per_s=round(mb / max(wall, 1e-9), 1),
-        host_prep_s=round(prep["s"], 4),
-        device_put_s=round(writer.put_seconds, 4),
+        mb_per_s=_PUT_RATE["mb_per_s"],
         overlap_ratio=round(overlap, 4),
         pieces=writer.pieces,
         pieces_viewed=writer.pieces_viewed,
@@ -778,12 +773,11 @@ def _chunked_device_put(arr: np.ndarray, sharding=None) -> "jax.Array":
     assembled on device instead of one transfer.  sharding=None targets
     the default device.  Deliberately uses the LEGACY global-update loop
     (`assemble_rows_serial`), never the per-device engine:
-    `RowStager._stage_serial` is the byte-parity/benchmark reference the
-    engine is measured against (routing it through the engine at large
-    sizes would make the 'serial' side of that comparison the engine
-    racing itself), and the other callers (ops/ivf.py, models/knn.py
-    index uploads) are unsharded default-device puts the per-device
-    writer could not improve."""
+    `RowStager._stage_serial` is the byte-parity reference the engine is
+    compared with (routed through the engine at large sizes, the
+    reference would be the engine itself), and the other callers
+    (ops/ivf.py, models/knn.py index uploads) are unsharded
+    default-device puts the per-device writer could not improve."""
     ensure_x64(arr.dtype)
     if arr.nbytes <= _MAX_PUT_BYTES or arr.ndim == 0 or arr.shape[0] <= 1:
         if arr.nbytes > _MAX_PUT_BYTES:
@@ -1085,9 +1079,8 @@ class RowStager:
         """LEGACY single-process staging: full padded host copy ->
         interleave permutation copy -> (chunked) device_put.  Kept for
         small arrays (one plain device_put beats per-device assembly
-        overheads), as the byte-parity reference for the pipelined
-        engine, and as the serial side of bench.py's `staging`
-        microbenchmark."""
+        overheads) and as the byte-parity reference for the pipelined
+        engine (tests/test_staging_pipeline.py)."""
         padded = self._pad_host(arr, dtype)
         sharding = NamedSharding(self.mesh, data_pspec(padded.ndim))
         return _chunked_device_put(self._to_layout(padded), sharding)

@@ -48,9 +48,9 @@
 # sizes reuse ONE compiled transform program per bucket — the jit-audit
 # zero-recompile guarantee extended to the serving path (asserted via
 # `compiles_total` deltas in tests/test_serving_control.py).  Each
-# dispatch records its decision in `LAST_BUCKET_DECISION` (the
-# `solver_decision` stamp idiom telemetry/report.py copies) and the
-# per-model bucket set surfaces in the serving report.
+# dispatch records its decision as a `serving_bucket` fact on its run
+# (`tracing.fact`; a fit's report shows its own under `solver_decision`)
+# and the per-model bucket set surfaces in the serving report.
 #
 from __future__ import annotations
 
@@ -60,7 +60,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from ..config import get_config
 from ..telemetry.locks import named_lock
 from ..telemetry.registry import counter, gauge
-from ..tracing import event
+from ..tracing import event, fact
 from ..utils import get_logger
 
 logger = get_logger("spark_rapids_ml_tpu.serving")
@@ -115,13 +115,6 @@ _INTERACTIVE_TIGHTEN = 8
 # padding-class bookkeeping bound: distinct buckets retained per model
 # for the report (the grid is coarse; real traffic sees a handful)
 _MAX_BUCKETS_TRACKED = 32
-
-# the last serving padding-class decision — the `solver_decision` stamp
-# idiom (ops/pca.py LAST_SOLVER_DECISION): telemetry/report.py copies
-# it into a fit report whose window covers the stamp, and the serving
-# report exposes it live
-LAST_BUCKET_DECISION: Dict[str, Any] = {}
-
 
 def resolve_priority(
     requested: Optional[str], model_default: Optional[str]
@@ -409,16 +402,11 @@ class ServingController:
         from ..parallel.mesh import bucket_rows
 
         bucket = int(bucket_rows(int(rows)))
-        decision = {
-            "model": name,
-            "rows": int(rows),
-            "bucket": bucket,
-            "pad_rows": bucket - int(rows),
-            "stamp": round(time.time(), 3),
-        }
+        fact(
+            "serving_bucket", model=name, rows=int(rows), bucket=bucket,
+            pad_rows=bucket - int(rows),
+        )
         with self._mu:
-            LAST_BUCKET_DECISION.clear()
-            LAST_BUCKET_DECISION.update(decision)
             st = self._models.setdefault(name, _ModelState())
             if (
                 bucket not in st.buckets
@@ -464,7 +452,6 @@ class ServingController:
 
 __all__ = [
     "BROWNOUT_PHASES",
-    "LAST_BUCKET_DECISION",
     "PRIORITY_CLASSES",
     "ServingController",
     "resolve_priority",

@@ -1420,8 +1420,7 @@ class ServingServer:
         # window sketches HERE — on the dispatcher's collect phase,
         # after the next batch's device work is already in flight, so
         # the device hot path pays nothing (host-tier only, bounded
-        # memory; the fold itself is buffered-amortized — bench `drift`
-        # section measures us/row)
+        # memory; the fold itself is buffered-amortized)
         self._observe_drift(flight, outs)
         # refresh EVERY served model, not just this flight's: a model
         # whose traffic stopped must decay even while the dispatcher

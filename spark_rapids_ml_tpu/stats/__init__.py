@@ -8,7 +8,7 @@
 # (summarizer.py).  See docs/statistics.md for the program contract,
 # the registered-program table and registration how-to.
 #
-from .engine import STAT_METRICS, iter_chunk_accs, run_program, run_programs
+from .engine import iter_chunk_accs, run_program, run_programs
 from .programs import (
     STAT_PROGRAMS,
     Field,
@@ -21,7 +21,6 @@ from .summarizer import SUPPORTED_METRICS, Summarizer, describe, summarize
 
 __all__ = [
     "Field",
-    "STAT_METRICS",
     "STAT_PROGRAMS",
     "SUPPORTED_METRICS",
     "StatProgram",

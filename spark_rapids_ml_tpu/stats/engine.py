@@ -31,18 +31,10 @@ from typing import Any, Callable, Dict, Iterable, Optional, Sequence, Tuple
 import numpy as np
 
 from ..config import get_config
-from ..telemetry.registry import counter, dict_view, histogram
+from ..telemetry.registry import counter, histogram
 from ..utils import get_logger
 
 logger = get_logger("spark_rapids_ml_tpu.stats")
-
-# last engine run (stamped), copied into the fit report's `stats`
-# section and read by bench.py's `summarize` section: programs/chunks/
-# bytes folded, wall + prep/accumulate split, measured overlap
-STAT_METRICS = dict_view(
-    "stat_program_last",
-    "Last statistic-program engine run (programs/chunks/overlap)",
-)
 
 _runs_total = counter(
     "stat_program_runs_total",
@@ -53,15 +45,12 @@ _pass_seconds = histogram(
     "Wall seconds per fused statistic pass by run label",
 )
 
-# STAT_METRICS is process-wide LAST-RUN state: two concurrent passes
-# (a caller running describe() from several threads) must not
-# interleave their clear+update into a chimera of both runs — the
-# writes are ATOMIC under this lock (whichever pass finishes last wins,
-# a consistent single-run view), and a pass that overlapped another —
-# in EITHER direction: every live pass is marked when a new one starts,
-# so the first starter finishing last still knows — records
-# `concurrent_passes` so readers know the engine counters around it are
-# process-level (the PR-5 concurrent-fits report guard, mirrored)
+# Each pass records its own `stats` fact on its run (`tracing.fact`).
+# A pass that overlapped another — in EITHER direction: every live pass
+# is marked when a new one starts, so the first starter finishing last
+# still knows — records `concurrent_passes`, so readers know the engine
+# counters around it are process-level (the PR-5 concurrent-fits report
+# guard, mirrored).  The lock guards the list of live passes.
 _stat_metrics_lock = named_lock("stat_metrics")
 _PASS_STATE: Dict[str, Any] = {"live": []}  # per-pass mutable tokens
 
@@ -314,7 +303,9 @@ def _one_pass(
     from ..telemetry.compile import compile_label
     from ..telemetry.heartbeat import Heartbeat
     from ..telemetry.memory import record_prediction
-    from ..tracing import current_run_id, mint_run_id, run_context
+    from ..tracing import (
+        current_run_id, event, fact, mint_run_id, run_context,
+    )
     from ..utils import prefetch_iter
 
     from .programs import resolve_opts
@@ -506,31 +497,25 @@ def _one_pass(
         for p in progs:
             _runs_total.inc(program=p.name)
         _pass_seconds.observe(wall, label=label)
-        # the clear+update is ATOMIC under the lock: a reader (or the
-        # other pass's writer) sees one complete run's record, never an
-        # interleaving of two (asserted by the concurrent-describe test)
         with _stat_metrics_lock:
             overlapped = pass_token["overlapped"]
-            STAT_METRICS.clear()
-            STAT_METRICS.update(
-                stamp=round(time.time(), 3),
-                label=label,
-                programs=len(progs),
-                passes=1,
-                chunks=n_chunks,
-                bytes=int(nbytes),
-                wall_s=round(wall, 4),
-                host_prep_s=round(prep["s"], 4),
-                device_acc_s=round(acc_s, 4),
-                overlap_s=round(overlap_s, 4),
-                overlap_fraction=round(overlap, 4),
-                **({"concurrent_passes": True} if overlapped else {}),
-            )
+        fact(
+            "stats",
+            label=label,
+            programs=len(progs),
+            passes=1,
+            chunks=n_chunks,
+            bytes=int(nbytes),
+            wall_s=round(wall, 4),
+            host_prep_s=round(prep["s"], 4),
+            device_acc_s=round(acc_s, 4),
+            overlap_s=round(overlap_s, 4),
+            overlap_fraction=round(overlap, 4),
+            **({"concurrent_passes": True} if overlapped else {}),
+        )
     finally:
         with _stat_metrics_lock:
             _PASS_STATE["live"].remove(pass_token)
-    from ..tracing import event
-
     event(
         f"stat_programs[{label}]",
         detail=(

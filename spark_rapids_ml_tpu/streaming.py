@@ -27,12 +27,8 @@ from typing import Iterator, Optional, Sequence, Tuple
 import numpy as np
 
 from .config import get_config
+from .tracing import fact
 from .utils import get_logger
-
-# wall-clock + bandwidth of the most recent stage_parquet (read by
-# bench.py and chip_smoke.py to split fit time into stage vs on-chip
-# solve)
-LAST_STAGE: dict = {}
 
 logger = get_logger("spark_rapids_ml_tpu.streaming")
 
@@ -788,33 +784,24 @@ def stage_parquet(
     jax.block_until_ready(bufX)
     el = time.perf_counter() - t_stage0
     mb = n_padded * d * dtype.itemsize / 1e6
-    LAST_STAGE.clear()
-    LAST_STAGE.update(
-        {"seconds": round(el, 2), "mb": round(mb, 1),
-         "mb_per_s": round(mb / max(el, 1e-9), 1),
-         "engine": (
-             "per-device-parallel" if shares is not None
-             else "per-device" if use_writer else "global-update"
-         ),
-         **({"readers": len(shares)} if shares is not None else {})}
-    )
+    staged = {
+        "label": "parquet",
+        "engine": (
+            "per-device-parallel" if shares is not None
+            else "per-device" if use_writer else "global-update"
+        ),
+        "seconds": round(el, 2),
+        "mb": round(mb, 1),
+        "mb_per_s": round(mb / max(el, 1e-9), 1),
+    }
+    if shares is not None:
+        staged["readers"] = len(shares)
     if use_writer:
-        # engine observability (mirrors mesh.STAGE_METRICS): actual bytes
-        # transferred (padding never travels) + dispatch-side put time
-        LAST_STAGE.update(
-            {"bytes_transferred": int(
-                wX.bytes_written + ww.bytes_written
-                + (wy.bytes_written if wy is not None else 0)
-             ),
-             "pieces": int(
-                wX.pieces + ww.pieces
-                + (wy.pieces if wy is not None else 0)
-             ),
-             "device_put_s": round(
-                wX.put_seconds + ww.put_seconds
-                + (wy.put_seconds if wy is not None else 0.0), 4
-             )}
-        )
+        # what travelled (padding never does) and in how many pieces
+        writers = [w for w in (wX, ww, wy) if w is not None]
+        staged["bytes_transferred"] = sum(w.bytes_written for w in writers)
+        staged["pieces"] = sum(w.pieces for w in writers)
+    fact("staging", **staged)
     logger.info(
         f"Streamed {n_total} rows x {d} cols from {path} in {n_chunks} "
         f"chunks of {chunk_rows} rows onto {mesh} "

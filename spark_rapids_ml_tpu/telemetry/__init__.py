@@ -6,7 +6,7 @@
 #
 #   registry.py   typed process-global metrics registry
 #                 (Counter/Gauge/Histogram with labels, snapshot/reset).
-#                 The legacy dicts — `mesh.STAGE_METRICS`/`STAGE_COUNTS`,
+#                 The legacy dicts — `mesh.STAGE_COUNTS`,
 #                 `device_cache.CACHE_METRICS`,
 #                 `elastic.RECOVERY_METRICS` — are now thin views over it
 #                 (`dict_view`), so every old caller keeps working while

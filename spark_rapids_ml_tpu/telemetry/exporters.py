@@ -107,6 +107,8 @@ def chrome_trace(
         args: Dict[str, Any] = {}
         if e.detail:
             args["detail"] = e.detail
+        if getattr(e, "fields", None):
+            args["fields"] = dict(e.fields)
         if e.run_id:
             args["run_id"] = e.run_id
         # the pod-global pass id (telemetry/fleet.py): the join key a

@@ -85,10 +85,6 @@ _MIN_PLAUSIBLE_TS = 1e9
 
 _clock_samples: Dict[int, Deque[Tuple[float, float]]] = {}
 
-# last completed pass report, for telemetry/report.py's stamp-gated
-# copy (same last-run-state discipline as FUSED_METRICS)
-LAST_PASS_REPORT: Dict[str, Any] = {}
-
 # current pass bookkeeping: id + perf_counter/wall start of the window
 _pass_state: Dict[str, Any] = {}
 
@@ -300,10 +296,11 @@ def complete_pod_pass(run_id: str = "") -> Optional[Dict[str, Any]]:
     seconds, ride them on a `reduce_blob_list` exchange (SPMD — every
     rank reaches this site after the pass reduction), and fold every
     rank's blob into the straggler table all ranks agree on.  Publishes
-    `pod_straggler_seconds{rank,phase}` and stamps `LAST_PASS_REPORT`
-    for the fit report.  A failed exchange (peer died after the main
-    reduce) degrades to a local-only report; never raises."""
-    from ..tracing import set_current_pass_id
+    `pod_straggler_seconds{rank,phase}` and records the report as the
+    run's `pass_report` fact (`tracing.fact`) for the fit report.  A
+    failed exchange (peer died after the main reduce) degrades to a
+    local-only report; never raises."""
+    from ..tracing import fact, set_current_pass_id
 
     with _fleet_lock:
         state = dict(_pass_state)
@@ -363,17 +360,17 @@ def complete_pod_pass(run_id: str = "") -> Optional[Dict[str, Any]]:
         "run_id": run_id,
         "stamp": round(time.time(), 3),
     }
-    with _fleet_lock:
-        LAST_PASS_REPORT.clear()
-        LAST_PASS_REPORT.update(report)
+    fact("pass_report", **report)
     set_current_pass_id("")
     return report
 
 
 def pass_report() -> Dict[str, Any]:
-    """The last completed pass report (stamped), or {}."""
-    with _fleet_lock:
-        return dict(LAST_PASS_REPORT)
+    """The last completed pass report any thread of this process still
+    holds in its trace buffer, or {}."""
+    from ..tracing import last_fact
+
+    return last_fact("pass_report", all_threads=True)
 
 
 # ---------------------------------------------------------------------------
@@ -599,7 +596,6 @@ def reset_fleet() -> None:
     with _fleet_lock:
         _clock_samples.clear()
         _pass_state.clear()
-        LAST_PASS_REPORT.clear()
         _drift_pub_seq.clear()
         _drift_next_seq.clear()
         _drift_latest.clear()
@@ -621,7 +617,6 @@ def on_reinit() -> None:
 
 
 __all__ = [
-    "LAST_PASS_REPORT",
     "begin_pod_pass",
     "clock_offsets",
     "complete_pod_pass",

@@ -29,8 +29,7 @@
 #
 # Recording must stay cheap enough to leave on under serving traffic:
 # one deque append per event plus a 5-second-rate-limited registry
-# snapshot; `measure_overhead()` reports the per-event cost and the
-# bench `serving` section publishes it.
+# snapshot; `measure_overhead()` reports the per-event cost.
 #
 from __future__ import annotations
 
@@ -378,8 +377,7 @@ def note_failure(
 def measure_overhead(n: int = 2000) -> float:
     """Measured per-event recording cost in MICROSECONDS: pushes `n`
     synthetic events through a THROWAWAY FlightRecorder (same code
-    path, same conf reads) and returns the mean.  The bench `serving`
-    section reports this next to the QPS numbers, so 'request tracing
+    path, same conf reads) and returns the mean, so 'request tracing
     ON' stays an accounted cost, not an article of faith.  The live
     RECORDER ring is untouched — flooding the real black box with 2000
     probe events would evict exactly the recent history a post-mortem

@@ -30,9 +30,9 @@
 #
 # One stall EPISODE dumps once: the doctor re-arms only after a progress
 # signal moves again, so a wedged run leaves one bundle, not one per
-# tick.  Tick cost is microseconds (bench `utilization` section reports
-# it); the default 120 s stall threshold keeps long XLA compiles — which
-# emit no trace events while they run — from reading as stalls in CI.
+# tick.  Tick cost is microseconds; the default 120 s stall threshold
+# keeps long XLA compiles — which emit no trace events while they run —
+# from reading as stalls in CI.
 #
 from __future__ import annotations
 

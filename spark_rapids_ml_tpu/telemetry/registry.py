@@ -1,8 +1,8 @@
 #
 # Typed process-global metrics registry — the single surface that absorbs
-# the metric dicts four PRs grew independently (`mesh.STAGE_METRICS` /
-# `STAGE_COUNTS`, `device_cache.CACHE_METRICS`,
-# `elastic.RECOVERY_METRICS`).  Three metric kinds with label support:
+# the metric dicts four PRs grew independently (`mesh.STAGE_COUNTS`,
+# `device_cache.CACHE_METRICS`, `elastic.RECOVERY_METRICS`).  Three
+# metric kinds with label support:
 #
 #   Counter    monotonically increasing (retries, faults injected,
 #              checkpoint saves) — `inc(amount, **labels)`
@@ -14,7 +14,7 @@
 # Values are stored as exact Python numbers (int stays int), so the
 # legacy dict views (`dict_view`) preserve the arithmetic the old
 # module-level dicts had.  `snapshot()` returns a plain nested dict for
-# delta computation (per-fit reports, bench sections); `reset()` zeroes
+# delta computation (per-fit reports); `reset()` zeroes
 # every sample but keeps registrations (and re-seeds view initials).
 # The Prometheus text rendering lives in exporters.py (`dump_prometheus`).
 #
@@ -153,7 +153,6 @@ METRIC_CATALOG: Dict[str, Dict[str, Any]] = {
         "kind": "gauge", "labels": (), "cardinality": 1,
     },
     # legacy dict-view families (gauges labeled by `key`)
-    "staging_last": {"kind": "view", "labels": ("key",), "cardinality": 32},
     "staging_counts": {"kind": "view", "labels": ("key",), "cardinality": 32},
     "device_cache": {"kind": "view", "labels": ("key",), "cardinality": 32},
     # chunk cache (parallel/device_cache.py ChunkCache): hit/miss/spill/
@@ -164,22 +163,15 @@ METRIC_CATALOG: Dict[str, Dict[str, Any]] = {
     # pod rank-loss recovery (resilience/pod.py): losses detected,
     # shares reassigned, recoveries, bounded-wait expiries, generation
     "pod_recovery": {"kind": "view", "labels": ("key",), "cardinality": 16},
-    "fused_last": {"kind": "view", "labels": ("key",), "cardinality": 32},
-    "pca_solver_last": {"kind": "view", "labels": ("key",), "cardinality": 16},
     # statistic-program engine (stats/engine.py): executions per
     # registered program, wall seconds per fused multi-program pass
     # (labeled by the run's caller-facing label — summarize / describe /
-    # estimator names, a fixed vocabulary), and the last-run state the
-    # fit report's `stats` section and bench.py's `summarize` section
-    # copy
+    # estimator names, a fixed vocabulary)
     "stat_program_runs_total": {
         "kind": "counter", "labels": ("program",), "cardinality": 64,
     },
     "stat_program_pass_seconds": {
         "kind": "histogram", "labels": ("label",), "cardinality": 32,
-    },
-    "stat_program_last": {
-        "kind": "view", "labels": ("key",), "cardinality": 32,
     },
     # drift monitor (monitor/): per-model divergence gauges, bounded to
     # the `drift_top_k` highest-scoring columns per model (stale column
@@ -574,8 +566,8 @@ class MetricsRegistry:
         self, name: str, help: str = "", initial: Optional[dict] = None
     ) -> DictView:
         """A legacy-dict facade over a gauge family labeled ``key``.
-        Idempotent per name: a repeat call (module reload, a test
-        re-importing bench.py) returns the SAME view with any new
+        Idempotent per name: a repeat call (module reload) returns the
+        SAME view with any new
         initial keys merged non-destructively — live counters are never
         zeroed and the view table stays bounded."""
         metric = self._register(name, "gauge", help)
@@ -629,7 +621,7 @@ def delta(
     before: Dict[str, Dict[str, Any]], after: Dict[str, Dict[str, Any]]
 ) -> Dict[str, Dict[str, Any]]:
     """Numeric per-sample change between two `snapshot()`s, keeping only
-    samples that moved (per-fit reports, bench section telemetry).
+    samples that moved (per-fit reports).
     Histogram samples diff their {"sum", "count"} pair."""
     out: Dict[str, Dict[str, Any]] = {}
     for name, fam in after.items():

@@ -56,6 +56,8 @@ def span_tree(events: List[Any]) -> List[Dict[str, Any]]:
         }
         if e.detail:
             node["detail"] = e.detail
+        if getattr(e, "fields", None) is not None:
+            node["fields"] = dict(e.fields)
         if getattr(e, "kind", "span") == "instant":
             node["instant"] = True
         node["children"] = []
@@ -132,19 +134,23 @@ def _view_delta(d: Dict[str, Dict[str, Any]], family: str) -> Dict[str, Any]:
 class FitTelemetry:
     """The per-fit observability scope `core.Estimator.fit` wraps every
     fit in: mints the run id, opens the root `fit[<Est>]` span, and after
-    the fit builds the report dict from the run's spans plus registry
-    deltas.
+    the fit builds the report dict from the run's own events (spans,
+    resilience markers, and the facts its subsystems recorded with
+    `tracing.fact`) plus registry deltas.
 
+    The run's events are exact: two fits that overlap each report their
+    own `staging`, `fused`, `stats`, `solver_decision`, `pass_report`.
     The registry deltas are process-global: when fits OVERLAP (a caller
     pulling `fitMultiple` from several threads), each report's
-    staging/cache/recovery sections include the concurrent fits'
-    activity too — the report then carries `"concurrent_fits": true` so
-    the numbers are read as process-level, not per-fit.  The span tree
-    and resilience marker counts stay exact (run-id filtered)."""
+    staging counts and cache/recovery/lock/compile-seconds sections
+    include the concurrent fits' activity too — the report then carries
+    `"concurrent_fits": true` so those numbers are read as
+    process-level, not per-fit."""
 
-    # fits currently inside span(); >1 means the registry deltas span
-    # more than this fit
-    _active = 0
+    # fits currently inside span(): a fit that starts while others are
+    # open marks them and itself, so the first starter finishing last
+    # still knows its registry deltas span more than this fit
+    _live: List["FitTelemetry"] = []
     _active_lock = named_lock("fit_telemetry_active")
 
     def __init__(self, estimator_name: str) -> None:
@@ -177,8 +183,9 @@ class FitTelemetry:
         self._t0 = time.time()
         cls = FitTelemetry
         with cls._active_lock:
-            cls._active += 1
-            self._overlapped = cls._active > 1
+            for other in cls._live:
+                other._overlapped = self._overlapped = True
+            cls._live.append(self)
         self._watermark = FitMemoryWatermark(self.run_id, self.estimator)
         self._watermark.open()
         try:
@@ -190,8 +197,7 @@ class FitTelemetry:
                         yield self
         finally:
             with cls._active_lock:
-                self._overlapped = self._overlapped or cls._active > 1
-                cls._active -= 1
+                cls._live.remove(self)
             self._watermark.close()
         self._t1 = time.time()
 
@@ -319,149 +325,35 @@ class FitTelemetry:
         wall = max(self._t1 - self._t0, 0.0)
         _fit_seconds.observe(wall, estimator=self.estimator)
 
-        staging: Dict[str, Any] = _view_delta(deltas, "staging_counts")
-        # the staging engine's throughput numbers are process-wide
-        # LAST-RUN state: copy them only when that run completed inside
-        # this fit's window (the `stamp` key) and no OTHER fit overlapped
-        # it — a cache-served / serial-path / concurrent fit must not
-        # inherit someone else's bytes and MB/s
-        try:
-            from ..parallel.mesh import STAGE_METRICS
-
-            if (
-                not self._overlapped
-                and STAGE_METRICS.get("stamp", 0) >= self._t0
-            ):
-                for k in ("bytes", "mb_per_s", "overlap_ratio", "pieces",
-                          "pieces_viewed"):
-                    v = STAGE_METRICS.get(k)
-                    if v is not None:
-                        staging[k] = v
-        except Exception:
-            pass
-
-        # fused stage-and-solve metrics (fused.py FUSED_METRICS): same
-        # last-run-state discipline as STAGE_METRICS — copy only when the
-        # fused pass completed inside this fit's window and no other fit
-        # overlapped; likewise the PCA solver decision (ops/pca.py)
-        fused: Dict[str, Any] = {}
-        solver_decision: Dict[str, Any] = {}
-        try:
-            from ..fused import FUSED_METRICS
-
-            if (
-                not self._overlapped
-                and FUSED_METRICS.get("stamp", 0) >= self._t0
-            ):
-                fused = {
-                    k: FUSED_METRICS.get(k)
-                    for k in (
-                        "kind", "solver", "passes", "chunks", "bytes",
-                        "wall_s", "host_prep_s", "device_acc_s",
-                        "overlap_s", "overlap_fraction",
-                    )
-                    if FUSED_METRICS.get(k) is not None
-                }
-        except Exception:
-            pass
-        # statistic-program engine metrics (stats/engine.py
-        # STAT_METRICS): same last-run-state discipline — a fused
-        # multi-program pass that completed inside this fit's window
-        # lands as the report's `stats` section
-        stats_section: Dict[str, Any] = {}
-        try:
-            from ..stats.engine import STAT_METRICS
-
-            if (
-                not self._overlapped
-                and STAT_METRICS.get("stamp", 0) >= self._t0
-            ):
-                stats_section = {
-                    k: STAT_METRICS.get(k)
-                    for k in (
-                        "label", "programs", "passes", "chunks", "bytes",
-                        "wall_s", "host_prep_s", "device_acc_s",
-                        "overlap_s", "overlap_fraction",
-                    )
-                    if STAT_METRICS.get(k) is not None
-                }
-        except Exception:
-            pass
-        try:
-            from ..ops.pca import LAST_SOLVER_DECISION
-
-            if (
-                not self._overlapped
-                and LAST_SOLVER_DECISION.get("stamp", 0) >= self._t0
-            ):
-                solver_decision = {
-                    k: LAST_SOLVER_DECISION.get(k)
-                    for k in ("solver", "reason", "d", "k", "l", "power_iters")
-                    if LAST_SOLVER_DECISION.get(k) is not None
-                }
-        except Exception:
-            pass
-        # parallel parquet-reader decision (fused.resolve_parquet_readers):
-        # same last-run-state discipline — "why did this fit decode with
-        # N readers" is part of the solver_decision story
-        try:
-            from ..fused import LAST_READER_DECISION
-
-            if (
-                not self._overlapped
-                and LAST_READER_DECISION.get("stamp", 0) >= self._t0
-            ):
-                solver_decision.update({
-                    k: LAST_READER_DECISION[k]
-                    for k in (
-                        "parquet_readers", "parquet_readers_mode",
-                        "parquet_readers_reason",
-                    )
-                    if LAST_READER_DECISION.get(k) is not None
-                })
-        except Exception:
-            pass
-        # serving padding-class decision (serving/control.py): which
-        # {1,1.5}x2^k bucket the last coalesced micro-batch padded to —
-        # same last-run-state discipline, prefixed so the serving keys
-        # never collide with the solver/reader keys above
-        try:
-            from ..serving.control import LAST_BUCKET_DECISION
-
-            if (
-                not self._overlapped
-                and LAST_BUCKET_DECISION.get("stamp", 0) >= self._t0
-            ):
-                solver_decision.update({
-                    f"serving_{k}": LAST_BUCKET_DECISION[k]
-                    for k in ("model", "rows", "bucket")
-                    if LAST_BUCKET_DECISION.get(k) is not None
-                })
-        except Exception:
-            pass
-        # pod pass report (telemetry/fleet.py LAST_PASS_REPORT): the
-        # straggler table of the last pod-correlated pass — same
-        # last-run-state discipline, so a report only claims a pass
-        # that completed inside its own window
-        pass_report: Dict[str, Any] = {}
-        try:
-            from . import fleet as _fleet
-
-            rep = _fleet.pass_report()
-            if (
-                not self._overlapped
-                and rep.get("stamp", 0) >= self._t0
-            ):
-                pass_report = rep
-        except Exception:
-            pass
+        # what the subsystems recorded for THIS run (`tracing.fact`): the
+        # last fact of a section stands
+        facts: Dict[str, Dict[str, Any]] = {
+            e.name[len("fact["):-1]: e.fields
+            for e in events
+            if getattr(e, "fields", None) is not None
+        }
+        staging: Dict[str, Any] = {
+            **_view_delta(deltas, "staging_counts"),
+            **facts.get("staging", {}),
+        }
+        # the three decisions a fit can take on the way: the PCA solver,
+        # the parquet reader count, and a serving dispatch's padding
+        # class (prefixed, so its keys never collide with the others')
+        solver_decision: Dict[str, Any] = {
+            **facts.get("pca_solver", {}),
+            **facts.get("parquet_readers", {}),
+            **{
+                f"serving_{k}": v
+                for k, v in facts.get("serving_bucket", {}).items()
+            },
+        }
 
         report: Dict[str, Any] = {
             "run_id": self.run_id,
             "estimator": self.estimator,
             # set when another fit overlapped this one: the registry
             # deltas below then include the concurrent fits' activity
-            # (span tree / marker counts stay run-exact)
+            # (what is built from the run's events stays run-exact)
             **({"concurrent_fits": True} if self._overlapped else {}),
             "t0": round(self._t0, 6),
             "t1": round(self._t1, 6),
@@ -504,12 +396,9 @@ class FitTelemetry:
         chunk_cache = _view_delta(deltas, "chunk_cache")
         if any(chunk_cache.values()):
             report["chunk_cache"] = chunk_cache
-        if fused:
-            report["fused"] = fused
-        if stats_section:
-            report["stats"] = stats_section
-        if pass_report:
-            report["pass_report"] = pass_report
+        for section in ("fused", "stats", "pass_report"):
+            if facts.get(section):
+                report[section] = dict(facts[section])
         if solver_decision:
             report["solver_decision"] = solver_decision
         if self._watermark is not None:

@@ -22,8 +22,8 @@
 #       `unattributed`).
 #
 # Consumers: the fit report's new `utilization` section
-# (telemetry/report.py), `ServingServer.report()`'s `_totals`
-# utilization block, and the bench `utilization` section.  The
+# (telemetry/report.py) and `ServingServer.report()`'s `_totals`
+# utilization block.  The
 # `device_busy_fraction{scope}` gauge feeds the planned SLO controller
 # (ROADMAP item 2) its missing utilization sensor.
 #

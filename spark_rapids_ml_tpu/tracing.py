@@ -26,7 +26,7 @@ import threading
 import time
 import uuid
 from dataclasses import dataclass
-from typing import Callable, Iterator, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 from .config import get_config
 from .utils import get_logger
@@ -59,6 +59,9 @@ class TraceEvent:
     # over the KV seam, so the SAME id lands on every rank's spans) —
     # the cross-rank correlation key a merged pod trace is joined on
     pass_id: str = ""
+    # a FACT's mapping (`fact()`): what a subsystem did for this run,
+    # read by the fit report; None on every other event
+    fields: Optional[Dict[str, Any]] = None
 
 
 # every thread's record list, registered once at creation so the
@@ -115,7 +118,17 @@ def remove_trace_tap(fn: Callable[[TraceEvent], None]) -> None:
 def _append(event: TraceEvent) -> None:
     rec = _records()
     if len(rec) >= MAX_EVENTS:
-        del rec[: MAX_EVENTS // 2]  # drop the oldest half
+        # drop the oldest half, less the running run's facts: each was
+        # recorded once and the fit report is built from it.  The last of
+        # each name stays, so what is kept is bounded by the names.
+        half = MAX_EVENTS // 2
+        run_id = getattr(_tls, "run_id", "")
+        kept = {
+            e.name: e
+            for e in (rec[:half] if run_id else ())
+            if e.fields is not None and e.run_id == run_id
+        }
+        rec[:half] = list(kept.values())
     rec.append(event)
     for tap in _taps:
         try:
@@ -253,7 +266,12 @@ def summarize() -> str:
     return "\n".join(lines)
 
 
-def event(name: str, detail: str = "", log: Optional[object] = None) -> None:
+def event(
+    name: str,
+    detail: str = "",
+    log: Optional[object] = None,
+    fields: Optional[Dict[str, Any]] = None,
+) -> None:
     """Record an INSTANTANEOUS event (zero-duration TraceEvent) — failure/
     recovery markers from the resilience layer: retries, injected faults,
     dispatch timeouts, checkpoint resumes.  Stamped with the active run
@@ -273,11 +291,47 @@ def event(name: str, detail: str = "", log: Optional[object] = None) -> None:
             run_id=getattr(_tls, "run_id", ""),
             kind="instant",
             pass_id=_current_pass_id,
+            fields=fields,
         )
     )
     if int(get_config("verbose") or 0) >= 1:
-        suffix = f" [{detail}]" if detail else ""
+        suffix = f" [{detail or fields}]" if detail or fields else ""
         (log or logger).info(f"[trace] {'  ' * depth}{name}{suffix}")
+
+
+def fact(section: str, **fields: Any) -> None:
+    """Record what a subsystem did for the active run, once, where it
+    knows it: an instant `fact[<section>]` carrying `fields`.  This is
+    the one way a number reaches a fit report (`telemetry/report.py`
+    reads the run's facts by `run_id`; the last of a section stands), so
+    the report holds this run's own and a run elsewhere in the process
+    cannot reach it.  Outside any run the fact still lands in the
+    thread's buffer (`last_fact`)."""
+    event(f"fact[{section}]", fields=fields)
+
+
+def last_fact(
+    section: str, run_id: Optional[str] = None, all_threads: bool = False
+) -> Dict[str, Any]:
+    """The fields of the newest `fact[<section>]` in this thread's buffer
+    (every thread's with `all_threads`), of `run_id` alone when given;
+    {} when there is none."""
+    name = f"fact[{section}]"
+    if all_threads:
+        with _buffers_lock:
+            bufs = [rec for _, _, rec in _buffers]
+    else:
+        bufs = [_records()]
+    newest: Optional[TraceEvent] = None
+    for rec in bufs:
+        for e in reversed(list(rec)):
+            if e.name == name and e.fields is not None and (
+                run_id is None or e.run_id == run_id
+            ):
+                if newest is None or e.t0 > newest.t0:
+                    newest = e
+                break
+    return dict(newest.fields) if newest is not None else {}
 
 
 @contextlib.contextmanager

@@ -128,101 +128,6 @@ def test_pod_launcher_two_process(tmp_path):
     assert "kmeans" in content and "inertia" in content
 
 
-def test_bench_refconfig_cpu_smoke(monkeypatch):
-    """The refconfig workload (bench.py's 1:1 reference-config matrix) is
-    chip-gated by default; this smoke exercises the whole path at toy
-    scale via the BENCH_REFCONFIG_CPU escape hatch so the code cannot rot
-    between chip runs.  All 7 workloads must
-    produce a *_fit_sec + *_vs_a10g_x pair, no *_error keys."""
-    monkeypatch.setenv("BENCH_REFCONFIG_CPU", "1")
-    monkeypatch.setenv("BENCH_REF_ROWS", "400")
-    monkeypatch.setenv("BENCH_REF_COLS", "16")
-    import importlib
-
-    import bench
-
-    importlib.reload(bench)  # re-read the env-driven sizes
-    extra = {}
-    bench.bench_refconfig(extra)
-    errors = {k: v for k, v in extra.items() if k.endswith("_error")}
-    assert not errors, errors
-    for name in ("pca", "logreg", "linreg", "kmeans",
-                 "ridge", "elasticnet", "rf_clf"):
-        # a scaled run must label keys with the REAL shape and emit no
-        # vs_a10g_x ratio (those belong to the 1:1 1Mx3000 config only)
-        assert f"refconfig_{name}_400x16_scaled_fit_sec" in extra, name
-        assert f"refconfig_{name}_vs_a10g_x" not in extra, name
-
-
-def test_bench_isolated_supervisor(tmp_path):
-    """bench.py's process-per-workload supervisor (one kmeans
-    RESOURCE_EXHAUSTED once turned every later in-process workload into
-    an error — isolation gives each workload a fresh client).  Two tiny
-    workloads + the auto-appended logreg must merge into ONE JSON line
-    carrying all three workloads' keys, the headline from the logreg
-    child, and the isolation marker.  The supervisor itself must never
-    initialise a jax backend: a chip belongs to one process at a time,
-    and its children need it one after another."""
-    import json
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env.update(
-        JAX_PLATFORMS="cpu", BENCH_WORKLOADS="pca,knn",
-        BENCH_ROWS="5000", BENCH_COLS="16", BENCH_WORKLOAD_TIMEOUT="300",
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=900, env=env, cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = [ln for ln in proc.stdout.splitlines() if ln.strip()][-1]
-    result = json.loads(line)
-    extra = result["extra"]
-    assert extra.get("isolation") == "process-per-workload"
-    assert extra.get("supervisor_backend_initialized") is False
-    errors = {k: v for k, v in extra.items() if k.endswith("_error")}
-    assert not errors, errors
-    assert any(k.startswith("pca_") for k in extra), sorted(extra)
-    assert any(k.startswith("knn_") for k in extra), sorted(extra)
-    assert result["value"] > 0  # the logreg child's headline merged
-
-
-def test_bench_total_budget_skips_and_exits_zero(tmp_path):
-    """A run that overruns its external budget loses the tail of the
-    matrix to rc=124: with BENCH_TOTAL_BUDGET set, bench.py must skip
-    sections that
-    no longer fit, still emit ONE valid JSON line recording every skip,
-    exit 0, and leave the partial-JSON flush file behind."""
-    import json
-    import subprocess
-    import sys
-
-    partial = str(tmp_path / "partial.json")
-    env = dict(os.environ)
-    env.update(
-        JAX_PLATFORMS="cpu", BENCH_WORKLOADS="pca,kmeans",
-        BENCH_ROWS="5000", BENCH_COLS="16",
-        BENCH_TOTAL_BUDGET="5",  # < one section: everything skips
-        BENCH_PARTIAL_PATH=partial,
-    )
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=300, env=env, cwd=REPO,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = [ln for ln in proc.stdout.splitlines() if ln.strip()][-1]
-    result = json.loads(line)
-    extra = result["extra"]
-    assert extra.get("total_budget_s") == 5.0
-    for name in ("pca", "kmeans", "logreg"):
-        assert "budget exhausted" in extra.get(f"{name}_error", ""), name
-    with open(partial) as f:
-        flushed = json.load(f)
-    assert "pca_error" in flushed["extra"]
-
-
 def test_rehearsal_pod_phase_smoke(tmp_path):
     """benchmark/rehearsal_100m.py's 2-process pod phase at toy scale:
     2-process streaming fit must match the

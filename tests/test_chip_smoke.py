@@ -318,21 +318,6 @@ def test_unpinned_measurement_without_a_tpu_exits(monkeypatch, capsys):
     assert "t: no TPU" in capsys.readouterr().err
 
 
-def test_bench_supervisor_takes_its_platform_from_the_first_child(tmp_path):
-    import bench
-
-    out = tmp_path / "child.out"
-    extra: dict = {}
-    out.write_text(json.dumps({"value": 0, "extra": {
-        "platform": "tpu x1", "pca_fit_sec": 1.0, "isolation": "child's"}}))
-    assert bench._merge_child_line(extra, str(out), "pca")
-    out.write_text(json.dumps({"value": 0, "extra": {
-        "platform": "cpu x1", "knn_qps": 2.0}}))
-    assert bench._merge_child_line(extra, str(out), "knn")
-    # first child's label, every child's numbers, none of their metadata
-    assert extra == {"platform": "tpu x1", "pca_fit_sec": 1.0, "knn_qps": 2.0}
-
-
 def test_pod_launcher_refuses_local_emulation_on_tpus(capsys):
     from benchmark.pod import launch
 

@@ -291,7 +291,8 @@ def test_parallel_stage_parquet_byte_parity(tmp_path, rng):
     """readers=3 range readers writing at global offsets must assemble
     the exact buffer the single in-order scan does."""
     from spark_rapids_ml_tpu.parallel.mesh import fetch_replicated
-    from spark_rapids_ml_tpu.streaming import LAST_STAGE, stage_parquet
+    from spark_rapids_ml_tpu.streaming import stage_parquet
+    from spark_rapids_ml_tpu.tracing import last_fact
 
     n, d = 3203, 6
     X = rng.normal(size=(n, d)).astype(np.float32)
@@ -300,11 +301,11 @@ def test_parallel_stage_parquet_byte_parity(tmp_path, rng):
 
     set_config(fused_parquet_readers=1, chunk_cache="off")
     ds1 = stage_parquet(path, label_col="label", dtype=np.float32)
-    assert LAST_STAGE["engine"] == "per-device"
+    assert last_fact("staging")["engine"] == "per-device"
     set_config(fused_parquet_readers=3)
     ds3 = stage_parquet(path, label_col="label", dtype=np.float32)
-    assert LAST_STAGE["engine"] == "per-device-parallel"
-    assert LAST_STAGE["readers"] == 3
+    assert last_fact("staging")["engine"] == "per-device-parallel"
+    assert last_fact("staging")["readers"] == 3
     for a, b in ((ds1.X, ds3.X), (ds1.y, ds3.y), (ds1.weight, ds3.weight)):
         np.testing.assert_array_equal(
             fetch_replicated(a, ds1.mesh), fetch_replicated(b, ds3.mesh)
@@ -317,20 +318,19 @@ def test_auto_readers_resolve_and_report(tmp_path, rng):
     solver_decision section."""
     import os
 
-    from spark_rapids_ml_tpu.fused import (
-        LAST_READER_DECISION,
-        resolve_parquet_readers,
-    )
+    from spark_rapids_ml_tpu.fused import resolve_parquet_readers
+    from spark_rapids_ml_tpu.tracing import last_fact
 
     n = resolve_parquet_readers()
     assert 1 <= n <= 16
-    assert LAST_READER_DECISION["parquet_readers_mode"] == "auto"
+    decision = last_fact("parquet_readers")
+    assert decision["parquet_readers_mode"] == "auto"
     assert f"cpu_count={os.cpu_count() or 1}" in (
-        LAST_READER_DECISION["parquet_readers_reason"]
+        decision["parquet_readers_reason"]
     )
     set_config(fused_parquet_readers=5)
     assert resolve_parquet_readers() == 5
-    assert LAST_READER_DECISION["parquet_readers_mode"] == "explicit"
+    assert last_fact("parquet_readers")["parquet_readers_mode"] == "explicit"
     set_config(fused_parquet_readers="auto")
 
     from spark_rapids_ml_tpu.regression import LinearRegression

@@ -551,6 +551,16 @@ _COMMON_PRELUDE = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     sys.path.insert(0, os.environ["SRMT_REPO"])
     import numpy as np
+    # pyarrow is first imported HERE, on the main thread.  The package
+    # imports it lazily inside the chunk generators, which run on a
+    # prefetch thread that ends with its pass; with pyarrow 25 the next
+    # ParquetFile opened more than ~0.1 s after the thread of its first
+    # import has exited dies by SIGSEGV (shown outside this repo by two
+    # threads, one after the other, each opening a file).  The retried
+    # pass of a chaos run is such an open, and it lost rank 0 whenever
+    # the machine was busy.  Every real entry point has touched pyarrow
+    # on a long-lived thread by then (pandas, a metadata read).
+    import pyarrow  # noqa: F401
     from spark_rapids_ml_tpu import init_distributed
     from spark_rapids_ml_tpu.config import set_config
     """

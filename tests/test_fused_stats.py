@@ -9,7 +9,6 @@ import pytest
 
 from spark_rapids_ml_tpu.config import reset_config, set_config
 from spark_rapids_ml_tpu.feature import PCA
-from spark_rapids_ml_tpu.fused import FUSED_METRICS
 from spark_rapids_ml_tpu.regression import LinearRegression
 
 
@@ -51,15 +50,14 @@ def test_fused_pca_matches_two_phase(rng):
     set_config(fused_stage_solve="off", pca_solver="full")
     m_ref = PCA(k=3).setInputCol("features").fit(X)
     set_config(fused_stage_solve="on")
-    stamp0 = FUSED_METRICS.get("stamp", 0)
+    assert "fused" not in m_ref.fit_report()
     m_fused = PCA(k=3).setInputCol("features").fit(X)
-    assert FUSED_METRICS.get("stamp", 0) > stamp0, "fused path did not run"
-    assert FUSED_METRICS["kind"] == "pca_moments"
-    assert FUSED_METRICS["chunks"] >= 2
-    _assert_pca_parity(m_fused, m_ref)
     # the fit report carries the fused section (overlap + solver keys)
     rep = m_fused.fit_report()
-    assert rep and "fused" in rep
+    assert rep and "fused" in rep, "fused path did not run"
+    assert rep["fused"]["kind"] == "pca_moments"
+    assert rep["fused"]["chunks"] >= 2
+    _assert_pca_parity(m_fused, m_ref)
     assert "overlap_fraction" in rep["fused"]
 
 
@@ -77,7 +75,7 @@ def test_fused_linreg_matches_two_phase(rng):
     m_ref = LinearRegression(**kw).setWeightCol("w").fit(df)
     set_config(fused_stage_solve="on")
     m_fused = LinearRegression(**kw).setWeightCol("w").fit(df)
-    assert FUSED_METRICS["kind"] == "linreg"
+    assert m_fused.fit_report()["fused"]["kind"] == "linreg"
     np.testing.assert_allclose(
         np.asarray(m_fused.coefficients), np.asarray(m_ref.coefficients),
         atol=1e-4,
@@ -154,10 +152,9 @@ def test_randomized_vs_full_parity_across_settings(rng):
     for solver in ("full", "randomized", "auto"):
         set_config(pca_solver=solver, fused_stage_solve="off")
         models[solver] = PCA(k=3).setInputCol("features").fit(X)
-    from spark_rapids_ml_tpu.ops.pca import LAST_SOLVER_DECISION
-
     # auto at d=256, k=3, l=13, p=2: threshold 4*13*4=208 <= 256
-    assert LAST_SOLVER_DECISION["solver"] == "randomized"
+    decision = models["auto"].fit_report()["solver_decision"]
+    assert decision["solver"] == "randomized"
     _assert_pca_parity(models["randomized"], models["full"], ev_rtol=0.01)
     _assert_pca_parity(models["auto"], models["full"], ev_rtol=0.01)
     # ratios stay exact: total variance comes from the true trace, not
@@ -210,10 +207,11 @@ def test_fused_randomized_stage_overlapped(rng):
     m_res = PCA(k=3).setInputCol("features").fit(X)
     set_config(fused_stage_solve="on")
     m_fused = PCA(k=3).setInputCol("features").fit(X)
-    assert FUSED_METRICS["kind"] == "pca_projected"
-    assert FUSED_METRICS["solver"] == "randomized"
+    fused = m_fused.fit_report()["fused"]
+    assert fused["kind"] == "pca_projected"
+    assert fused["solver"] == "randomized"
     # 2 + power_iters passes over the source
-    assert FUSED_METRICS["passes"] == 4
+    assert fused["passes"] == 4
     _assert_pca_parity(m_fused, m_res, ev_rtol=0.01)
 
 
@@ -325,25 +323,23 @@ def test_stats_precision_rejects_unknown_level():
 
 def test_fused_eligibility_gates(rng):
     X = _structured(rng, n=3000, d=8)
+    def fused_by(X):
+        return "fused" in PCA(k=2).setInputCol("features").fit(X).fit_report()
+
     set_config(fused_stage_solve="off")
-    stamp0 = FUSED_METRICS.get("stamp", 0)
-    PCA(k=2).setInputCol("features").fit(X)
-    assert FUSED_METRICS.get("stamp", 0) == stamp0, "off must not fuse"
+    assert not fused_by(X), "off must not fuse"
     # auto below the byte floor keeps the two-phase path
     set_config(fused_stage_solve="auto")
-    PCA(k=2).setInputCol("features").fit(X)
-    assert FUSED_METRICS.get("stamp", 0) == stamp0
+    assert not fused_by(X)
     # sparse batches keep the two-phase/CSR paths
     import scipy.sparse as sp
 
     set_config(fused_stage_solve="on")
     Xs = sp.random(2000, 8, density=0.2, format="csr", dtype=np.float32,
                    random_state=0)
-    PCA(k=2).setInputCol("features").fit(Xs)
-    assert FUSED_METRICS.get("stamp", 0) == stamp0
+    assert not fused_by(Xs)
     # dense + on engages
-    PCA(k=2).setInputCol("features").fit(X)
-    assert FUSED_METRICS.get("stamp", 0) > stamp0
+    assert fused_by(X)
     set_config(fused_stage_solve="bogus")
     from spark_rapids_ml_tpu.fused import fused_mode
 
@@ -365,7 +361,7 @@ def test_fused_fault_restarts_pass_without_double_count(rng):
         fused_stage_solve="on", retry_backoff_s=0.01, retry_jitter=0.0
     )
     m_clean = PCA(k=3).setInputCol("features").fit(X)
-    chunks_clean = FUSED_METRICS["chunks"]
+    chunks_clean = m_clean.fit_report()["fused"]["chunks"]
     retries = REGISTRY.get("retries_total")
     before = retries.value(default=0, label="fused_fit", action="oom")
     with fault_inject("fused_accumulate", "oom", times=1, skip=2):
@@ -376,7 +372,7 @@ def test_fused_fault_restarts_pass_without_double_count(rng):
     )
     # the retried pass re-ran from chunk 0: same chunk count, identical
     # statistics
-    assert FUSED_METRICS["chunks"] == chunks_clean
+    assert m_faulted.fit_report()["fused"]["chunks"] == chunks_clean
     _assert_pca_parity(m_faulted, m_clean, ev_rtol=1e-6, dot_min=0.99999)
     np.testing.assert_allclose(
         m_faulted.singular_values_, m_clean.singular_values_, rtol=1e-6

@@ -559,6 +559,9 @@ _CHAOS_WORKER = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     sys.path.insert(0, os.environ["SRMT_REPO"])
     import numpy as np
+    # on the main thread, before any prefetch thread can be the first to
+    # import it: see tests/test_fleet_observatory.py _COMMON_PRELUDE
+    import pyarrow  # noqa: F401
     from spark_rapids_ml_tpu import init_distributed
     from spark_rapids_ml_tpu.config import set_config
     set_config(

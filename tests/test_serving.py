@@ -775,8 +775,7 @@ def test_sustained_overload_leaves_postmortem(pca_model, rng, tmp_path):
 @pytest.mark.slow
 def test_coalesced_qps_beats_sequential_3x(logreg_model, rng):
     """At batchable load (many tiny concurrent requests) the coalesced
-    server must beat sequential per-request transforms by >= 3x QPS —
-    the acceptance bar the bench section tracks longitudinally."""
+    server must beat sequential per-request transforms by >= 3x QPS."""
     n = 200
     rows = [_q(rng, 1) for _ in range(n)]
     # sequential per-request baseline: each row pays the full chunked

@@ -27,10 +27,10 @@ from spark_rapids_ml_tpu.serving import (
 )
 from spark_rapids_ml_tpu.serving.control import (
     BROWNOUT_PHASES,
-    LAST_BUCKET_DECISION,
     PRIORITY_CLASSES,
     resolve_priority,
 )
+from spark_rapids_ml_tpu.tracing import last_fact
 
 
 @pytest.fixture(autouse=True)
@@ -411,8 +411,9 @@ def test_live_spike_sheds_batch_then_recovers(pca_model, rng, tmp_path):
 def test_padding_buckets_reuse_compiled_program(pca_model, rng):
     """Churning request sizes inside one {1,1.5}x2^k bucket stage to
     the SAME padded shape: zero new backend compiles after warmup (the
-    jit-audit guarantee extended to serving), the decision lands in
-    LAST_BUCKET_DECISION, and the report lists the padding class."""
+    jit-audit guarantee extended to serving), the decision is the
+    dispatch's `serving_bucket` fact, and the report lists the padding
+    class."""
     from spark_rapids_ml_tpu.parallel.mesh import bucket_rows
     from spark_rapids_ml_tpu.telemetry import delta, snapshot
     from spark_rapids_ml_tpu.telemetry.compile import install_jax_listener
@@ -429,10 +430,12 @@ def test_padding_buckets_reuse_compiled_program(pca_model, rng):
             assert out["proj"].shape == (n, 3)  # padding trimmed
         d = delta(before, snapshot())
         assert not d.get("compiles_total"), d.get("compiles_total")
-        assert LAST_BUCKET_DECISION["model"] == "pad"
-        assert LAST_BUCKET_DECISION["rows"] == 255
-        assert LAST_BUCKET_DECISION["bucket"] == bucket_rows(255)
-        assert LAST_BUCKET_DECISION["stamp"] > 0
+        # recorded on the dispatch thread, not this one
+        decision = last_fact("serving_bucket", all_threads=True)
+        assert decision["model"] == "pad"
+        assert decision["rows"] == 255
+        assert decision["bucket"] == bucket_rows(255)
+        assert decision["pad_rows"] == bucket_rows(255) - 255
         rep = server.report()["pad"]
         assert bucket_rows(255) in rep["controller"]["padding_classes"]
     finally:
@@ -441,12 +444,13 @@ def test_padding_buckets_reuse_compiled_program(pca_model, rng):
 
 def test_padding_buckets_off_stages_exact(pca_model, rng):
     set_config(serving_padding_buckets=False)
-    LAST_BUCKET_DECISION.clear()
     server = _serve(nopad=pca_model)
     try:
         out = server.transform("nopad", _q(rng, 5), timeout=60)
         assert out["proj"].shape == (5, 3)
-        assert LAST_BUCKET_DECISION == {}  # no decision recorded
+        # no decision recorded for this model, on any thread
+        decision = last_fact("serving_bucket", all_threads=True)
+        assert decision.get("model") != "nopad"
         assert server.report()["nopad"]["controller"]["padding_classes"] == []
     finally:
         server.stop()

@@ -77,7 +77,7 @@ def test_dbscan_tiled_path_at_scale(rng):
     """60k rows with a small max_mbytes_per_batch forces the tiled
     adjacency recompute (the N^2/p working set would be ~11 GB untiled);
     cluster structure must survive.  Scaled for the CPU-mesh nightly —
-    the same path covers 1M+ rows on chip (see bench.py dbscan notes)."""
+    the same path covers 1M+ rows on chip."""
     from sklearn.datasets import make_blobs
 
     from spark_rapids_ml_tpu.clustering import DBSCAN

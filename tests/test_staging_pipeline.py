@@ -14,13 +14,20 @@ import jax
 import spark_rapids_ml_tpu.parallel.mesh as mesh_mod
 from spark_rapids_ml_tpu.config import reset_config, set_config
 from spark_rapids_ml_tpu.parallel.mesh import (
-    STAGE_METRICS,
     RowStager,
     ShardedRowWriter,
     _writer_devices,
     assemble_rows_chunked,
     get_mesh,
 )
+
+
+def _staged() -> dict:
+    """The last staging's own record: its `staging` fact, read from this
+    thread's trace buffer."""
+    from spark_rapids_ml_tpu.tracing import last_fact
+
+    return last_fact("staging")
 
 
 @pytest.fixture(autouse=True)
@@ -137,9 +144,9 @@ def test_piece_is_a_view_exactly_when_stored_as_needed(
     staged = st.stage(X, np.float32)
     consecutive = n_dev == 1 or not st._interleave
     viewed = src_dt == np.float32 and stored == "C" and consecutive
-    assert len(pieces) == STAGE_METRICS["pieces"] > n_dev
+    assert len(pieces) == _staged()["pieces"] > n_dev
     assert [np.shares_memory(p, X) for p in pieces] == [viewed] * len(pieces)
-    assert STAGE_METRICS["pieces_viewed"] == (len(pieces) if viewed else 0)
+    assert _staged()["pieces_viewed"] == (len(pieces) if viewed else 0)
     assert np.array_equal(st.fetch(staged), X.astype(np.float32))
     if not viewed:
         # copied pieces share a few buffers, however many pieces there are
@@ -220,10 +227,10 @@ def test_caller_may_overwrite_rows_once_stage_returns(
     monkeypatch.setattr(mesh_mod, "_shard_update_fns", spied)
     staged = st.stage(X, np.float32)
     X[:] = np.nan
-    assert STAGE_METRICS["label"] == ("stage_mp" if multi_process else "stage")
-    assert STAGE_METRICS["pieces"] > 4 * mesh_mod._MAX_INFLIGHT_PIECES
-    assert STAGE_METRICS["pieces_viewed"] == STAGE_METRICS["pieces"]
-    assert len(tokens) == STAGE_METRICS["pieces"]
+    assert _staged()["label"] == ("stage_mp" if multi_process else "stage")
+    assert _staged()["pieces"] > 4 * mesh_mod._MAX_INFLIGHT_PIECES
+    assert _staged()["pieces_viewed"] == _staged()["pieces"]
+    assert len(tokens) == _staged()["pieces"]
     assert all(any(t is w for w in waited) for t in tokens)
     assert np.array_equal(_host(staged)[:n], want)
 
@@ -298,22 +305,30 @@ def test_depth_one_serial_fallback(force_pipelined):
     st = RowStager(5_000, m)
     serial = _host(st._stage_serial(X, np.dtype(np.float32)))
     assert np.array_equal(serial, _host(st.stage(X, np.float32)))
-    assert STAGE_METRICS["depth"] == 1
-    assert STAGE_METRICS["overlap_ratio"] == 0.0
+    assert _staged()["depth"] == 1
+    assert _staged()["overlap_ratio"] == 0.0
 
 
 def test_stage_metrics_populated(force_pipelined):
     rng = np.random.default_rng(2)
     X = rng.standard_normal((8_192, 8)).astype(np.float32)
     st = RowStager(8_192, get_mesh(8))
-    st.stage(X, np.float32)
-    for key in ("bytes", "seconds", "mb_per_s", "host_prep_s",
-                "device_put_s", "overlap_ratio", "pieces", "pieces_viewed",
-                "depth", "n_dev"):
-        assert key in STAGE_METRICS, key
+    from spark_rapids_ml_tpu import tracing
+
+    with tracing.run_context(prefix="stage-test") as run_id:
+        st.stage(X, np.float32)
+    staged = tracing.last_fact("staging", run_id=run_id)
+    for key in ("bytes", "seconds", "mb_per_s", "overlap_ratio", "pieces",
+                "pieces_viewed", "depth", "n_dev", "label"):
+        assert key in staged, key
+    # the prep and put seconds are the run's spans, one of each per piece
+    spans = [e.name for e in tracing.get_all_trace_events(run_id)]
+    assert spans.count("stage_prep") == spans.count("stage_put") == (
+        staged["pieces"]
+    )
     # padding never travels: transferred bytes == valid rows only
-    assert STAGE_METRICS["bytes"] == X.size * 4
-    assert STAGE_METRICS["n_dev"] == 8
+    assert _staged()["bytes"] == X.size * 4
+    assert _staged()["n_dev"] == 8
 
 
 def test_chunked_pieces_respect_budget(force_pipelined):
@@ -326,7 +341,7 @@ def test_chunked_pieces_respect_budget(force_pipelined):
     st = RowStager(16_384, m)
     serial = _host(st._stage_serial(X, np.dtype(np.float32)))
     assert np.array_equal(serial, _host(st.stage(X, np.float32)))
-    assert STAGE_METRICS["pieces"] > 4  # more than one piece per device
+    assert _staged()["pieces"] > 4  # more than one piece per device
 
 
 # ---------------------------------------------------------------------------
@@ -454,7 +469,7 @@ def test_producer_error_surfaces(force_pipelined):
 
 def test_stage_parquet_per_device_engine(tmp_path):
     pd = pytest.importorskip("pandas")
-    from spark_rapids_ml_tpu.streaming import LAST_STAGE, stage_parquet
+    from spark_rapids_ml_tpu.streaming import stage_parquet
 
     rng = np.random.default_rng(4)
     n, d = 20_000, 24
@@ -468,8 +483,8 @@ def test_stage_parquet_per_device_engine(tmp_path):
     ds = stage_parquet(path, label_col="label", weight_col="w",
                        chunk_rows=4_096, num_workers=8,
                        label_dtype=np.float64)
-    assert LAST_STAGE["engine"] == "per-device"
-    assert LAST_STAGE["bytes_transferred"] > 0
+    assert _staged()["engine"] == "per-device"
+    assert _staged()["bytes_transferred"] > 0
     hX, hy, hw = _host(ds.X), _host(ds.y), _host(ds.weight)
     assert np.array_equal(hX[:n], X)
     assert np.array_equal(hy[:n], y)
@@ -487,8 +502,8 @@ def test_pipelined_beats_serial_on_multi_device_mesh():
     """On the 8-device CPU mesh the serial path pays the n_dev x GSPMD
     replication per chunk plus two full host copies; the engine transfers
     each byte once with prep overlapped.  The speedup itself is a number
-    for the chip (and bench.py's `staging` section), not for a CPU wall
-    clock shared with five other test workers; what is checked here is
+    for the chip, not for a CPU wall clock shared with five other test
+    workers; what is checked here is
     what makes the win possible and needs no race against a clock: the
     same bytes land, prep ran on another thread than the puts, and every
     piece was put exactly once."""
@@ -515,7 +530,7 @@ def test_pipelined_beats_serial_on_multi_device_mesh():
         "host prep did not run on the prefetch thread"
     )
     assert {e.thread_id for e in put} == {threading.get_ident()}
-    assert len(put) == len(prep) == STAGE_METRICS["pieces"] >= 8
+    assert len(put) == len(prep) == _staged()["pieces"] >= 8
 
 
 @pytest.mark.parametrize("src_dt", [np.float32, np.float64])

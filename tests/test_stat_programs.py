@@ -25,7 +25,7 @@ from spark_rapids_ml_tpu.stats import (
     run_programs,
     summarize,
 )
-from spark_rapids_ml_tpu.stats.engine import STAT_METRICS
+from spark_rapids_ml_tpu.tracing import last_fact
 
 
 @pytest.fixture(autouse=True)
@@ -307,9 +307,10 @@ def test_summarize_six_plus_statistics_single_pass(rng):
     # (STAGE_COUNTS tracks every 2-D host->device staging), and the
     # engine reports exactly one multi-chunk pass
     assert STAGE_COUNTS["dataset_stagings"] == stagings0
-    assert STAT_METRICS["passes"] == 1
-    assert STAT_METRICS["chunks"] >= 2
-    assert STAT_METRICS["programs"] >= 5
+    stats = last_fact("stats")
+    assert stats["passes"] == 1
+    assert stats["chunks"] >= 2
+    assert stats["programs"] >= 5
     # spot-check the statistics came out right
     assert s["count"] == n
     np.testing.assert_allclose(s["mean"], X.mean(0), atol=1e-5)
@@ -335,9 +336,9 @@ def test_summarize_parquet_single_pass(tmp_path, rng):
         s["variance"], X.var(0, ddof=1), rtol=1e-3
     )
     np.testing.assert_allclose(s["min"], X.min(0), atol=1e-6)
-    # the engine's last-run state stamped this pass
-    assert STAT_METRICS["label"] == "summarize"
-    assert STAT_METRICS["chunks"] >= 1
+    # the pass left its own record on this thread
+    assert last_fact("stats")["label"] == "summarize"
+    assert last_fact("stats")["chunks"] >= 1
 
 
 def test_describe_matches_pandas(rng):
@@ -445,20 +446,20 @@ def test_describe_closes_heartbeat_gauges(rng):
 
 def test_concurrent_describes_do_not_cross_contaminate(rng):
     """Satellite (ISSUE 14): two threads running describe()
-    simultaneously must each get THEIR OWN correct summary, and the
-    process-wide `stat_program_last` view must hold one internally
-    consistent run's record (whichever finished last, marked
-    `concurrent_passes`) — never an interleaving of both (the PR-5
-    concurrent-fits report guard, mirrored)."""
+    simultaneously must each get THEIR OWN correct summary and leave
+    THEIR OWN pass's record on their thread, marked `concurrent_passes`
+    — never the other's, nor a mix of both (the PR-5 concurrent-fits
+    report guard, mirrored)."""
     import threading
 
     X1 = rng.normal(size=(48_000, 6)).astype(np.float32)
     X2 = rng.normal(size=(16_000, 3)).astype(np.float32) + 4.0
     ref1 = describe(X1)
-    chunks1 = int(STAT_METRICS["chunks"])
+    chunks1 = int(last_fact("stats")["chunks"])
     ref2 = describe(X2)
-    chunks2 = int(STAT_METRICS["chunks"])
+    chunks2 = int(last_fact("stats")["chunks"])
     results = {}
+    snaps = {}
     errors = []
     barrier = threading.Barrier(2)
 
@@ -466,6 +467,7 @@ def test_concurrent_describes_do_not_cross_contaminate(rng):
         try:
             barrier.wait(timeout=30)
             results[key] = describe(X)
+            snaps[key] = last_fact("stats")
         except Exception as e:  # pragma: no cover - diagnostic
             errors.append(e)
 
@@ -480,25 +482,22 @@ def test_concurrent_describes_do_not_cross_contaminate(rng):
     assert not errors, errors
     pd.testing.assert_frame_equal(results["a"], ref1)
     pd.testing.assert_frame_equal(results["b"], ref2)
-    snap = dict(STAT_METRICS)
-    # one consistent record: its (bytes, chunks) pair belongs to exactly
-    # one of the two runs — an interleaved clear/update would mix them
-    assert snap["label"] == "summarize"
-    assert snap["programs"] == 2  # moments + quantile_sketch
-    assert (int(snap["bytes"]), int(snap["chunks"])) in {
-        (X1.nbytes, chunks1),
-        (X2.nbytes, chunks2),
-    }, snap
-    # both passes overlapped: the record says so, and the report-side
-    # consumers (FitTelemetry stats section) know the engine counters
-    # around it are process-level
-    assert snap.get("concurrent_passes") is True
+    # each thread's record is its own pass's: the (bytes, chunks) pair of
+    # its own input, not the other's
+    for key, want in (("a", (X1.nbytes, chunks1)), ("b", (X2.nbytes, chunks2))):
+        snap = snaps[key]
+        assert snap["label"] == "summarize"
+        assert snap["programs"] == 2  # moments + quantile_sketch
+        assert (int(snap["bytes"]), int(snap["chunks"])) == want, snap
+        # both passes overlapped: the record says so, and the report-side
+        # consumers (FitTelemetry stats section) know the engine counters
+        # around it are process-level
+        assert snap.get("concurrent_passes") is True
 
 
 def test_fit_report_carries_stats_section(rng):
-    """A statistic pass completing inside a fit's telemetry window
-    lands as the report's `stats` section (the FUSED_METRICS last-run
-    discipline)."""
+    """A statistic pass run inside a fit lands as the report's `stats`
+    section: its `stats` fact carries the fit's run id."""
     from spark_rapids_ml_tpu.telemetry.report import FitTelemetry
 
     ft = FitTelemetry("SummarizerRun")

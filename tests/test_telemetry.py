@@ -115,11 +115,11 @@ def test_dict_view_back_compat_surface():
 
 
 def test_legacy_dict_names_read_through_registry():
-    """The four legacy metric dicts are views over the process registry:
+    """The legacy metric dicts are views over the process registry:
     a mutation through the OLD name is visible in `dump_prometheus` and
     `snapshot()` immediately."""
     from spark_rapids_ml_tpu.parallel.device_cache import CACHE_METRICS
-    from spark_rapids_ml_tpu.parallel.mesh import STAGE_COUNTS, STAGE_METRICS
+    from spark_rapids_ml_tpu.parallel.mesh import STAGE_COUNTS
     from spark_rapids_ml_tpu.resilience import RECOVERY_METRICS
 
     s0 = STAGE_COUNTS["dataset_stagings"]
@@ -129,7 +129,7 @@ def test_legacy_dict_names_read_through_registry():
     )
     STAGE_COUNTS["dataset_stagings"] = s0
     for view, family in (
-        (STAGE_METRICS, "staging_last"),
+        (STAGE_COUNTS, "staging_counts"),
         (CACHE_METRICS, "device_cache"),
         (RECOVERY_METRICS, "recovery"),
     ):
