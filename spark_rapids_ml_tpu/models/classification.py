@@ -500,6 +500,10 @@ class LogisticRegression(
 
             vals, cols = fit_input.X, fit_input.extra["ell_cols"]
             d = fit_input.pdesc.n
+            event(
+                "lbfgs_eval_kernel[autodiff]",
+                detail="ELL sparse rows: the margin is a gather-contract",
+            )
             if standardization:
                 _, std = ell_weighted_moments(vals, cols, w, d=d)
                 vals = ell_scale_columns(vals, cols, 1.0 / std)
@@ -591,6 +595,16 @@ class LogisticRegression(
                 f"lbfgs_route[{'host_dispatch' if host_dispatch else 'fused'}]",
                 detail=why,
             )
+            # which evaluation either route runs is read from the rows
+            # alone: one read of them an evaluation (a Pallas kernel) for
+            # dense float32 binomial rows on TPUs, autodiff's two otherwise
+            from ..ops.pallas_logistic import one_pass_plan
+
+            one_pass, why_kernel = one_pass_plan(X, binomial)
+            event(
+                f"lbfgs_eval_kernel[{'one_pass' if one_pass else 'autodiff'}]",
+                detail=why_kernel,
+            )
             if host_dispatch:
                 from ..ops.logistic import logreg_fit_host_dispatch
 
@@ -600,7 +614,7 @@ class LogisticRegression(
                 coef, b, loss, n_iter, hist = logreg_fit_host_dispatch(
                     X, w, fit_input.y, n_classes=n_classes,
                     binomial=binomial, checkpoint_path=ckpt_path,
-                    checkpoint_tag=ckpt_tag, **kwargs
+                    checkpoint_tag=ckpt_tag, one_pass=one_pass, **kwargs
                 )
             else:
                 # asynchronous: the call returns once the one program is
@@ -609,7 +623,7 @@ class LogisticRegression(
                 with trace("lbfgs_fused_dispatch"):
                     if binomial:
                         coef, b, loss, n_iter, hist = logreg_fit_binary(
-                            X, w, fit_input.y, **kwargs
+                            X, w, fit_input.y, one_pass=one_pass, **kwargs
                         )
                     else:
                         coef, b, loss, n_iter, hist = logreg_fit(

@@ -17,7 +17,7 @@
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -80,22 +80,32 @@ def _binary_problem(
     y: jax.Array,
     l2: float,
     fit_intercept: bool,
+    one_pass: Optional[Callable] = None,
 ):
     """(loss_fn, unpack, l1_mask, n_param) for the Spark binomial family:
     a single coefficient vector β with margin m(x)+b and penalty on β
     (NOT the softmax-2 form, whose L2 optimum differs by a factor of 2 in
     the penalty).  Shared by the fused while_loop solver and the
-    host-dispatched solver."""
+    host-dispatched solver.
+
+    `one_pass(w, sgn)`, where given, builds the data term in place of
+    `margin_fn` and autodiff: (β, b) -> Σᵢ wᵢ·softplus(-sgnᵢ·mᵢ) with its
+    own gradient (`ops/pallas_logistic.one_pass_data_term`, one read of
+    the rows an evaluation where autodiff makes two)."""
     wsum = w.sum()
     sgn = 2.0 * y.astype(dtype) - 1.0  # {-1, +1}
     _, n_param, l1_mask, unpack = _theta_layout(1, d, dtype, fit_intercept)
+    data_term = one_pass(w, sgn) if one_pass is not None else None
 
     def loss_fn(theta):
         beta, b = unpack(theta)
-        margin = margin_fn(beta) + b
-        # log(1 + exp(-sgn*margin)), numerically stable via softplus
-        nll = jax.nn.softplus(-sgn * margin)
-        data_loss = (nll * w).sum() / wsum
+        if data_term is not None:
+            nll_sum = data_term(beta, b)
+        else:
+            margin = margin_fn(beta) + b
+            # log(1 + exp(-sgn*margin)), numerically stable via softplus
+            nll_sum = (jax.nn.softplus(-sgn * margin) * w).sum()
+        data_loss = nll_sum / wsum
         reg = 0.5 * l2 * (beta * beta).sum()
         return data_loss + reg
 
@@ -115,9 +125,10 @@ def _solve_binary(
     max_iter: int,
     history: int,
     ls_max: int,
+    one_pass: Optional[Callable] = None,
 ):
     loss_fn, unpack, l1_mask, n_param = _binary_problem(
-        margin_fn, d, dtype, w, y, l2, fit_intercept
+        margin_fn, d, dtype, w, y, l2, fit_intercept, one_pass
     )
     theta0 = jnp.zeros((n_param,), dtype)
     res = lbfgs_minimize(
@@ -217,8 +228,19 @@ def logreg_fit(
     )
 
 
+def _one_pass_builder(one_pass, X):
+    """`_binary_problem`'s `one_pass` for the rows `X`: None without a plan
+    (`ops/pallas_logistic.one_pass_plan`)."""
+    if one_pass is None:
+        return None
+    from .pallas_logistic import one_pass_data_term
+
+    return partial(one_pass_data_term, one_pass, X)
+
+
 @partial(
-    jax.jit, static_argnames=("fit_intercept", "max_iter", "history", "ls_max")
+    jax.jit,
+    static_argnames=("fit_intercept", "max_iter", "history", "ls_max", "one_pass"),
 )
 def logreg_fit_binary(
     X: jax.Array,
@@ -231,12 +253,16 @@ def logreg_fit_binary(
     max_iter: int = 100,
     history: int = 10,
     ls_max: int = 20,
+    one_pass=None,
 ):
-    """Dense binary fit; returns (coef (d,), intercept, loss, n_iter)."""
+    """Dense binary fit; returns (coef (d,), intercept, loss, n_iter).
+    `one_pass`: the plan `ops/pallas_logistic.one_pass_plan` read from
+    these rows, None for autodiff."""
     dtype = jnp.promote_types(X.dtype, jnp.float32)
     return _solve_binary(
         lambda beta: X @ beta, X.shape[1], dtype, w, y,
         l2, l1, fit_intercept, tol, max_iter, history, ls_max,
+        _one_pass_builder(one_pass, X),
     )
 
 
@@ -318,6 +344,7 @@ def logreg_fit_host_dispatch(
     data=None,
     checkpoint_path: str = None,
     checkpoint_tag: str = "",
+    one_pass=None,
 ):
     """HOST-driven L-BFGS over device-RESIDENT data: one dispatched
     value+grad program per evaluation instead of the whole solve in one
@@ -348,6 +375,9 @@ def logreg_fit_host_dispatch(
     the optimizer state persists per accepted iteration and an
     interrupted fit resumes its trajectory (resilience/checkpoint.py).
 
+    `one_pass`: the plan `ops/pallas_logistic.one_pass_plan` read from
+    dense binomial rows `X`, None for autodiff.
+
     Returns (W (C,d) | coef (d,), b, loss, n_iter, history) matching the
     fused kernels' shapes for the same `binomial` flag.
     """
@@ -374,7 +404,7 @@ def logreg_fit_host_dispatch(
         if binomial:
             loss_fn, _, _, _ = _binary_problem(
                 lambda beta: mfn(dat, beta), d, dtype, w_, y_, l2,
-                fit_intercept,
+                fit_intercept, _one_pass_builder(one_pass, dat),
             )
         else:
             loss_fn, _, _, _ = _multinomial_problem(
