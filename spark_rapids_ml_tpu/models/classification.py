@@ -974,6 +974,16 @@ class RandomForestClassifier(
     ops/forest.py histogram builder; no collectives are needed during
     growth (the reference similarly uses no NCCL for RF, tree.py:523-524).
 
+    Growth is exact level by level to `maxDepth`: a level's frontier holds
+    min(2^level, the worker's rows) nodes and the node table is the heap.
+    (`max_active_nodes`, a backend parameter, caps the frontier for those
+    who pass it; above the cap the largest nodes keep growing.)  Bin edges
+    are quantiles of a seeded stratified sample of max(maxBins^2, 10,000)
+    of each worker's rows; bin ids are 8-bit, so `maxBins` <= 256.  The
+    draws (edge sample, Poisson bootstrap weights, each node's features)
+    are stated in ops/forest.py's header; the same seed and rows give the
+    same forest, bit for bit, fit after fit.
+
     Examples
     --------
     >>> import numpy as np, pandas as pd

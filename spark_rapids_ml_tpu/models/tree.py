@@ -82,11 +82,11 @@ class _RandomForestClass:
             "min_samples_leaf": 1,
             "min_impurity_decrease": 0.0,
             "split_criterion": None,  # set per subclass (gini/variance)
-            # width budget of the active-node frontier per level (ops/forest
-            # builds exactly level-wise while 2^level <= max_active_nodes,
-            # then best-first under this width); program size and compile
-            # memory scale with it, not with 2^max_depth
-            "max_active_nodes": 256,
+            # optional width budget of the active-node frontier per level.
+            # None (the default) grows exactly level-wise: a frontier holds
+            # min(2^level, the worker's rows) nodes.  A number caps it: above
+            # the cap ops/forest grows best-first under that width
+            "max_active_nodes": None,
             "verbose": False,
         }
 
@@ -306,19 +306,12 @@ class _RandomForestEstimator(
             min_info_gain=float(p["min_impurity_decrease"]),
             bootstrap=bool(p["bootstrap"]),
             subsample=float(p["max_samples"]),
-            max_active=int(p.get("max_active_nodes", 256)),
+            max_active=p.get("max_active_nodes"),
             mesh=mesh,
         )
-        # forest_fit dispatches tree chunks from the host and returns
-        # host-side TreeArrays (the per-chunk fetch is the sync)
-        host = trees
+        # host-side TreeArrays; the trees past n_trees pad the last worker
         return {
-            "feature": np.asarray(host.feature)[:n_trees],
-            "threshold": np.asarray(host.threshold)[:n_trees],
-            "leaf_stats": np.asarray(host.leaf_stats)[:n_trees],
-            "gain": np.asarray(host.gain)[:n_trees],
-            "count": np.asarray(host.count)[:n_trees],
-            "left_child": np.asarray(host.left_child)[:n_trees],
+            **{f: np.asarray(getattr(trees, f))[:n_trees] for f in trees._fields},
             "max_depth": max_depth,
             "n_cols": d,
             "dtype": str(np.dtype(fit_input.dtype).name),

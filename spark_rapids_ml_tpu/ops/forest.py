@@ -6,33 +6,63 @@
 #
 # There is no cuML to call into — this is a from-scratch histogram
 # (XGBoost-style binned) tree builder designed for XLA:
-#   - Quantile bin edges are computed per worker from the local shard (one
-#     sort per feature); rows are digitized once into int32 bin ids.
-#   - Trees grow LEVEL-WISE over a bounded ACTIVE-NODE frontier: each level
-#     processes at most `max_active` nodes (a fixed-shape batch), one
-#     scatter-add builds the (active-slot, bin, feature, stat) histogram,
-#     cumulative sums over bins give every candidate split's left/right
-#     statistics, and an argmax picks the best (feature, bin) per slot.
-#     Children are allocated in an explicit node TABLE (`left_child`
-#     pointers) whose size is 1 + sum_l 2*min(2^l, max_active) — linear in
-#     depth, NOT the 2^depth heap that capped the depth-6 compiler ceiling.
-#     When a level has more splittable children than `max_active`, the
-#     largest (by weighted count) keep growing and the rest become leaves
-#     (best-first growth under a width budget, LightGBM-style); with
-#     max_active >= 2^level the build is exact level-wise growth.
+#   - BINS.  Quantile bin edges come from a seeded stratified SAMPLE of each
+#     worker's rows (`edge_sample_rows`: Spark's own findSplits samples
+#     max(maxBins^2, 10,000) rows), read out of the resident rows in row
+#     blocks, sorted per feature.  The rows are digitized once, block by
+#     block, into 8-bit bin ids packed four to an int32 word
+#     (`pack_words`): nothing of the rows' size is held twice.  A bin id is
+#     the count of edges strictly below x, so `x <= edges[b]` on the raw
+#     value routes exactly as `bin <= b` does during the build.
+#   - A LEVEL STEP READS EACH NODE'S OWN FEATURES ONLY.  Every tree keeps
+#     its rows in a TILED, NODE-SORTED layout: (tiles, T) row ids, every
+#     tile the rows of ONE frontier node (a node's rows padded to whole
+#     tiles with weightless pads).  Per level: the tiles' packed rows are
+#     gathered, the node's K features selected out of them by a one-hot
+#     (features x K) matmul (bin ids are exact in bfloat16), the
+#     (node, K, bin, stat) histogram accumulated by a one-hot (rows x
+#     K*bins) matmul against the rows' statistics split into three exact
+#     bfloat16 parts (integer counts stay exact, real sums f32), cumulative
+#     sums over bins give every candidate split's left/right statistics,
+#     an argmax picks the best (feature, bin) per node, and ONE stable sort
+#     by next-level node (with a pool of pads that rounds every node up to
+#     whole tiles) is the next level's layout.  Rows of nodes that stop
+#     leave the layout; a leaf's statistics are its parent's histogram at
+#     the chosen split.  No per-row scatter, no per-row table gather.
+#   - Children are allocated in an explicit node TABLE (`left_child`
+#     pointers) whose size is 1 + sum_l 2*min(2^l, max_active).  The
+#     default `max_active` is the worker's row count, which no frontier can
+#     pass, so growth is EXACT level-wise and, while 2^level <= rows, the
+#     table is the heap.  A caller's smaller cap turns the levels above it
+#     into best-first growth under a width budget (LightGBM-style): the
+#     largest children (by weighted count) keep growing, the rest rest.
 #     No recursion, no dynamic shapes, no host round-trips.
 #   - Per-node feature subsets (featureSubsetStrategy) use the Gumbel
 #     top-K trick; bootstrap resampling uses Poisson(rate) weights (the
 #     standard large-n approximation of multinomial bootstrap, also used
 #     by cuML's GPU forest).
-#   - A whole device's worth of trees builds under one vmap; across the
-#     mesh, trees are embarrassingly parallel (shard_map with no
-#     collectives — the analog of reference tree.py's barrier-allGather-
-#     only pattern).
+#   - Trees are dispatched in equal chunks sized from the shapes and the
+#     memory the device has left (`chunk_trees_for`), a chunk's trees under
+#     one vmap; across the mesh, trees are embarrassingly parallel
+#     (shard_map with no collectives — the analog of reference tree.py's
+#     barrier-allGather-only pattern).
 #
-# Samples that reach a node that does not split simply keep that node id;
-# deeper levels ignore them (their id falls outside the active range), and
-# the final leaf-statistics scatter reads each sample's resting node.
+# THE DRAWS, stated so that a reference can re-derive them with jax.random
+# alone (chipbench/estimators/rfc.py does).  Worker `i` (its position on
+# the mesh's data axis) holds m rows (padding included) and
+# base = fold_in(PRNGKey(seed), i).
+#   edges   q = max(1, m // edge_sample_rows(n_bins)), S = m // q; sample
+#           row j is j*q + randint(fold_in(base, EDGE_STREAM), (S,), 0, q)[j];
+#           a sampled row of weight 0 counts as +inf; with n_eff sampled
+#           rows of positive weight, edge e (1..n_bins-1) of a feature is
+#           its sorted sample's element (e * n_eff) // n_bins.
+#   tree t  key = split(base, trees_per_worker)[t]; kb, kf = split(key);
+#           bootstrap weights poisson(kb, rate, (m,)) (bernoulli without
+#           bootstrap and rate < 1, else ones), times the row's weight.
+#   node    of frontier slot s at level l: the K features with the largest
+#           gumbel(fold_in(kf, l), (A_l, d), the rows' dtype)[s], A_l =
+#           min(2^l, max_active).  Uncapped, slot s of level l is table node
+#           2^l - 1 + s.
 #
 from __future__ import annotations
 
@@ -47,11 +77,40 @@ from ..parallel.mesh import DATA_AXIS
 
 GINI, ENTROPY, VARIANCE = 0, 1, 2  # split criteria
 
+EDGE_STREAM = 0x0ED6E5  # fold_in tag of the edge sample's offsets
+EDGE_RULE = "stratified sample of max(n_bins^2, 10000) rows per worker"
+
+# rows to one program of the bin phase, and to one step of a level's scans
+_BIN_BLOCK_ROWS = 32_768
+_SCAN_ROWS = 8_192
+_MAX_TILE_ROWS = 512
+
+
+def edge_sample_rows(n_bins: int) -> int:
+    """Rows the bin edges are read from at the least (Spark's findSplits:
+    max(maxBins^2, 10,000)); fewer local rows are all taken."""
+    return max(n_bins * n_bins, 10_000)
+
+
+def _edge_stride(m: int, n_bins: int) -> int:
+    """q: the edge sample takes one row out of every q of a worker's m."""
+    return max(1, m // edge_sample_rows(n_bins))
+
+
+def edge_sample(key, m: int, n_bins: int) -> jax.Array:
+    """Local positions of the worker's edge sample: one row out of each
+    run of q rows (`_edge_stride`), at a seeded offset."""
+    q = _edge_stride(m, n_bins)
+    n = m // q
+    off = jax.random.randint(jax.random.fold_in(key, EDGE_STREAM), (n,), 0, q)
+    return jnp.arange(n, dtype=jnp.int32) * q + off.astype(jnp.int32)
+
 
 def compute_bin_edges(
     X: jax.Array, n_bins: int, valid: jax.Array | None = None
 ) -> jax.Array:
-    """(n_bins-1, d) interior quantile boundaries from the local rows.
+    """(n_bins-1, d) interior quantile boundaries of the rows `X` (the edge
+    sample, or all of a small worker's rows).
 
     Zero-padding and zero-weight rows are pushed past the last quantile
     (+inf before the sort) so they cannot skew the edges toward 0; the
@@ -74,9 +133,36 @@ def compute_bin_edges(
 
 
 def digitize(X: jax.Array, edges: jax.Array) -> jax.Array:
-    """Bin ids in [0, n_bins): number of interior edges strictly below x."""
+    """uint8 bin ids in [0, n_bins): number of interior edges strictly
+    below x (n_bins <= 256)."""
     # (m, d) vs (B-1, d) -> count over edges
-    return (X[:, None, :] > edges[None, :, :]).sum(axis=1).astype(jnp.int32)
+    return (X[:, None, :] > edges[None, :, :]).sum(axis=1).astype(jnp.uint8)
+
+
+def pack_words(d: int) -> int:
+    """int32 words to a packed row of d bin ids: four ids to a word, whole
+    lane tiles once a row is longer than one (a (rows, words) array whose
+    minor dimension is no multiple of 128 lies column-major on a TPU, and
+    a row gather from it would copy it)."""
+    words = -(-d // 4)
+    return words if words <= 128 else -(-words // 128) * 128
+
+
+def pack_bins(Xb: jax.Array) -> jax.Array:
+    """(m, d) uint8 bin ids -> (m, W) int32, byte b of word w holding
+    feature b*W + w (plane-major, so unpacking is four aligned planes side
+    by side and no interleave)."""
+    m, d = Xb.shape
+    W = pack_words(d)
+    planes = jnp.pad(Xb, ((0, 0), (0, 4 * W - d))).reshape(m, 4, W).astype(jnp.int32)
+    return (planes[:, 0] | (planes[:, 1] << 8) | (planes[:, 2] << 16)
+            | (planes[:, 3] << 24))
+
+
+def _unpack_planes(words: jax.Array, dtype=jnp.bfloat16):
+    """The four (…, W) byte planes of packed rows, as `dtype` (a bin id is
+    at most 255: exact in bfloat16)."""
+    return [((words >> (8 * b)) & 0xFF).astype(dtype) for b in range(4)]
 
 
 def _impurity(stats: jax.Array, criterion: int) -> jax.Array:
@@ -96,7 +182,10 @@ def _impurity(stats: jax.Array, criterion: int) -> jax.Array:
     safe_n = jnp.maximum(n, 1e-12)
     p = stats / safe_n[..., None]
     if criterion == GINI:
-        imp = 1.0 - (p * p).sum(axis=-1)
+        # sum_c p_c (1 - p_c), not 1 - sum_c p_c^2: no cancellation, so the
+        # rounding is a few ulp OF THE IMPURITY and a nearly pure node ranks
+        # its candidate splits as well as an even one (n - count is exact)
+        imp = (p * ((n[..., None] - stats) / safe_n[..., None])).sum(axis=-1)
     else:  # entropy (Spark uses log2? MLlib uses natural log; sklearn ln)
         imp = -(jnp.where(p > 0, p * jnp.log(p), 0.0)).sum(axis=-1)
     return jnp.where(n > 0, imp, 0.0), n
@@ -118,187 +207,303 @@ def table_nodes(max_depth: int, max_active: int) -> int:
     return 1 + sum(2 * min(2**lv, max_active) for lv in range(max_depth))
 
 
+def _level_shape(m: int, A: int):
+    """(rows to a tile, tiles, tiles to a scan step) of a level whose
+    frontier holds A nodes over m rows: tiles of about half a node's mean
+    rows, room for every row plus one ragged tile a node, whole steps."""
+    T = 8
+    while 2 * T <= min(_MAX_TILE_ROWS, m // (2 * A)):
+        T *= 2
+    tiles = -(-m // T) + A
+    step = max(1, min(_SCAN_ROWS // T, tiles))
+    return T, -(-tiles // step) * step, step
+
+
+def _layout(key, rowid, w, y, counts, m: int, A: int, T: int, tiles: int):
+    """The tiled, node-sorted layout of a level: rows (`key` their frontier
+    slot, A for a row that rests; `rowid` < m, -1 for a pad of the level
+    before) sorted by slot together with a pool of pads that rounds every
+    slot's `counts` rows up to whole tiles.  Returns (slot of each tile (A
+    where empty), rowid (tiles, T) with -1 for pads, w, y).
+
+    Slot and row id share ONE uint32 sort key where their bits fit (4,096
+    slots over 500,000 rows do): every key but a pad's is unique, so the
+    order is the rows' own whatever sorts them, and the sort carries two
+    operands less than a stable one by slot would."""
+    lane = jnp.arange(T, dtype=jnp.int32)[None, :]
+    slots = jnp.arange(A, dtype=jnp.int32)[:, None]
+    pool_key = jnp.where(lane < (-counts[:, None]) % T, slots, A).reshape(-1)
+    pad = jnp.zeros((A * T,), w.dtype)
+    key = jnp.concatenate([key, pool_key])
+    rowid = jnp.concatenate([rowid, jnp.full((A * T,), -1, jnp.int32)])
+    w, y = jnp.concatenate([w, pad]), jnp.concatenate([y, pad])
+    row_bits = m.bit_length()  # 2^row_bits - 1 >= m marks a pad
+    if row_bits + A.bit_length() <= 32:
+        mark = jnp.uint32((1 << row_bits) - 1)
+        code = (key.astype(jnp.uint32) << row_bits) | jnp.where(
+            rowid < 0, mark, rowid.astype(jnp.uint32))
+        code, w, y = jax.lax.sort((code, w, y), num_keys=1, is_stable=False)
+        key = (code >> row_bits).astype(jnp.int32)
+        rowid = jnp.where(code & mark == mark, -1, (code & mark).astype(jnp.int32))
+    else:
+        key, rowid, w, y = jax.lax.sort((key, rowid, w, y), num_keys=1, is_stable=True)
+    n = tiles * T
+
+    def tiled(a, fill):  # the first n sorted elements; a level may be smaller than its room
+        short = max(0, n - a.shape[0])
+        return jnp.concatenate([a[:n], jnp.full((short,), fill, a.dtype)]).reshape(tiles, T)
+
+    return tiled(key, A)[:, 0], tiled(rowid, -1), tiled(w, 0), tiled(y, 0)
+
+
+def _row_stats(w, y, criterion: int, n_stats: int):
+    """(…, S, T) statistic channels of rows (…, T) of weight w and label
+    y, the rows on the minor axis."""
+    if criterion == VARIANCE:
+        return jnp.stack([w, w * y, w * y * y], axis=-2)
+    return w[..., None, :] * (
+        y.astype(jnp.int32)[..., None, :] == jnp.arange(n_stats)[:, None]
+    ).astype(w.dtype)
+
+
+def _three_parts(x, dtype):
+    """f32 (…, S, T) -> (…, 3S, T): three parts of `dtype` (bfloat16 on a
+    TPU) that add up to it exactly, so a one-hot product with them is as
+    exact as f32."""
+    hi = x.astype(dtype)
+    r1 = x - hi.astype(jnp.float32)
+    mid = r1.astype(dtype)
+    lo = (r1 - mid.astype(jnp.float32)).astype(dtype)
+    return jnp.concatenate([hi, mid, lo], axis=-2)
+
+
 def _grow_one_tree(
     key,
-    Xb: jax.Array,  # (m, d) int32 bin ids
+    packed: jax.Array,  # (m, W) int32 packed bin ids
     edges: jax.Array,  # (B-1, d) raw edge values
-    stats: jax.Array,  # (m, S) per-sample statistic channels (pre-weighted)
+    y: jax.Array,  # (m,) labels
     valid: jax.Array,  # (m,) row validity * user weight
     max_depth: int,
     n_bins: int,
     criterion: int,
+    n_stats: int,  # statistic channels: classes, or 3 for regression
     max_features: int,  # features considered per node (Gumbel top-K)
     min_instances: float,
     min_info_gain: float,
     bootstrap: bool,
     subsample: float,
     max_active: int,
+    room: int,  # rows a level's layout has room for (`rows_room`)
+    operand,  # dtype of the one-hot products' operands: bfloat16 on a TPU
 ):
-    m, d = Xb.shape
-    S = stats.shape[1]
+    m, W = packed.shape
+    d = edges.shape[1]
+    S, K, B = n_stats, max_features, n_bins
+    dtype = edges.dtype
     n_nodes = table_nodes(max_depth, max_active)
 
     kb, kf = jax.random.split(key)
-    # pcast marks the rate as device-varying to match the varying key inside
-    # jax.random's internal control flow under shard_map
-    rate = jax.lax.pcast(
-        jnp.asarray(subsample, jnp.float32), (DATA_AXIS,), to="varying"
-    )
     if bootstrap:
-        w = jax.random.poisson(kb, rate, (m,)).astype(stats.dtype)
+        w = jax.random.poisson(kb, subsample, (m,)).astype(dtype)
     elif subsample < 1.0:
-        w = jax.random.bernoulli(kb, rate, (m,)).astype(stats.dtype)
+        w = jax.random.bernoulli(kb, subsample, (m,)).astype(dtype)
     else:
-        w = jnp.ones((m,), stats.dtype)
-    w = w * valid
-    wstats = stats * w[:, None]  # (m, S)
+        w = jnp.ones((m,), dtype)
+    w = w * valid.astype(dtype)
 
     # node-table arrays carry ONE trash row at index n_nodes: writes for
     # empty frontier slots land there instead of corrupting real nodes
     # (negative scatter ids would wrap in JAX)
     feature = jnp.full((n_nodes + 1,), -1, jnp.int32)
-    threshold = jnp.zeros((n_nodes + 1,), edges.dtype)
-    gain_arr = jnp.zeros((n_nodes + 1,), stats.dtype)
-    count_arr = jnp.zeros((n_nodes + 1,), stats.dtype)
+    threshold = jnp.zeros((n_nodes + 1,), dtype)
+    gain_arr = jnp.zeros((n_nodes + 1,), dtype)
+    count_arr = jnp.zeros((n_nodes + 1,), dtype)
     left_arr = jnp.full((n_nodes + 1,), -1, jnp.int32)
+    leaf_stats = jnp.zeros((n_nodes + 1, S), dtype)
 
-    node = jnp.zeros((m,), jnp.int32)  # table id where each sample rests
-    # frontier slot of each sample; A_l (the level width) means inactive
-    slot = jnp.where(w > 0, 0, 1).astype(jnp.int32)
-    frontier = jnp.zeros((1,), jnp.int32)  # table ids of active nodes
-    base = jnp.int32(1)  # next unallocated table id
+    def level_step(level, A_l, state, layout, last):
+        (feature, threshold, gain_arr, count_arr, left_arr, leaf_stats,
+         frontier, base) = state
+        tile_slot, rowid, wt, yt = layout
+        T, tiles, step = _level_shape(room, A_l)
+        live = tile_slot < A_l
+        slot_c = jnp.minimum(tile_slot, A_l - 1)
+
+        # each node's K features, ascending (ties in gain go to the lowest)
+        if K < d:
+            g = jax.random.gumbel(jax.random.fold_in(kf, level), (A_l, d), dtype)
+            feats = jnp.sort(jax.lax.top_k(g, K)[1].astype(jnp.int32), axis=1)
+        else:
+            feats = jnp.broadcast_to(jnp.arange(d, dtype=jnp.int32), (A_l, d))
+
+        def chunks(a):
+            return a.reshape((tiles // step, step) + a.shape[1:])
+
+        # the rows' bin ids of their node's features, rows minor: (tiles, K, T)
+        def select(args):
+            rows, node_feats = args  # (step, T), (step, K)
+            planes = _unpack_planes(jnp.take(packed, jnp.maximum(rows, 0), axis=0), operand)
+            if K == d:
+                ids = jnp.concatenate(planes, axis=-1)[..., :d]
+                return jnp.swapaxes(ids, 1, 2).astype(jnp.uint8)
+            words = jnp.arange(W, dtype=jnp.int32)
+            sel = sum(
+                jnp.einsum(
+                    "ckw,ctw->ckt",
+                    (b * W + words == node_feats[:, :, None]).astype(operand), planes[b],
+                    preferred_element_type=jnp.float32)
+                for b in range(4))
+            return sel.astype(jnp.uint8)
+
+        with jax.named_scope("forest_hist"):
+            sel = jax.lax.map(select, (chunks(rowid), chunks(feats[slot_c])))
+            sel = sel.reshape(tiles, K, T)
+            stats3 = _three_parts(_row_stats(wt, yt, criterion, S), operand)  # (tiles, 3S, T)
+
+            # (A_l + 1, S, K*B): a tile's one-hot product, added to its node
+            def accumulate(hist, args):
+                s, st, node = args  # (step, K, T), (step, 3S, T), (step,)
+                onehot = (s[:, :, None, :] == jnp.arange(B, dtype=jnp.uint8)[:, None]).astype(
+                    operand).reshape(step, K * B, T)
+                part = jnp.einsum("cst,cqt->csq", st, onehot,
+                                  preferred_element_type=jnp.float32)
+                part = part[:, :S] + part[:, S:2 * S] + part[:, 2 * S:]
+                return hist.at[node].add(part.astype(dtype)), None
+
+            hist, _ = jax.lax.scan(
+                accumulate, jnp.zeros((A_l + 1, S, K * B), dtype),
+                (chunks(sel), chunks(stats3), chunks(jnp.where(live, tile_slot, A_l))))
+            hist = hist[:A_l].reshape(A_l, S, K, B).transpose(0, 2, 3, 1)  # (A,K,B,S)
+
+        with jax.named_scope("forest_split"):
+            cum = jnp.cumsum(hist, axis=2)
+            total = cum[:, :, -1, :]  # (A_l, K, S) same for every feature
+            left = cum[:, :, : B - 1, :]  # (A_l, K, B-1, S)
+            right = total[:, :, None, :] - left
+
+            imp_parent, n_parent = _impurity(total[:, 0, :], criterion)  # (A_l,)
+            imp_l, n_left = _impurity(left, criterion)  # (A_l, K, B-1)
+            imp_r, n_right = _impurity(right, criterion)
+            safe_np = jnp.maximum(n_parent, 1e-12)[:, None, None]
+            gain = (
+                imp_parent[:, None, None]
+                - (n_left * imp_l + n_right * imp_r) / safe_np
+            )
+            ok = (n_left >= min_instances) & (n_right >= min_instances)
+            gain = jnp.where(ok, gain, -jnp.inf)
+
+            flat = gain.reshape(A_l, -1)
+            best = jnp.argmax(flat, axis=1)
+            best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
+            bj = (best // (B - 1)).astype(jnp.int32)  # (A_l,) index into feats
+            bb = (best % (B - 1)).astype(jnp.int32)
+            bf = jnp.take_along_axis(feats, bj[:, None], axis=1)[:, 0]
+            real = frontier >= 0
+            can_split = jnp.isfinite(best_gain) & (best_gain > min_info_gain) & real
+            left_stats = jnp.take_along_axis(
+                left.reshape(A_l, K * (B - 1), S), best[:, None, None], axis=1)[:, 0]
+            node_stats = total[:, 0, :]
+
+            sids = jnp.where(real, frontier, n_nodes)  # dead slots -> trash row
+            left_ids = base + 2 * jnp.arange(A_l, dtype=jnp.int32)
+            feature = feature.at[sids].set(jnp.where(can_split, bf, -1))
+            threshold = threshold.at[sids].set(
+                jnp.where(can_split, edges[bb, bf], 0.0)
+            )
+            gain_arr = gain_arr.at[sids].set(
+                jnp.where(can_split, best_gain, 0.0)
+            )
+            count_arr = count_arr.at[sids].set(n_parent)
+            left_arr = left_arr.at[sids].set(jnp.where(can_split, left_ids, -1))
+            # a frontier node that does not split is a leaf with its rows
+            leaf_stats = leaf_stats.at[sids].set(
+                jnp.where(can_split[:, None], 0.0, node_stats))
+
+            # the children, as candidates 2*slot (left) and 2*slot + 1
+            child_stats = jnp.stack(
+                [left_stats, node_stats - left_stats], axis=1).reshape(2 * A_l, S)
+            cand_counts = _impurity(child_stats, criterion)[1]
+            cand_valid = jnp.repeat(can_split, 2)
+            cand_ids = base + jnp.arange(2 * A_l, dtype=jnp.int32)
+            if last:
+                kept_cand = jnp.zeros((2 * A_l,), bool)
+            else:
+                # next frontier: the up-to-A_next largest children (weighted
+                # count) that could still split; the rest rest as leaves
+                A_next = min(2 * A_l, max_active)
+                growable = cand_counts >= jnp.maximum(2.0 * min_instances, 1e-12)
+                score = jnp.where(cand_valid & growable, cand_counts, -jnp.inf)
+                if 2 * A_l <= max_active:
+                    keep_vals = score
+                    keep_idx = jnp.arange(2 * A_l, dtype=jnp.int32)
+                else:
+                    keep_vals, keep_idx = jax.lax.top_k(score, A_next)
+                    keep_idx = keep_idx.astype(jnp.int32)
+                kept = keep_vals > -jnp.inf
+                frontier = jnp.where(kept, base + keep_idx, -1)
+                # inverse map: candidate child -> next-level slot (A_next = none)
+                inv = jnp.full((2 * A_l,), A_next, jnp.int32).at[keep_idx].set(
+                    jnp.where(kept, jnp.arange(A_next, dtype=jnp.int32), A_next)
+                )
+                kept_cand = inv < A_next
+            # a child that leaves the frontier is a leaf with its statistics
+            rest = jnp.where(cand_valid & ~kept_cand, cand_ids, n_nodes)
+            leaf_stats = leaf_stats.at[rest].set(child_stats)
+            count_arr = count_arr.at[rest].set(cand_counts)
+
+        if not last:
+            with jax.named_scope("forest_route"):
+                # left child if bin id <= split bin, read off the selected ids
+                pick = jnp.arange(K, dtype=jnp.int32)[:, None] == bj[slot_c][:, None, None]
+                row_bin = jnp.where(pick, sel, 0).max(axis=1)  # (tiles, T)
+                go_left = row_bin <= bb[slot_c][:, None].astype(jnp.uint8)
+                held = live[:, None] & (rowid >= 0)
+                side = jnp.stack([inv[2 * slot_c], inv[2 * slot_c + 1]], axis=1)
+                key_next = jnp.where(
+                    held, jnp.where(go_left, side[:, :1], side[:, 1:]), A_next)
+                # rows to each next slot, from the tiles' own counts
+                n_l = (held & go_left).sum(axis=1, dtype=jnp.int32)
+                n_r = (held & ~go_left).sum(axis=1, dtype=jnp.int32)
+                counts = jnp.zeros((A_next + 1,), jnp.int32).at[side].add(
+                    jnp.stack([n_l, n_r], axis=1))[:A_next]
+                layout = _layout(
+                    key_next.reshape(-1), rowid.reshape(-1), wt.reshape(-1),
+                    yt.reshape(-1), counts, m, A_next, *_level_shape(room, A_next)[:2])
+        base = base + 2 * A_l
+        return (feature, threshold, gain_arr, count_arr, left_arr, leaf_stats,
+                frontier, base), layout
+
+    state = (feature, threshold, gain_arr, count_arr, left_arr, leaf_stats,
+             jnp.zeros((1,), jnp.int32), jnp.int32(1))
+    active = w > 0
+    n_active = active.sum().astype(jnp.int32)
+    layout = _layout(
+        jnp.where(active, 0, 1).astype(jnp.int32), jnp.arange(m, dtype=jnp.int32),
+        w, y.astype(dtype), n_active[None], m, 1, *_level_shape(room, 1)[:2])
 
     # Program-size structure: levels where the frontier is still widening
     # (A_l < max_active) have level-specific shapes and unroll; once the
     # frontier saturates at max_active every remaining level has IDENTICAL
-    # shapes, so all of them but the last share ONE lax.fori_loop body —
-    # compiled program size is O(log2(max_active)), independent of
-    # max_depth.  (The fully-unrolled deep build overwhelmed the TPU
-    # compile helper at depth 16, BENCH r03.)  `level` may be traced (the
-    # fori index): it only feeds fold_in.
-    def level_step(level, A_l, state, last):
-        (feature, threshold, gain_arr, count_arr, left_arr,
-         node, slot, frontier, base) = state
-        active = slot < A_l
-        slot_c = jnp.clip(slot, 0, A_l - 1)
-
-        # histogram: (A_l * B, d, S) via one batched scatter-add
-        idx = slot_c[:, None] * n_bins + Xb  # (m, d)
-        upd = jnp.where(active[:, None, None], wstats[:, None, :], 0.0)
-        upd = jnp.broadcast_to(upd, (m, d, S))
-        hist = jnp.zeros((A_l * n_bins, d, S), stats.dtype)
-        hist = hist.at[idx, jnp.arange(d)[None, :], :].add(upd)
-        hist = hist.reshape(A_l, n_bins, d, S).transpose(0, 2, 1, 3)
-        # (A_l, d, B, S)
-
-        cum = jnp.cumsum(hist, axis=2)
-        total = cum[:, :, -1, :]  # (A_l, d, S) same for every feature
-        left = cum[:, :, : n_bins - 1, :]  # (A_l, d, B-1, S)
-        right = total[:, :, None, :] - left
-
-        imp_parent, n_parent = _impurity(total[:, 0, :], criterion)  # (A_l,)
-        imp_l, n_left = _impurity(left, criterion)  # (A_l, d, B-1)
-        imp_r, n_right = _impurity(right, criterion)
-        safe_np = jnp.maximum(n_parent, 1e-12)[:, None, None]
-        gain = (
-            imp_parent[:, None, None]
-            - (n_left * imp_l + n_right * imp_r) / safe_np
-        )
-        ok = (n_left >= min_instances) & (n_right >= min_instances)
-        gain = jnp.where(ok, gain, -jnp.inf)
-
-        if max_features < d:
-            # per-node feature subset: Gumbel top-K mask over features
-            g = jax.random.gumbel(
-                jax.random.fold_in(kf, level), (A_l, d), stats.dtype
-            )
-            kth = jnp.sort(g, axis=1)[:, d - max_features]
-            fmask = g >= kth[:, None]  # exactly K True per node
-            gain = jnp.where(fmask[:, :, None], gain, -jnp.inf)
-
-        flat = gain.reshape(A_l, -1)
-        best = jnp.argmax(flat, axis=1)
-        best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
-        bf = (best // (n_bins - 1)).astype(jnp.int32)  # (A_l,)
-        bb = (best % (n_bins - 1)).astype(jnp.int32)
-        real = frontier >= 0
-        can_split = jnp.isfinite(best_gain) & (best_gain > min_info_gain) & real
-
-        sids = jnp.where(real, frontier, n_nodes)  # dead slots -> trash row
-        left_ids = base + 2 * jnp.arange(A_l, dtype=jnp.int32)
-        feature = feature.at[sids].set(jnp.where(can_split, bf, -1))
-        threshold = threshold.at[sids].set(
-            jnp.where(can_split, edges[bb, bf], 0.0)
-        )
-        gain_arr = gain_arr.at[sids].set(
-            jnp.where(can_split, best_gain, 0.0)
-        )
-        count_arr = count_arr.at[sids].set(n_parent)
-        left_arr = left_arr.at[sids].set(jnp.where(can_split, left_ids, -1))
-
-        # route samples: left child if bin id <= split bin
-        samp_f = bf[slot_c]
-        samp_b = bb[slot_c]
-        go_left = (
-            jnp.take_along_axis(Xb, samp_f[:, None], axis=1)[:, 0] <= samp_b
-        )
-        splits = active & can_split[slot_c]
-        child_node = left_ids[slot_c] + jnp.where(go_left, 0, 1)
-        node = jnp.where(splits, child_node, node)
-
-        if not last:
-            # next frontier: the up-to-A_next largest children (weighted
-            # count) that could still split; the rest rest as leaves
-            A_next = min(2 * A_l, max_active)
-            flat2 = n_left.reshape(A_l, -1)
-            nl_b = jnp.take_along_axis(flat2, best[:, None], axis=1)[:, 0]
-            nr_b = n_parent - nl_b
-            cand_counts = jnp.stack([nl_b, nr_b], axis=1).reshape(-1)
-            cand_valid = jnp.repeat(can_split, 2)
-            growable = cand_counts >= jnp.maximum(2.0 * min_instances, 1e-12)
-            score = jnp.where(cand_valid & growable, cand_counts, -jnp.inf)
-            if 2 * A_l <= max_active:
-                keep_vals = score
-                keep_idx = jnp.arange(2 * A_l, dtype=jnp.int32)
-            else:
-                keep_vals, keep_idx = jax.lax.top_k(score, A_next)
-                keep_idx = keep_idx.astype(jnp.int32)
-            kept = keep_vals > -jnp.inf
-            frontier = jnp.where(kept, base + keep_idx, -1)
-            # inverse map: candidate child -> next-level slot (A_next = none)
-            inv = jnp.full((2 * A_l,), A_next, jnp.int32).at[keep_idx].set(
-                jnp.where(kept, jnp.arange(A_next, dtype=jnp.int32), A_next)
-            )
-            cand_of_sample = 2 * slot_c + jnp.where(go_left, 0, 1)
-            slot = jnp.where(splits, inv[cand_of_sample], A_next)
-        base = base + 2 * A_l
-        return (feature, threshold, gain_arr, count_arr, left_arr,
-                node, slot, frontier, base)
-
-    state = (feature, threshold, gain_arr, count_arr, left_arr,
-             node, slot, frontier, base)
-    # first level whose frontier width reaches max_active
+    # shapes, so all of them but the last share ONE lax.fori_loop body.
+    # `level` may be traced (the fori index): it only feeds fold_in.
     sat = 0
     while (1 << sat) < max_active and sat < max_depth:
         sat += 1
     for lv in range(min(sat, max_depth)):
-        state = level_step(
-            lv, min(1 << lv, max_active), state, last=(lv == max_depth - 1)
+        state, layout = level_step(
+            lv, min(1 << lv, max_active), state, layout, last=(lv == max_depth - 1)
         )
     if sat < max_depth:
         if max_depth - 1 > sat:
-            state = jax.lax.fori_loop(
+            state, layout = jax.lax.fori_loop(
                 sat,
                 max_depth - 1,
-                lambda lv, st: level_step(lv, max_active, st, last=False),
-                state,
+                lambda lv, sl: level_step(lv, max_active, *sl, last=False),
+                (state, layout),
             )
         # final level: no next-frontier bookkeeping (nothing grows past it)
-        state = level_step(max_depth - 1, max_active, state, last=True)
-    (feature, threshold, gain_arr, count_arr, left_arr,
-     node, slot, frontier, base) = state
-
-    leaf_stats = jnp.zeros((n_nodes + 1, S), stats.dtype).at[node].add(wstats)
+        state, layout = level_step(max_depth - 1, max_active, state, layout, last=True)
+    feature, threshold, gain_arr, count_arr, left_arr, leaf_stats = state[:6]
     return TreeArrays(
         feature[:n_nodes],
         threshold[:n_nodes],
@@ -306,67 +511,133 @@ def _grow_one_tree(
         gain_arr[:n_nodes],
         count_arr[:n_nodes],
         left_arr[:n_nodes],
+    ), n_active > room
+
+
+def _local(kernel, mesh, n_sharded: int, n_replicated: int = 0, out_specs=P(DATA_AXIS)):
+    """`kernel` over each device's own rows: the first arguments sharded by
+    rows, the rest replicated, no collective."""
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(P(DATA_AXIS),) * n_sharded + (P(),) * n_replicated,
+        out_specs=out_specs, check_vma=False,
     )
 
 
-@partial(
-    jax.jit,
-    static_argnames=("n_bins", "criterion", "n_classes", "mesh"),
-)
-def _forest_prep(X, y, valid, n_bins: int, criterion: int, n_classes: int,
-                 mesh=None):
-    """One pass shared by every tree chunk: per-device bin edges (sorted
-    local quantiles), digitized rows, and histogram statistic channels."""
+def _block_of(a, k, rows: int):
+    """Rows [start, start + rows) of the device's `a`, read in place; the
+    last block starts early enough to be whole, so blocks may overlap."""
+    start = jnp.minimum(k * rows, a.shape[0] - rows)
+    return start, jax.lax.dynamic_slice_in_dim(a, start, rows, 0)
 
-    def kernel(Xl, yl, validl):
-        if criterion == VARIANCE:
-            yf = yl.astype(Xl.dtype)
-            statsl = jnp.stack([jnp.ones_like(yf), yf, yf * yf], axis=1)
-        else:
-            statsl = (
-                yl.astype(jnp.int32)[:, None] == jnp.arange(n_classes)[None, :]
-            ).astype(Xl.dtype)
-        edges = compute_bin_edges(Xl, n_bins, valid=validl)
-        Xb = digitize(Xl, edges)
-        return Xb, edges, statsl
 
-    shard = jax.shard_map(
-        kernel,
-        mesh=mesh,
-        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
-        out_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
-    )
-    return shard(X, y, valid)
+@partial(jax.jit, static_argnames=("n_bins", "rows", "mesh"), donate_argnums=(0, 1))
+def _forest_sample_block(sample, sample_valid, X, valid, seed, k, n_bins: int,
+                         rows: int, mesh=None):
+    """Writes into `sample` the rows of the worker's edge sample that lie
+    in row block k of its shard (and into `sample_valid` their weights).
+    The sample is in row order, so a block's part of it is one window."""
+
+    def kernel(sl, svl, Xl, validl, k_):
+        m, n = Xl.shape[0], sl.shape[0]
+        base = jax.random.fold_in(jax.random.PRNGKey(seed), jax.lax.axis_index(DATA_AXIS))
+        q = _edge_stride(m, n_bins)
+        window = min(n, rows // q + 2)
+        first = jnp.minimum((k_ * rows) // q, n - window)
+        at = jax.lax.dynamic_slice_in_dim(edge_sample(base, m, n_bins), first, window)
+        start, Xb = _block_of(Xl, k_, rows)
+        _, vb = _block_of(validl, k_, rows)
+        mine = (at >= k_ * rows) & (at < (k_ + 1) * rows)
+        rel = jnp.clip(at - start, 0, rows - 1)
+
+        def put(a, new):  # the block's own samples into their window of `a`
+            held = jax.lax.dynamic_slice_in_dim(a, first, window)
+            mask = mine.reshape((-1,) + (1,) * (a.ndim - 1))
+            return jax.lax.dynamic_update_slice_in_dim(a, jnp.where(mask, new, held), first, 0)
+
+        return put(sl, jnp.take(Xb, rel, axis=0)), put(svl, jnp.take(vb, rel))
+
+    return _local(kernel, mesh, 4, 1, out_specs=(P(DATA_AXIS), P(DATA_AXIS)))(
+        sample, sample_valid, X, valid, jnp.asarray(k, jnp.int32))
+
+
+@partial(jax.jit, static_argnames=("n_bins", "mesh"))
+def _forest_edges(sample, sample_valid, n_bins: int, mesh=None):
+    """Per-device bin edges from its edge sample."""
+    return _local(lambda s, v: compute_bin_edges(s, n_bins, valid=v), mesh, 2)(
+        sample, sample_valid)
+
+
+@partial(jax.jit, static_argnames=("rows", "mesh"), donate_argnums=(0,))
+def _forest_bin_block(packed, X, edges, k, rows: int, mesh=None):
+    """Writes row block k of each device's packed bin ids."""
+
+    def kernel(pl, Xl, edgesl, k_):
+        start, Xb = _block_of(Xl, k_, rows)
+        return jax.lax.dynamic_update_slice_in_dim(
+            pl, pack_bins(digitize(Xb, edgesl)), start, 0)
+
+    return _local(kernel, mesh, 3, 1)(packed, X, edges, jnp.asarray(k, jnp.int32))
+
+
+def forest_bins(X, valid, seed, n_bins: int, mesh):
+    """(packed bin ids (N_pad, W) int32, edges (n_dev * (n_bins-1), d)),
+    both sharded like the rows: the edge sample gathered and the rows
+    digitized in row blocks read in place, one program a block."""
+    from jax.sharding import NamedSharding
+
+    if not 2 <= n_bins <= 256:
+        raise ValueError(f"maxBins must lie in [2, 256] (8-bit bin ids), got {n_bins}")
+    n_dev = int(mesh.devices.size)
+    m, d = int(X.shape[0]) // n_dev, int(X.shape[1])
+    rows = min(m, _BIN_BLOCK_ROWS)
+    blocks = -(-m // rows)
+    n_sample = m // _edge_stride(m, n_bins)
+    by_rows = NamedSharding(mesh, P(DATA_AXIS))
+    sample = jnp.zeros((n_dev * n_sample, d), X.dtype, device=by_rows)
+    sample_valid = jnp.zeros((n_dev * n_sample,), valid.dtype, device=by_rows)
+    for k in range(blocks):
+        sample, sample_valid = _forest_sample_block(
+            sample, sample_valid, X, valid, seed, k, n_bins=n_bins, rows=rows, mesh=mesh)
+    edges = _forest_edges(sample, sample_valid, n_bins=n_bins, mesh=mesh)
+    packed = jnp.zeros((n_dev * m, pack_words(d)), jnp.int32, device=by_rows)
+    for k in range(blocks):
+        packed = _forest_bin_block(packed, X, edges, k, rows=rows, mesh=mesh)
+    return packed, edges
 
 
 @partial(
     jax.jit,
     static_argnames=(
         "count", "trees_per_worker", "max_depth", "n_bins", "criterion",
-        "max_features", "bootstrap", "subsample", "max_active", "mesh",
+        "n_stats", "max_features", "bootstrap", "subsample", "max_active", "room",
+        "mesh",
     ),
 )
 def _forest_fit_chunk(
-    Xb, edges, stats, valid, seed, lo,
+    packed, edges, y, valid, seed, lo,
     count: int,
     trees_per_worker: int,
     max_depth: int,
     n_bins: int,
     criterion: int,
+    n_stats: int,
     max_features: int,
     min_instances: float,
     min_info_gain: float,
     bootstrap: bool,
     subsample: float,
     max_active: int,
+    room: int,
     mesh=None,
 ):
     """Grow trees [lo, lo+count) of each device's `trees_per_worker`
-    allocation.  `lo` is traced, so every full chunk shares one
-    compilation; per-tree PRNG keys come from one split of the full
-    allocation, so the forest is identical for any chunking."""
+    allocation.  `lo` is traced, so every chunk shares one compilation;
+    per-tree PRNG keys come from one split of the full allocation, so the
+    forest is identical for any chunking.  Returns (TreeArrays, per tree
+    whether its rows of positive weight passed `room`)."""
 
-    def kernel(Xbl, edgesl, statsl, validl, lo_):
+    def kernel(packedl, edgesl, yl, validl, lo_):
         widx = jax.lax.axis_index(DATA_AXIS)
         base = jax.random.fold_in(jax.random.PRNGKey(seed), widx)
         keys = jax.lax.dynamic_slice_in_dim(
@@ -374,30 +645,75 @@ def _forest_fit_chunk(
         )
         grow = partial(
             _grow_one_tree,
-            Xb=Xbl,
+            packed=packedl,
             edges=edgesl,
-            stats=statsl,
+            y=yl,
             valid=validl,
             max_depth=max_depth,
             n_bins=n_bins,
             criterion=criterion,
+            n_stats=n_stats,
             max_features=max_features,
             min_instances=min_instances,
             min_info_gain=min_info_gain,
             bootstrap=bootstrap,
             subsample=subsample,
             max_active=max_active,
+            room=room,
+            # the CPU backend has no bfloat16 dot; f32 operands are as exact
+            operand=(jnp.bfloat16 if mesh.devices.flat[0].platform == "tpu"
+                     else jnp.float32),
         )
         return jax.vmap(lambda k: grow(k))(keys)
 
-    shard = jax.shard_map(
-        kernel,
-        mesh=mesh,
-        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS),
-                  P()),
-        out_specs=TreeArrays(*([P(DATA_AXIS)] * 6)),
-    )
-    return shard(Xb, edges, stats, valid, jnp.asarray(lo, jnp.int32))
+    return _local(
+        kernel, mesh, 4, 1, out_specs=(TreeArrays(*([P(DATA_AXIS)] * 6)), P(DATA_AXIS))
+    )(packed, edges, y, valid, jnp.asarray(lo, jnp.int32))
+
+
+def rows_room(m: int, bootstrap: bool, subsample: float) -> int:
+    """Rows a tree's level layouts have room for.  A row of weight 0 is in
+    no layout; under Poisson(rate) bootstrap a row has weight 0 with
+    probability e^-rate (37 % at rate 1), without it and a rate under 1
+    with probability 1 - rate.  Room for the mean count of the others and
+    eight standard deviations (passed once in 10^15 trees; `forest_fit`
+    grows such a tree again with room for every row)."""
+    import math
+
+    if bootstrap:
+        p = 1.0 - math.exp(-subsample)
+    elif subsample < 1.0:
+        p = subsample
+    else:
+        return m
+    return min(m, math.ceil(m * p + 8.0 * math.sqrt(m * p * (1.0 - p))) + 1)
+
+
+def tree_bytes(room: int, d: int, max_depth: int, n_bins: int, n_stats: int,
+               max_features: int, max_active: int) -> int:
+    """Device bytes one tree's build holds at its widest level, from the
+    shapes alone: the histogram, its cumulative sums and the gains derived
+    from it (three arrays of its size at a time), the selected bin ids, the
+    layout and its sort, a scan step's gathered rows and one-hots, and the
+    node table.  For the benchmark's tree this says 1.3 GB where a v5e's
+    compiler asks 0.75."""
+    A = min(2 ** (max_depth - 1), max_active)
+    T, tiles, step = _level_shape(room, A)
+    hist = 4 * A * n_stats * max_features * n_bins
+    rows = tiles * T
+    scan = step * T * (4 * pack_words(d) * 3 + 2 * max_features * n_bins)
+    if max_features < d:
+        scan += 2 * step * 4 * pack_words(d) * max_features
+        hist += 8 * A * d  # the Gumbel draws and their top-k
+    table = 4 * table_nodes(max_depth, max_active) * (5 + n_stats)
+    return 3 * hist + rows * (max_features + 4 * 4 * 3 + 6 * n_stats) + scan + table
+
+
+def chunk_trees_for(trees: int, per_tree: int, free: int) -> int:
+    """Trees to one dispatch: the largest divisor of `trees` (equal chunks,
+    one compilation) whose builds fit in half of `free` bytes."""
+    want = max(1, min(trees, free // 2 // max(per_tree, 1)))
+    return next(c for c in range(want, 0, -1) if trees % c == 0)
 
 
 def forest_fit(
@@ -415,7 +731,7 @@ def forest_fit(
     min_info_gain: float,
     bootstrap: bool,
     subsample: float,
-    max_active: int = 256,
+    max_active: int | None = None,  # None: exact growth
     mesh=None,
     chunk_trees: int | None = None,  # test hook: fixed chunk size
 ):
@@ -424,77 +740,74 @@ def forest_fit(
     Returns HOST TreeArrays with a leading (trees_per_worker * n_devices)
     axis.
 
-    Trees are dispatched from the host in adaptively-sized chunks that
-    each target `_TARGET_DISPATCH_S` of device time (a 100-tree depth-16
-    build on 1M rows is minutes).  The bound was sized for a development
-    link that no longer exists; it is kept until re-justified on the chip
-    or deleted (ROADMAP Design 2).  Trees are embarrassingly parallel, so
-    chunking changes nothing but dispatch count; per-chunk host fetches
-    double as the sync points."""
-    import time as _time
+    Trees are dispatched from the host in equal chunks sized from the
+    shapes and the memory a device has left beside its rows and their bins
+    (`chunk_trees_for`).  Trees are embarrassingly parallel and every
+    count is exact, so chunking changes nothing but the dispatch count:
+    the forest is bit-identical for any chunking and from fit to fit.
 
+    Spans (docs/observability.md): `forest_bin` (edges and bins), one
+    `forest_grow` per dispatched chunk (ended when the chunk is built),
+    `forest_fetch`; one `fact[forest]` a fit."""
     import numpy as np
 
+    from ..parallel.device_cache import bytes_beside
     from ..parallel.mesh import fetch_replicated
+    from ..tracing import fact, trace
 
-    prep = _forest_prep(
-        X, y, valid, n_bins=n_bins, criterion=criterion,
-        n_classes=n_classes, mesh=mesh,
-    )
+    ndev = int(mesh.devices.size)
+    m_local, d = int(X.shape[0]) // ndev, int(X.shape[1])
+    # no frontier holds more nodes than the worker has rows
+    width = m_local if max_active is None else max(1, min(int(max_active), m_local))
+    n_stats = 3 if criterion == VARIANCE else int(n_classes)
 
-    def run(lo: int, count: int):
-        t0 = _time.perf_counter()
-        chunk = _forest_fit_chunk(
-            *prep, valid, seed, lo,
-            count=count,
+    with trace("forest_bin"):
+        packed, edges = forest_bins(X, valid, seed, n_bins, mesh)
+        jax.block_until_ready(packed)
+
+    room = rows_room(m_local, bootstrap, subsample)
+    per_tree = tree_bytes(room, d, max_depth, n_bins, n_stats, max_features, width)
+    if chunk_trees is not None:
+        size = max(1, min(chunk_trees, trees_per_worker))
+    else:
+        free = bytes_beside(X) - packed.addressable_shards[0].data.nbytes
+        size = chunk_trees_for(trees_per_worker, per_tree, max(free, 0))
+
+    def grow(lo, room):
+        return _forest_fit_chunk(
+            packed, edges, y, valid, seed, lo,
+            count=min(size, trees_per_worker - lo),
             trees_per_worker=trees_per_worker,
             max_depth=max_depth,
             n_bins=n_bins,
             criterion=criterion,
+            n_stats=n_stats,
             max_features=max_features,
             min_instances=min_instances,
             min_info_gain=min_info_gain,
             bootstrap=bootstrap,
             subsample=subsample,
-            max_active=max_active,
+            max_active=width,
+            room=room,
             mesh=mesh,
         )
-        host = TreeArrays(
-            *(np.asarray(fetch_replicated(t, mesh)) for t in chunk)
-        )  # the fetch is the sync
-        return host, _time.perf_counter() - t0
 
-    # estimated histogram work per device: levels x rows x features
-    # scatter-adds per tree.  Small builds run as ONE dispatch (far from
-    # the deadline; probing would just add compiles), big builds probe a
-    # single tree and size chunks from its warm time.
-    m_local = int(X.shape[0]) // max(int(mesh.devices.size), 1)
-    est_ops = trees_per_worker * max_depth * m_local * int(X.shape[1])
     chunks = []
-    done = 0
-    if chunk_trees is not None:
-        size = max(1, min(chunk_trees, trees_per_worker))
-    elif trees_per_worker > 1 and est_ops > 2e8:
-        c0, _ = run(0, 1)  # cold: includes compile
-        c1, warm = run(1, 1)  # warm: honest per-tree device time
-        chunks += [c0, c1]
-        done = 2
-        # ~20 s of device work per dispatch, floor 1
-        size = int(min(max(20.0 / max(warm, 1e-3), 1), trees_per_worker - done))
-    else:
-        size = trees_per_worker
-    while trees_per_worker - done >= size and size > 0:
-        chunks.append(run(done, size)[0])
-        done += size
-    if trees_per_worker - done:
-        chunks.append(run(done, trees_per_worker - done)[0])
+    for lo in range(0, trees_per_worker, size):
+        with trace("forest_grow"):
+            trees, over = jax.block_until_ready(grow(lo, room))
+            if np.asarray(fetch_replicated(over, mesh)).any():
+                trees, _ = jax.block_until_ready(grow(lo, m_local))
+            chunks.append(trees)
+    with trace("forest_fetch"):
+        chunks = [
+            TreeArrays(*(np.asarray(fetch_replicated(t, mesh)) for t in chunk))
+            for chunk in chunks
+        ]
 
     # reassemble DEVICE-MAJOR: each chunk is (ndev*count, ...) device-major
-    # over its own count; naive chunk concat would interleave devices and
-    # make the caller's [:n_trees] padding trim timing-dependent (chunk
-    # sizes come from a wall-clock probe)
-    ndev = int(mesh.devices.size)
-
+    # over its own count; naive chunk concat would interleave devices, and
+    # the caller's [:n_trees] trim of the padding counts on the order
     def reassemble(field):
         parts = [
             getattr(c, field).reshape(
@@ -505,7 +818,36 @@ def forest_fit(
         cat = np.concatenate(parts, axis=1)  # (ndev, trees_per_worker, ...)
         return cat.reshape((ndev * trees_per_worker,) + cat.shape[2:])
 
-    return TreeArrays(*(reassemble(f) for f in TreeArrays._fields))
+    trees = TreeArrays(*(reassemble(f) for f in TreeArrays._fields))
+    internal = trees.feature >= 0
+    fact(
+        "forest",
+        trees=int(trees.feature.shape[0]),
+        internal_nodes=int(internal.sum()),
+        depth_reached=_depth_reached(trees.left_child, internal),
+        widest_frontier=int(min(2 ** (max_depth - 1), width)),
+        bins=int(n_bins),
+        features_per_node=int(max_features),
+        chunk_trees=int(size),
+        tree_bytes=int(per_tree),
+        edge_rule=EDGE_RULE,
+    )
+    return trees
+
+
+def _depth_reached(left_child, internal) -> int:
+    """Levels of splits in the deepest tree (host arrays)."""
+    import numpy as np
+
+    level = np.full(left_child.shape, -1, np.int32)
+    level[:, 0] = 0
+    reached = 0
+    while True:
+        t, i = np.nonzero((level == reached) & internal)
+        if not len(t):
+            return reached
+        reached += 1
+        level[t, left_child[t, i]] = level[t, left_child[t, i] + 1] = reached
 
 
 @partial(jax.jit, static_argnames=("max_depth",))

@@ -339,10 +339,9 @@ class RandomForestClassifier(_FacadeBase):
         self._warn_ignored(_ignored)
         self.n_estimators = n_estimators
         # sklearn's max_depth=None means unbounded; the histogram builder
-        # grows trees over a bounded active-node frontier (max_active_nodes,
-        # ops/forest.py), so program size is linear in depth — 16 (cuML's
-        # default) is the practical cap here.  Pass max_depth explicitly for
-        # deeper trees.
+        # unrolls one program body per level until the frontier is as wide
+        # as the rows (ops/forest.py), so 16 (cuML's default) is the
+        # practical cap here.  Pass max_depth explicitly for deeper trees.
         self.max_depth = max_depth if max_depth is not None else 16
         self.criterion = criterion
         self.max_features = max_features

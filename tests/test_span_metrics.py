@@ -71,12 +71,18 @@ KMEANS_READERS = ["lloyd_step_roofline", "lloyd_iter_gap_ms", "lloyd_iters_per_f
                   "kmeans_init_s"]
 
 
+# PR 33's, appended after them: the readers of the forest's spans, fact and programs
+FOREST_READERS = ["forest_bin_s", "forest_grow_s", "forest_fetch_s", "forest_nodes_per_fit",
+                  "forest_level_roofline", "forest_bin_roofline"]
+
+
 def test_the_manifest_lists_the_readers_last_and_finds_them():
-    # each PR appends: PR 26's eight readers in their order, then PR 29's four
+    # each PR appends: PR 26's eight readers in their order, PR 29's four, PR 33's six
     names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-len(READERS + KMEANS_READERS):] == READERS + KMEANS_READERS
+    appended = READERS + KMEANS_READERS + FOREST_READERS
+    assert names[-len(appended):] == appended
     assert mf.problems(MANIFEST) == []
-    for m in MANIFEST["per_layer"][-len(READERS + KMEANS_READERS):]:
+    for m in MANIFEST["per_layer"][-len(appended):]:
         assert m["moves"] == "fit_s" and m["workloads"]
         assert m["better"] == ("higher" if m["name"].endswith("_roofline") else "lower")
 
@@ -194,3 +200,59 @@ def test_compile_s_counts_an_overlap_once():
               ("compile[backend_compile]", 5.0, 6.0), ("compile[cache_read]", 5.2, 5.3),
               ("lbfgs_eval", 0.0, 9.0)]
     assert read("compile_s", ctx_of([nested], compiles=1.0)) == pytest.approx(4.0)
+
+
+# two fits of a forest of two dispatched chunks of trees; the second fit's
+# chunks run a little longer
+FOREST_1 = [
+    ("fit_kernel", 0.0, 5.0), ("forest_bin", 0.0, 0.4),
+    ("forest_grow", 0.4, 2.4), ("forest_grow", 2.4, 4.4), ("forest_fetch", 4.4, 4.5),
+]
+FOREST_2 = [
+    ("fit_kernel", 10.0, 15.6), ("forest_bin", 10.0, 10.6),
+    ("forest_grow", 10.6, 12.8), ("forest_grow", 12.8, 15.0), ("forest_fetch", 15.0, 15.3),
+]
+FOREST_PARAMS = {"numTrees": 4, "maxDepth": 13, "featureSubsetStrategy": "auto"}
+# four chunk programs and the bin phase's 2 x 16 block programs and one sort
+FOREST_MODULES = {"jit__forest_fit_chunk": (8.0, 4), "jit__forest_sample_block": (0.1, 32),
+                  "jit__forest_bin_block": (0.5, 32), "jit__forest_edges": (0.2, 2),
+                  "jit__label_check_kernel": (0.3, 2)}
+
+
+def forest_ctx(fits, modules=FOREST_MODULES, facts=(1000, 1200)):
+    from chipbench import roofline
+
+    rfc = mf.adapter("rfc")
+    ctx = ctx_of(fits, modules, programs=rfc.PROGRAMS)
+    for f, n in zip(ctx["fits"], facts):
+        f["answer"] = {"fact": None if n is None else {"internal_nodes": n}}
+    ctx.update(work=rfc.work(500_000, 3_000, 1, FOREST_PARAMS), reference={},
+               traced_fits=len(fits) if modules is not None else 0,
+               peaks=roofline.peaks_for("TPU v5 lite"))
+    return ctx
+
+
+@pytest.mark.parametrize("name, by_hand", [
+    ("forest_bin_s", (0.4 + 0.6) / 2),
+    ("forest_grow_s", (4.0 + 4.4) / 2),
+    ("forest_fetch_s", (0.1 + 0.3) / 2),
+    ("forest_nodes_per_fit", 1100.0),
+    # 4 trees x 13 levels of 500,000 x 66 bytes at 819e9 a second, over 4 device seconds a fit
+    ("forest_level_roofline", 100 * (52 * 33e6 / 819e9) / 4.0),
+    # 7.5e9 bytes at 819e9 a second over (0.1 + 0.5 + 0.2) / 2 device seconds a fit
+    ("forest_bin_roofline", 100 * (7.5e9 / 819e9) / 0.4),
+])
+def test_forest_readers_by_hand(name, by_hand):
+    assert read(name, forest_ctx([FOREST_1, FOREST_2])) == pytest.approx(by_hand, rel=1e-12)
+
+
+@pytest.mark.parametrize("name", FOREST_READERS)
+def test_forest_readers_read_nothing_where_there_is_nothing(name):
+    """The parent (no span, no fact, no such program), another family's fits,
+    an untraced run: None, never a raise."""
+    bare = [("fit_kernel", 0.0, 5.0)]
+    assert read(name, forest_ctx([bare, bare], {"jit__forest_prep": (1.0, 2)}, (None, None))) is None
+    if name.endswith("_roofline"):
+        assert read(name, forest_ctx([FOREST_1, FOREST_2], None)) is None  # untraced
+    else:
+        assert read(name, ctx_of([FIT_1, FIT_2], MODULES)) is None

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import spark_rapids_ml_tpu.ops.lbfgs as lbfgs_mod
 import spark_rapids_ml_tpu.ops.logistic as logistic_mod
 from spark_rapids_ml_tpu import tracing
-from spark_rapids_ml_tpu.classification import LogisticRegression
+from spark_rapids_ml_tpu.classification import LogisticRegression, RandomForestClassifier
 from spark_rapids_ml_tpu.clustering import KMeans
 from spark_rapids_ml_tpu.config import reset_config, set_config
 from spark_rapids_ml_tpu.regression import LinearRegression
@@ -73,6 +73,12 @@ ROUTES = {
         (65_536, 64), "fit_kernel",
         {"kmeans_route[stepwise]", "kmeans_init", "kmeans_lloyd_iter",
          "kmeans_cost", "kmeans_fetch"},
+    ),
+    # the bins, every dispatched chunk of trees (here one), the fetch
+    "forest": (
+        lambda: RandomForestClassifier(numTrees=4, maxDepth=8, seed=1, num_workers=1),
+        (32_768, 16), "fit_kernel",
+        {"forest_bin", "forest_grow", "forest_fetch"},
     ),
     # 256 MB of rows, far over `_PIPELINED_MIN_BYTES`: the staging engine
     "pipelined_stage": (
