@@ -37,25 +37,254 @@ import numpy as np
 SUPPORTS_ZERO_WEIGHT_ROWS = True
 
 
+# Columns to a panel of the split Gram's two symmetric products (h^T h and
+# m^T m), a multiple of the MXU's 128: a panel multiplies itself and the
+# panels to its right only, so over p panels those products cost (p+1)/2p of
+# a whole pass.  On a v5e at 1M x 3000 (PERF.md §6, PR 34) the statistics
+# take 378 ms at 1,024 columns, 369 at 768, 360 at 512, 359 at 384, 372 at
+# 256, and 614 ms as XLA's `highest` makes them.
+_GRAM_PANEL_COLS = 512
+
+# rows to a block of the split Gram, past which a block only costs memory:
+# 65,536 x 3,000 x 3,000 is 1.2 TFLOP a pass, ~20 ms of MXU work a block
+# behind every ~100 us dispatch (76,924 and 83,334 rows read no faster)
+_MAX_GRAM_BLOCK_ROWS = 65_536
+
+
+def _moments(X, w, y, precision):
+    """(sxy, s1, sw, sy, syy) of weighted rows: every term carries `w`."""
+    Xw = X * w[:, None]
+    return (
+        jnp.matmul(Xw.T, y, precision=precision),  # (d,)
+        Xw.sum(axis=0),  # (d,)
+        w.sum(),
+        (y * w).sum(),
+        (y * y * w).sum(),
+    )
+
+
 @jax.jit
-def linreg_sufficient_stats(X: jax.Array, w: jax.Array, y: jax.Array):
-    """One pass: weighted Gram, moment, and cross terms.  X (N_pad,d)
-    row-sharded, w validity*sample weights, y labels (0 on padding)."""
+def _linreg_sufficient_stats_xla(X: jax.Array, w: jax.Array, y: jax.Array):
+    """The statistics in one program over all the rows, the Gram one
+    `jnp.matmul` at `stats_precision()`."""
     from .precision import stats_precision
 
     # the scope names the kernels in a profile (metadata only)
     with jax.named_scope("linreg_gram"):
-        Xw = X * w[:, None]
         # the normal equations invert this Gram: f32-exact products by
         # default (cuML parity; see ops/precision.py stats_precision)
         hi = stats_precision()
-        gram = jnp.matmul(Xw.T, X, precision=hi)  # (d,d) — MXU, psum over shards
-        sxy = jnp.matmul(Xw.T, y, precision=hi)  # (d,)
-        s1 = Xw.sum(axis=0)  # (d,)
-        sw = w.sum()
-        sy = (y * w).sum()
-        syy = (y * y * w).sum()
-    return gram, sxy, s1, sw, sy, syy
+        gram = jnp.matmul((X * w[:, None]).T, X, precision=hi)  # (d,d) — MXU, psum over shards
+        return (gram, *_moments(X, w, y, hi))
+
+
+def _bf16_parts(Z: jax.Array):
+    """Three bfloat16 arrays that add up to the float32 `Z` (to its last
+    bit but one or two: 24 significand bits in three of 8)."""
+    parts, rest = [], Z
+    for _ in range(3):
+        # reduce_precision, not a cast there and back: XLA may elide that
+        # pair (xla_allow_excess_precision) and leave no remainder
+        part = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
+        parts.append(part.astype(jnp.bfloat16))
+        rest = rest - part
+    return parts
+
+
+def _rows_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a^T b of bfloat16 (rows, p) and (rows, q): one MXU pass, every
+    product exact in the float32 it is accumulated in."""
+    return jax.lax.dot_general(
+        a, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def split_gram_half(Z: jax.Array, panel_cols: int = _GRAM_PANEL_COLS) -> jax.Array:
+    """A (d,d) float32 `A` with `A + A.T` the Gram Z^T Z of the float32
+    rows `Z`, as exact as XLA's `highest` makes it, in 3-and-a-bit bfloat16
+    MXU passes for its six.
+
+    `highest` splits each operand into three bfloat16 parts (z = h + m + l)
+    and adds the six products h^T h, h^T m, m^T h, m^T m, h^T l, l^T h.  For
+    the Gram of ONE matrix m^T h is the transpose of h^T m and l^T h of
+    h^T l: with P = h^T m + h^T l the same six terms are
+    h^T h + m^T m + P + P^T, four products.  h^T h and m^T m are symmetric
+    themselves, so a panel of `panel_cols` columns multiplies only itself
+    and the columns to its right, and its diagonal block is halved, since
+    `A + A.T` counts that one twice.  The caller adds the halves of its
+    row blocks and transposes once: `A + A.T` is symmetric bit for bit."""
+    h, m, l = _bf16_parts(Z)
+    d = Z.shape[1]
+    stairs = []
+    for lo in range(0, d, panel_cols):
+        width = min(panel_cols, d - lo)
+        sym = (_rows_dot(h[:, lo:lo + width], h[:, lo:])
+               + _rows_dot(m[:, lo:lo + width], m[:, lo:]))
+        sym = jnp.concatenate([0.5 * sym[:, :width], sym[:, width:]], axis=1)
+        stairs.append(jnp.pad(sym, ((0, 0), (lo, 0))))
+    return _rows_dot(h, m) + _rows_dot(h, l) + jnp.concatenate(stairs)
+
+
+def _linreg_sufficient_stats_block(acc, X, w, y, start, fresh_from,
+                                   rows: int, panel_cols: int):
+    """One row block's share of the statistics, added to acc = (half Gram
+    (d,d), sxy, s1, sw, sy, syy), each with or without a leading axis of
+    one (a device's own accumulators, `_split_block_program`).  The block is
+    rows [start, start + rows) of a device's own X, w and y: slices, so no
+    second copy of the rows.  Rows before `fresh_from` count for nothing:
+    the last block of a shard that the blocks do not tile starts early and
+    overlaps the one before it, so one program serves every block."""
+    with jax.named_scope("linreg_gram"):
+        Xb = jax.lax.dynamic_slice(
+            X, (start, jnp.zeros((), jnp.int32)), (rows, X.shape[1]))
+        wb = jax.lax.dynamic_slice(w, (start,), (rows,))
+        yb = jax.lax.dynamic_slice(y, (start,), (rows,))
+        wb = jnp.where(start + jnp.arange(rows, dtype=jnp.int32) >= fresh_from, wb, 0.0)
+        # Z^T Z = X^T diag(w) X; a zero-weight row is a row of zeros in Z
+        half = split_gram_half(Xb * jnp.sqrt(wb)[:, None], panel_cols)
+        part = (half, *_moments(Xb, wb, yb, jax.lax.Precision.HIGHEST))
+        return jax.tree.map(lambda a, p: a + p.reshape(a.shape), acc, part)
+
+
+@jax.jit
+def _linreg_sufficient_stats_finish(acc):
+    """The statistics from the accumulators of the row blocks: those with
+    a leading device axis are summed over it, the one sum that crosses
+    chips, and the half Gram meets its transpose."""
+    with jax.named_scope("linreg_gram"):
+        if acc[0].ndim == 3:
+            acc = jax.tree.map(lambda a: a.sum(axis=0), acc)
+        half, *moments = acc
+        return (half + half.T, *moments)
+
+
+@functools.lru_cache(maxsize=None)
+def _split_block_program(mesh, rows: int, panel_cols: int):
+    """`_linreg_sufficient_stats_block` over blocks of `rows` rows, jitted
+    under that name (the benchmark finds the Gram's device time by it), the
+    accumulators donated.  With a mesh the rows are sharded over its first
+    axis: every device slices the block out of ITS shard (`start` counts
+    from the shard's first row) into its own accumulators, stacked on a
+    leading device axis, and nothing crosses chips until
+    `_linreg_sufficient_stats_finish` sums that axis."""
+    block = functools.wraps(_linreg_sufficient_stats_block)(functools.partial(
+        _linreg_sufficient_stats_block, rows=rows, panel_cols=panel_cols))
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+
+        axis = mesh.axis_names[0]
+        block = jax.shard_map(
+            block, mesh=mesh,
+            in_specs=(P(axis), P(axis, None), P(axis), P(axis), P(), P()),
+            out_specs=P(axis), check_vma=False,
+        )
+    return jax.jit(block, donate_argnums=(0,))
+
+
+def gram_block_rows(X: jax.Array, shard_rows: int) -> int:
+    """Rows to a block of the split Gram, equal blocks that cover the
+    `shard_rows` rows a device holds: as many as half the memory the device
+    has left beside its shard pays for (a row's slice times sqrt(w) and its
+    three bfloat16 parts, 10 bytes a feature; an upper count, XLA fuses
+    some of it into the products), and no more than `_MAX_GRAM_BLOCK_ROWS`."""
+    from ..parallel.device_cache import bytes_beside
+
+    per_row = (X.dtype.itemsize + 6) * int(X.shape[1])
+    limit = max(1, min(bytes_beside(X) // 2 // per_row, _MAX_GRAM_BLOCK_ROWS, shard_rows))
+    return -(-shard_rows // -(-shard_rows // limit))
+
+
+def linreg_stats_split(X: jax.Array, w: jax.Array, y: jax.Array, mesh=None,
+                       block_rows: int = None, panel_cols: int = _GRAM_PANEL_COLS):
+    """The statistics of float32 rows with the Gram as `split_gram_half`
+    makes it, in row blocks read in place: the bfloat16 parts of 1M x 3000
+    rows are 18 GB, so they exist one block at a time (default
+    `gram_block_rows`: what fits beside the rows).  The host dispatches
+    one program a block; a `while_loop` over them would copy its invariant
+    rows (`parallel/device_cache.fused_program_fits`).  `mesh`: the mesh
+    whose first axis the rows are sharded over, None for one device."""
+    d = int(X.shape[1])
+    lead, placed = (), {}
+    if mesh is not None:  # one accumulator a device, stacked and so sharded
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        axis = mesh.axis_names[0]
+        lead = (mesh.shape[axis],)
+        placed = {"device": NamedSharding(mesh, PartitionSpec(axis))}
+    shard_rows = int(X.shape[0]) // (lead[0] if lead else 1)
+    rows = max(1, min(int(block_rows or gram_block_rows(X, shard_rows)), shard_rows))
+    program = _split_block_program(mesh, rows, int(panel_cols))
+    acc = tuple(
+        jnp.zeros(lead + shape, jnp.float32, **placed)
+        for shape in ((d, d), (d,), (d,), (), (), ())
+    )
+    for fresh_from in range(0, shard_rows, rows):
+        start = min(fresh_from, shard_rows - rows)
+        acc = program(acc, X, w, y, np.int32(start), np.int32(fresh_from))
+    return _linreg_sufficient_stats_finish(acc)
+
+
+def _on_tpu(X: jax.Array) -> bool:
+    return all(dev.platform == "tpu" for dev in X.devices())
+
+
+def gram_kernel_plan(X: jax.Array):
+    """(kernel, mesh, why): which Gram a fit's statistics take, read from
+    the rows and the precision level alone, and why (the `detail` of the
+    fit's `linreg_gram_kernel[...]` instant).  `symmetric_split` for
+    float32 rows wider than one panel at `stats_precision` `highest` on
+    TPUs, where XLA makes a `highest` product of six bfloat16 passes; `xla`,
+    the one `jnp.matmul` at the stated precision, anywhere else: a lower
+    level is fewer passes already, a CPU's or GPU's `highest` is a native
+    float32 product that a split only adds work to, and on a v5e at 1M
+    rows the split is 28.2 ms for XLA's 30.9 at 640 columns but 29.8 for
+    21.1 at 512 (57 for 71 at 1,000, 175 for 278 at 2,000; PERF.md §6)."""
+    from jax.sharding import NamedSharding
+
+    from .precision import stats_precision
+
+    precision = stats_precision()
+    facts = f"{X.dtype} {tuple(X.shape)} at {precision.name.lower()}"
+    if X.dtype != jnp.float32:
+        return "xla", None, f"{facts}: the split is of float32 into bfloat16 parts"
+    if precision != jax.lax.Precision.HIGHEST:
+        return "xla", None, f"{facts}: fewer passes than the split already"
+    if not _on_tpu(X):
+        return "xla", None, (
+            f"{facts}: backend {jax.default_backend()}, not a TPU: `highest` is "
+            "a native float32 product there"
+        )
+    if X.shape[1] <= _GRAM_PANEL_COLS:
+        return "xla", None, (
+            f"{facts}: one panel of {_GRAM_PANEL_COLS} columns has no symmetric "
+            "half to skip, and the parts' traffic costs more than two passes there"
+        )
+    if len(X.devices()) == 1:
+        return "symmetric_split", None, f"{facts}: Z^T Z of one matrix on one TPU"
+    sharding = X.sharding
+    if isinstance(sharding, NamedSharding):
+        mesh, spec = sharding.mesh, tuple(sharding.spec)
+        if spec[:1] == (mesh.axis_names[0],) and not any(spec[1:]):
+            return "symmetric_split", mesh, (
+                f"{facts}: Z^T Z of one matrix, rows over {len(X.devices())} TPUs, "
+                "one sum across them"
+            )
+    return "xla", None, f"{facts}: rows not sharded over a mesh's first axis alone ({sharding})"
+
+
+def linreg_sufficient_stats(X: jax.Array, w: jax.Array, y: jax.Array):
+    """Weighted Gram, moment, and cross terms (gram, sxy, s1, sw, sy, syy)
+    in one read of the rows.  X (N_pad,d) row-sharded, w validity*sample
+    weights (non-negative), y labels (0 on padding).  Which Gram kernel ran
+    is a fact of the fit: the instant `linreg_gram_kernel[symmetric_split|xla]`
+    (`gram_kernel_plan`)."""
+    from ..tracing import event
+
+    kernel, mesh, why = gram_kernel_plan(X)
+    event(f"linreg_gram_kernel[{kernel}]", detail=why)
+    if kernel == "xla":
+        return _linreg_sufficient_stats_xla(X, w, y)
+    return linreg_stats_split(X, w, y, mesh)
 
 
 def _soft_threshold(v: np.ndarray, t: float) -> np.ndarray:

@@ -69,12 +69,15 @@ def stats_precision() -> jax.lax.Precision:
     matrix inversion or eigendecomposition (PCA covariance, the linear-
     regression Gram/cross terms; in-memory AND streaming accumulators).
     cuML computes these in fp32; a default bf16 pass costs eigenvector/
-    coefficient fidelity for almost nothing — the Gram is <1 s of device
-    time even at the reference's 1M x 3000 config.  Config key
-    `stats_precision`, default "highest"; "high" (3-pass bf16) trades
-    ~2^-14 relative error for ~2x on very large-d grams;
-    "high_compensated" adds Kahan-compensated chunk accumulation on top
-    of the 3-pass bf16 products (see `stats_compensated`)."""
+    coefficient fidelity.  What "highest" costs: six bf16 MXU passes as
+    XLA makes an f32 product, about 3.1 for the same six terms where the
+    linear-regression Gram takes `ops/linear.split_gram_half` (f32 rows
+    wider than a panel on TPUs: 0.36 s of device time at the reference's
+    1M x 3000 on a v5e, PERF.md §6).  Config key `stats_precision`,
+    default "highest"; "high" is three passes and drops terms (~2^-14
+    relative error); "high_compensated" adds Kahan-compensated chunk
+    accumulation on top of the 3-pass bf16 products (see
+    `stats_compensated`)."""
     name = str(get_config("stats_precision")).lower()
     if name not in _STATS_LEVELS:
         raise ValueError(

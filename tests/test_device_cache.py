@@ -403,7 +403,15 @@ def test_ops_zero_weight_row_invariance(rng):
     stats_b = linear_ops.linreg_sufficient_stats(
         jnp.asarray(Xz), jnp.asarray(wz), jnp.asarray(yz)
     )
-    for a, b in zip(stats_a, stats_b):
+    # the split Gram too, a TPU's path (called directly: a CPU fit takes the
+    # single matmul), its row blocks not tiling the rows
+    split_a = linear_ops.linreg_stats_split(
+        jnp.asarray(X), jnp.asarray(w), jnp.asarray(y), block_rows=32, panel_cols=2
+    )
+    split_b = linear_ops.linreg_stats_split(
+        jnp.asarray(Xz), jnp.asarray(wz), jnp.asarray(yz), block_rows=32, panel_cols=2
+    )
+    for a, b in [*zip(stats_a, stats_b), *zip(split_a, split_b), *zip(split_a, stats_a)]:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
 

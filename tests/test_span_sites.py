@@ -251,6 +251,12 @@ def _lowered_logistic_vg(monkeypatch):
     return vg_fn.lower(jnp.zeros(9, jnp.float32), Xd, w, yd)
 
 
+def _gram_acc(d, lead=()):
+    """The split Gram's accumulators (ops/linear.py `linreg_stats_split`)."""
+    return tuple(jnp.zeros(lead + shape, jnp.float32)
+                 for shape in ((d, d), (d,), (d,), (), (), ()))
+
+
 def test_programs_keep_their_scopes_and_module_names(monkeypatch):
     from chipbench import manifest as mf
 
@@ -259,6 +265,7 @@ def test_programs_keep_their_scopes_and_module_names(monkeypatch):
 
     X, y = _rows(256, 8)
     Xd, yd, w = jnp.asarray(X), jnp.asarray(y), jnp.ones(256, jnp.float32)
+    at0 = jnp.asarray(0, jnp.int32)
     lowered = {
         "jit_vg_fn": (_lowered_logistic_vg(monkeypatch), {"lbfgs_eval"}),
         "jit_logreg_fit_binary": (
@@ -269,8 +276,16 @@ def test_programs_keep_their_scopes_and_module_names(monkeypatch):
                 Xd, w, yd.astype(jnp.int32), n_classes=3, l2=1e-5, l1=0.0,
                 max_iter=2),
             {"lbfgs_eval", "lbfgs_two_loop"}),
-        "jit_linreg_sufficient_stats": (
-            linear.linreg_sufficient_stats.lower(Xd, w, yd), {"linreg_gram"}),
+        # every program that holds Gram work: the single matmul's, and the
+        # split's row-block program (alone and under a mesh) and its finish
+        "jit__linreg_sufficient_stats_xla": (
+            linear._linreg_sufficient_stats_xla.lower(Xd, w, yd), {"linreg_gram"}),
+        "jit__linreg_sufficient_stats_block": (
+            linear._split_block_program(None, 64, 4).lower(
+                _gram_acc(8), Xd, w, yd, at0, at0),
+            {"linreg_gram"}),
+        "jit__linreg_sufficient_stats_finish": (
+            linear._linreg_sufficient_stats_finish.lower(_gram_acc(8)), {"linreg_gram"}),
         "jit_linreg_residual_sse": (
             linear.linreg_residual_sse.lower(Xd, w, yd, jnp.zeros(8), 0.0),
             {"linreg_residual"}),
@@ -279,14 +294,24 @@ def test_programs_keep_their_scopes_and_module_names(monkeypatch):
                 Xd, Xd[:16], jnp.asarray(0, jnp.int32)),
             {"stage_chunk"}),
     }
-    for module, (low, scopes) in lowered.items():
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",))
+    on_a_mesh = linear._split_block_program(mesh, 64, 4).lower(
+        _gram_acc(8, lead=(2,)), Xd, w, yd, at0, at0)
+    for module, (low, scopes) in [
+        *lowered.items(), ("jit__linreg_sufficient_stats_block", (on_a_mesh, {"linreg_gram"})),
+    ]:
         # the name XLA gives the program, which the profiler's "XLA Modules"
         # line and chipbench/estimators/*.PROGRAMS go by
         assert re.search(r"module @(\S+)", low.as_text()).group(1) == module
         located = set(re.findall(r'loc\("([^"]+)"', low.as_text(debug_info=True)))
         for scope in scopes:
-            assert any(re.search(rf"[/(]{scope}[/)]", loc) for loc in located), (
+            # (a program under `shard_map` starts its paths at the scope)
+            assert any(re.search(rf"(^|[/(]){scope}[/)]", loc) for loc in located), (
                 module, scope)
+    # the Gram's roofline divides by the device time of what its pattern
+    # matches: Gram work under another name would flatter it
+    (gram_pattern,) = mf.adapter("ridge").PROGRAMS["gram"]
+    assert sum(gram_pattern in module for module in lowered) == 3
     # every pattern the benchmark's adapters match finds its program
     for adapter in ("logreg", "ridge"):
         for patterns in mf.adapter(adapter).PROGRAMS.values():
