@@ -287,7 +287,9 @@ _DEFAULTS: Dict[str, Any] = {
     # eigh (cuML PCAMG parity, O(n d^2)); "randomized" = Halko
     # randomized range-finder (O(n d l), l = k + pca_oversamples) —
     # the tradeoff the reference's cuML MG path makes when k << d;
-    # "auto" (default) picks randomized when d is large and k small
+    # "auto" (default) picks randomized when d is large and k small,
+    # except on resident rows whose exact Gram and (d,d) eigensolve
+    # are each a fraction of a second, which get the exact answer
     # (see ops/pca.py resolve_pca_solver).
     "pca_solver": "auto",
     # Oversampling columns for the randomized range-finder (l = k +

@@ -1,7 +1,8 @@
 #
 # Feature transformers: PCA — the analog of reference feature.py (468 LoC).
 # The cuML PCAMG distributed fit (feature.py:240-261) is replaced by
-# ops/pca.py: one sharded Gram matmul + replicated eigh.
+# ops/pca.py: the covariance from the sharded rows in place + the top-k
+# eigenpairs of it on the host.
 #
 from __future__ import annotations
 
@@ -99,35 +100,59 @@ class PCA(PCAClass, _TpuEstimator, _PCATpuParams):
         self._set_params(**kwargs)
 
     def _fit_array(self, fit_input: FitInput) -> Dict[str, Any]:
-        from ..ops.pca import pca_fit, pca_fit_randomized, resolve_pca_solver
+        import jax
 
-        k = fit_input.params.get("n_components") or fit_input.pdesc.n
-        if k > fit_input.pdesc.n:
-            raise ValueError(f"k={k} exceeds the number of features {fit_input.pdesc.n}")
+        from ..ops.pca import (
+            pca_eigensolve_host,
+            pca_fit_randomized,
+            pca_scatter,
+            resolve_pca_solver,
+        )
+        from ..tracing import event, trace
+
+        d = fit_input.pdesc.n
+        k = fit_input.params.get("n_components") or d
+        if k > d:
+            raise ValueError(f"k={k} exceeds the number of features {d}")
         k = int(k)
-        # solver dispatch (conf pca_solver=auto|full|randomized): the
+        X, w = fit_input.X, fit_input.w
+        # solver dispatch (conf pca_solver=auto|full|randomized): resident
+        # rows whose Gram and (d,d) eigensolve are each a fraction of a
+        # second get the exact answer, as the reference's cuML MG path
+        # gives it; longer or wider than that, the
         # randomized range-finder scales the Gram work O(n d l) instead
-        # of O(n d^2) when k << d — the same tradeoff the reference's
-        # cuML MG path makes (ops/pca.py resolve_pca_solver)
+        # of O(n d^2) when k << d (ops/pca.py resolve_pca_solver)
         solver, l, power_iters, _reason = resolve_pca_solver(
-            fit_input.pdesc.n, k
+            d, k, resident_rows=int(X.shape[0]) // max(len(X.devices()), 1)
         )
         if solver == "randomized":
-            mean, components, ev, evr, sv = pca_fit_randomized(
-                fit_input.X, fit_input.w, k, int(l), int(power_iters)
-            )
+            out = pca_fit_randomized(X, w, k, int(l), int(power_iters))
         else:
-            mean, components, ev, evr, sv = pca_fit(
-                fit_input.X, fit_input.w, k
-            )
+            # the device's two passes, the copy to the host and the host's
+            # eigensolve each under a span of its own, as a ridge fit's
+            with trace("pca_covariance"):
+                stats = jax.block_until_ready(pca_scatter(X, w))
+            with trace("pca_fetch"):
+                scatter, s1, sw, shift = (np.asarray(a) for a in stats)
+            with trace("pca_eigensolve"):
+                event(
+                    "pca_eigensolver[host_lapack]",
+                    detail=f"dsyevr in float64 on the fetched ({d},{d}) "
+                    f"{scatter.dtype} covariance, top {k}: exact and never "
+                    "compiled (a v5e's own eigh of 3,000 columns is slower, "
+                    "less exact and compiles for minutes: PERF.md §6, PR 35)",
+                )
+                out = pca_eigensolve_host(scatter, s1, float(sw), shift, k)
+        dtype = np.dtype(fit_input.dtype)
+        mean, components, ev, evr, sv = (np.asarray(a).astype(dtype) for a in out)
         return {
-            "mean_": np.asarray(mean),
-            "components_": np.asarray(components),
-            "explained_variance_": np.asarray(ev),
-            "explained_variance_ratio_": np.asarray(evr),
-            "singular_values_": np.asarray(sv),
-            "n_cols": fit_input.pdesc.n,
-            "dtype": str(np.dtype(fit_input.dtype).name),
+            "mean_": mean,
+            "components_": components,
+            "explained_variance_": ev,
+            "explained_variance_ratio_": evr,
+            "singular_values_": sv,
+            "n_cols": d,
+            "dtype": str(dtype.name),
         }
 
     def _supports_streaming_stats(self) -> bool:
@@ -229,7 +254,7 @@ class PCA(PCAClass, _TpuEstimator, _PCATpuParams):
         """Beyond-HBM fit from multi-pass streamed second moments
         (streaming.py `pca_streaming_stats`): the dataset never resides in
         host RAM or HBM, only the (d,d) accumulator does.  The host
-        finalization replicates `ops/pca.py pca_fit` in float64."""
+        finalization is the resident fit's (`ops/pca.py pca_eigensolve_host`)."""
         from ..streaming import pca_streaming_stats
 
         fcol, fcols, _, weight_col, dtype = self._streaming_io_params()
@@ -250,24 +275,15 @@ class PCA(PCAClass, _TpuEstimator, _PCATpuParams):
         return self._attrs_from_moments(st, dtype)
 
     def _attrs_from_moments(self, st: Dict[str, Any], dtype) -> Dict[str, Any]:
-        S, s1, sw = np.asarray(st["S"]), np.asarray(st["s1"]), float(st["sw"])
+        """Finalize a streamed or fused fit from its second moments about
+        zero: the resident fit's host eigensolve with no shift."""
+        from ..ops.pca import pca_eigensolve_host
+
+        S, s1 = np.asarray(st["S"]), np.asarray(st["s1"])
         d = S.shape[0]
-        k = int(self._tpu_params.get("n_components") or d)
-        if k > d:
-            raise ValueError(f"k={k} exceeds the number of features {d}")
-        mean = s1 / sw
-        cov = (S - sw * np.outer(mean, mean)) / (sw - 1.0)
-        evals, evecs = np.linalg.eigh(cov)
-        evals = evals[::-1]
-        evecs = evecs[:, ::-1]
-        components = evecs[:, :k].T
-        flip_idx = np.argmax(np.abs(components), axis=1)
-        signs = np.sign(components[np.arange(k), flip_idx])
-        signs[signs == 0] = 1.0
-        components = components * signs[:, None]
-        ev = np.clip(evals[:k], 0.0, None)
-        evr = ev / np.clip(evals, 0.0, None).sum()
-        sv = np.sqrt(ev * (sw - 1.0))
+        mean, components, ev, evr, sv = pca_eigensolve_host(
+            S, s1, float(st["sw"]), np.zeros(d), self._resolved_k(d)
+        )
         dtype = np.dtype(dtype)
         return {
             "mean_": mean.astype(dtype),

@@ -52,21 +52,26 @@ _MAX_GRAM_BLOCK_ROWS = 65_536
 
 
 def _moments(X, w, y, precision):
-    """(sxy, s1, sw, sy, syy) of weighted rows: every term carries `w`."""
+    """(sxy, s1, sw, sy, syy) of weighted rows: every term carries `w`.
+    Rows without labels (`y` None: PCA's covariance) give (s1, sw)."""
     Xw = X * w[:, None]
+    s1, sw = Xw.sum(axis=0), w.sum()  # (d,), ()
+    if y is None:
+        return s1, sw
     return (
         jnp.matmul(Xw.T, y, precision=precision),  # (d,)
-        Xw.sum(axis=0),  # (d,)
-        w.sum(),
+        s1,
+        sw,
         (y * w).sum(),
         (y * y * w).sum(),
     )
 
 
 @jax.jit
-def _linreg_sufficient_stats_xla(X: jax.Array, w: jax.Array, y: jax.Array):
+def _linreg_sufficient_stats_xla(X: jax.Array, w: jax.Array, y, shift=None):
     """The statistics in one program over all the rows, the Gram one
-    `jnp.matmul` at `stats_precision()`."""
+    `jnp.matmul` at `stats_precision()`; of the rows less `shift` where one
+    is given."""
     from .precision import stats_precision
 
     # the scope names the kernels in a profile (metadata only)
@@ -74,6 +79,8 @@ def _linreg_sufficient_stats_xla(X: jax.Array, w: jax.Array, y: jax.Array):
         # the normal equations invert this Gram: f32-exact products by
         # default (cuML parity; see ops/precision.py stats_precision)
         hi = stats_precision()
+        if shift is not None:
+            X = X - shift
         gram = jnp.matmul((X * w[:, None]).T, X, precision=hi)  # (d,d) — MXU, psum over shards
         return (gram, *_moments(X, w, y, hi))
 
@@ -125,20 +132,25 @@ def split_gram_half(Z: jax.Array, panel_cols: int = _GRAM_PANEL_COLS) -> jax.Arr
     return _rows_dot(h, m) + _rows_dot(h, l) + jnp.concatenate(stairs)
 
 
-def _linreg_sufficient_stats_block(acc, X, w, y, start, fresh_from,
-                                   rows: int, panel_cols: int):
+def _linreg_sufficient_stats_block(acc, X, w, y, start, fresh_from, shift=None,
+                                   *, rows: int, panel_cols: int):
     """One row block's share of the statistics, added to acc = (half Gram
     (d,d), sxy, s1, sw, sy, syy), each with or without a leading axis of
-    one (a device's own accumulators, `_split_block_program`).  The block is
-    rows [start, start + rows) of a device's own X, w and y: slices, so no
+    one (a device's own accumulators, `_split_block_program`); without
+    labels (`y` None) acc = (half Gram, s1, sw).  The block is rows
+    [start, start + rows) of a device's own X, w and y: slices, so no
     second copy of the rows.  Rows before `fresh_from` count for nothing:
     the last block of a shard that the blocks do not tile starts early and
-    overlaps the one before it, so one program serves every block."""
+    overlaps the one before it, so one program serves every block.  With a
+    `shift` (d,) the statistics are of the block's rows less it: the
+    subtraction is of a slice, inside the pass that reads it."""
     with jax.named_scope("linreg_gram"):
         Xb = jax.lax.dynamic_slice(
             X, (start, jnp.zeros((), jnp.int32)), (rows, X.shape[1]))
+        if shift is not None:
+            Xb = Xb - shift
         wb = jax.lax.dynamic_slice(w, (start,), (rows,))
-        yb = jax.lax.dynamic_slice(y, (start,), (rows,))
+        yb = None if y is None else jax.lax.dynamic_slice(y, (start,), (rows,))
         wb = jnp.where(start + jnp.arange(rows, dtype=jnp.int32) >= fresh_from, wb, 0.0)
         # Z^T Z = X^T diag(w) X; a zero-weight row is a row of zeros in Z
         half = split_gram_half(Xb * jnp.sqrt(wb)[:, None], panel_cols)
@@ -159,14 +171,17 @@ def _linreg_sufficient_stats_finish(acc):
 
 
 @functools.lru_cache(maxsize=None)
-def _split_block_program(mesh, rows: int, panel_cols: int):
+def _split_block_program(mesh, rows: int, panel_cols: int,
+                         labelled: bool = True, shifted: bool = False):
     """`_linreg_sufficient_stats_block` over blocks of `rows` rows, jitted
     under that name (the benchmark finds the Gram's device time by it), the
     accumulators donated.  With a mesh the rows are sharded over its first
     axis: every device slices the block out of ITS shard (`start` counts
     from the shard's first row) into its own accumulators, stacked on a
     leading device axis, and nothing crosses chips until
-    `_linreg_sufficient_stats_finish` sums that axis."""
+    `_linreg_sufficient_stats_finish` sums that axis.  `labelled`: whether
+    its `y` is an array or None; `shifted`: whether a `shift` follows
+    `fresh_from` (a mesh's program has to know its arguments)."""
     block = functools.wraps(_linreg_sufficient_stats_block)(functools.partial(
         _linreg_sufficient_stats_block, rows=rows, panel_cols=panel_cols))
     if mesh is not None:
@@ -175,7 +190,8 @@ def _split_block_program(mesh, rows: int, panel_cols: int):
         axis = mesh.axis_names[0]
         block = jax.shard_map(
             block, mesh=mesh,
-            in_specs=(P(axis), P(axis, None), P(axis), P(axis), P(), P()),
+            in_specs=(P(axis), P(axis, None), P(axis), P(axis) if labelled else None,
+                      P(), P()) + ((P(),) if shifted else ()),
             out_specs=P(axis), check_vma=False,
         )
     return jax.jit(block, donate_argnums=(0,))
@@ -194,15 +210,18 @@ def gram_block_rows(X: jax.Array, shard_rows: int) -> int:
     return -(-shard_rows // -(-shard_rows // limit))
 
 
-def linreg_stats_split(X: jax.Array, w: jax.Array, y: jax.Array, mesh=None,
-                       block_rows: int = None, panel_cols: int = _GRAM_PANEL_COLS):
+def linreg_stats_split(X: jax.Array, w: jax.Array, y, mesh=None,
+                       block_rows: int = None, panel_cols: int = _GRAM_PANEL_COLS,
+                       shift=None):
     """The statistics of float32 rows with the Gram as `split_gram_half`
     makes it, in row blocks read in place: the bfloat16 parts of 1M x 3000
     rows are 18 GB, so they exist one block at a time (default
     `gram_block_rows`: what fits beside the rows).  The host dispatches
     one program a block; a `while_loop` over them would copy its invariant
     rows (`parallel/device_cache.fused_program_fits`).  `mesh`: the mesh
-    whose first axis the rows are sharded over, None for one device."""
+    whose first axis the rows are sharded over, None for one device.  `y`
+    None: rows without labels, (gram, s1, sw).  `shift` (d,): the
+    statistics of the rows less it."""
     d = int(X.shape[1])
     lead, placed = (), {}
     if mesh is not None:  # one accumulator a device, stacked and so sharded
@@ -213,14 +232,14 @@ def linreg_stats_split(X: jax.Array, w: jax.Array, y: jax.Array, mesh=None,
         placed = {"device": NamedSharding(mesh, PartitionSpec(axis))}
     shard_rows = int(X.shape[0]) // (lead[0] if lead else 1)
     rows = max(1, min(int(block_rows or gram_block_rows(X, shard_rows)), shard_rows))
-    program = _split_block_program(mesh, rows, int(panel_cols))
-    acc = tuple(
-        jnp.zeros(lead + shape, jnp.float32, **placed)
-        for shape in ((d, d), (d,), (d,), (), (), ())
-    )
+    program = _split_block_program(
+        mesh, rows, int(panel_cols), y is not None, shift is not None)
+    shapes = ((d, d), (d,), (d,), (), (), ()) if y is not None else ((d, d), (d,), ())
+    acc = tuple(jnp.zeros(lead + shape, jnp.float32, **placed) for shape in shapes)
+    more = () if shift is None else (shift,)
     for fresh_from in range(0, shard_rows, rows):
         start = min(fresh_from, shard_rows - rows)
-        acc = program(acc, X, w, y, np.int32(start), np.int32(fresh_from))
+        acc = program(acc, X, w, y, np.int32(start), np.int32(fresh_from), *more)
     return _linreg_sufficient_stats_finish(acc)
 
 
@@ -272,19 +291,21 @@ def gram_kernel_plan(X: jax.Array):
     return "xla", None, f"{facts}: rows not sharded over a mesh's first axis alone ({sharding})"
 
 
-def linreg_sufficient_stats(X: jax.Array, w: jax.Array, y: jax.Array):
+def linreg_sufficient_stats(X: jax.Array, w: jax.Array, y, shift=None):
     """Weighted Gram, moment, and cross terms (gram, sxy, s1, sw, sy, syy)
     in one read of the rows.  X (N_pad,d) row-sharded, w validity*sample
     weights (non-negative), y labels (0 on padding).  Which Gram kernel ran
     is a fact of the fit: the instant `linreg_gram_kernel[symmetric_split|xla]`
-    (`gram_kernel_plan`)."""
+    (`gram_kernel_plan`).  The Gram's second caller is PCA's covariance
+    (`ops/pca.pca_scatter`): no labels (`y` None gives (gram, s1, sw)), and
+    a `shift` (d,) near the mean that every row loses as it is read."""
     from ..tracing import event
 
     kernel, mesh, why = gram_kernel_plan(X)
     event(f"linreg_gram_kernel[{kernel}]", detail=why)
     if kernel == "xla":
-        return _linreg_sufficient_stats_xla(X, w, y)
-    return linreg_stats_split(X, w, y, mesh)
+        return _linreg_sufficient_stats_xla(X, w, y, shift)
+    return linreg_stats_split(X, w, y, mesh, shift=shift)
 
 
 def _soft_threshold(v: np.ndarray, t: float) -> np.ndarray:

@@ -2,9 +2,10 @@
 # PCA kernel — the TPU-native replacement for `cuml.decomposition.pca_mg.
 # PCAMG.fit` (called from reference feature.py:240-261).  The cuML MG kernel
 # computes a distributed covariance then an eigendecomposition with NCCL
-# reductions; here the Gram matrix of the row-sharded centered data is one
-# jnp matmul (XLA inserts the psum over ICI) and the k×k eigh runs
-# replicated on every chip.
+# reductions; here the second moments of the row-sharded data come from the
+# rows as they lie (the linear-regression Gram's programs, about a shift
+# near the mean, one sum across chips) and the top-k eigenpairs of the full
+# (d,d) covariance from LAPACK in float64 on the host.
 #
 from __future__ import annotations
 
@@ -22,38 +23,86 @@ import jax.numpy as jnp
 SUPPORTS_ZERO_WEIGHT_ROWS = True
 
 
-@partial(jax.jit, static_argnames=("k",))
-def pca_fit(X: jax.Array, w: jax.Array, k: int):
-    """Distributed PCA fit.
+# `auto` keeps the exact route for resident rows while BOTH of its costs are
+# a fraction of a second.  A device's share of the Gram, rows x d^2
+# multiply-adds, is at most `_EXACT_RESIDENT_GRAM`: 1M x 3000 is 0.9e13 and
+# 0.36 s of a v5e's time (PERF.md §5).  And d is at most
+# `_EXACT_RESIDENT_COLS`, since nothing of the eigensolve shrinks with the
+# rows: a (d,d) float32 Gram on every device, its float64 copy on the host
+# and 4/3 d^3 FLOP of dsyevr there, 0.28 s at 3,000 columns on the chip's
+# host (PERF.md §6), so by d^3 0.7 s, 67 MB and 134 MB at 4,096, where
+# 50,000 columns would be 10 GB, 20 GB and an hour.  Inside both the exact
+# answer costs what the range-finder's saving does not repay; past either
+# the rule is the threshold below, as it was.
+_EXACT_RESIDENT_GRAM = 1 << 44
+_EXACT_RESIDENT_COLS = 4096
 
-    X: (N_pad, d) rows sharded over the data axis, zero-padded.
-    w: (N_pad,) validity weights (0 for padded rows).
-    Returns (mean (d,), components (k,d), explained_variance (k,),
-             explained_variance_ratio (k,), singular_values (k,)).
 
-    The d×d covariance keeps all FLOPs in one MXU-friendly matmul; the
-    eigendecomposition of the small replicated matrix matches the
-    reference's strategy (distributed cov + replicated eig,
-    SURVEY.md §2.11 row 1).
-    """
-    wsum = w.sum()
-    mean = (X * w[:, None]).sum(axis=0) / wsum
-    from .precision import stats_precision
+@jax.jit
+def _pca_covariance_shift(X: jax.Array, w: jax.Array):
+    """The weighted column means in the rows' own precision: the shift the
+    covariance pass subtracts from every row as it reads it.  One read of
+    the rows in place (a product with `w` fused into the sum)."""
+    with jax.named_scope("pca_covariance"):
+        return (X * w[:, None]).sum(axis=0) / w.sum()
 
-    # sqrt-weighted centering keeps cov = A^T A symmetric in one matmul;
-    # padded rows have w=0 and drop out.  stats_precision(): f32-exact
-    # covariance by default (cuML parity; see ops/precision.py)
-    A = (X - mean) * jnp.sqrt(w)[:, None]
-    cov = jnp.matmul(A.T, A, precision=stats_precision()) / (wsum - 1.0)
-    evals, evecs = jnp.linalg.eigh(cov)  # ascending order
-    evals = evals[::-1]
-    evecs = evecs[:, ::-1]
-    components = _svd_flip(evecs[:, :k].T)  # (k, d), deterministic sign
-    explained_variance = jnp.clip(evals[:k], 0.0, None)
-    total_var = jnp.clip(evals, 0.0, None).sum()
-    explained_variance_ratio = explained_variance / total_var
-    singular_values = jnp.sqrt(explained_variance * (wsum - 1.0))
-    return mean, components, explained_variance, explained_variance_ratio, singular_values
+
+def pca_scatter(X: jax.Array, w: jax.Array):
+    """(scatter (d,d), s1 (d,), sw, shift (d,)) of the resident rows, on
+    the devices: scatter = sum_i w_i (x_i - shift)(x_i - shift)^T and
+    s1 = sum_i w_i (x_i - shift), from two reads of the rows where they
+    lie and no centred copy of them.
+
+    The second moments are the linear-regression Gram of rows without
+    labels (`ops/linear.linreg_sufficient_stats`: the same row-block
+    programs, `split_gram_half` where `gram_kernel_plan` says so, its
+    `linreg_gram_kernel[...]` instant), so zero-weight rows are absent.
+    They are taken about `shift`, the mean as float32 sums give it, so a
+    column at 100 +- 1 loses no digit of its variance to cancellation;
+    what the shift misses of the true mean, delta = s1 / sw, the host
+    removes exactly: covariance = (scatter - sw delta delta^T) / (sw - 1)
+    (`pca_eigensolve_host`)."""
+    from .linear import linreg_sufficient_stats
+
+    shift = _pca_covariance_shift(X, w)
+    scatter, s1, sw = linreg_sufficient_stats(X, w, None, shift=shift)
+    return scatter, s1, sw, shift
+
+
+def pca_eigensolve_host(scatter, s1, sw: float, shift, k: int):
+    """The top-k eigenpairs of the full covariance, in float64 on the host,
+    from `pca_scatter`'s statistics as fetched: LAPACK's dsyevr (a
+    tridiagonalisation of the whole matrix, then only the k pairs asked
+    for) on the one float64 buffer the centring makes.  Through scipy's
+    LAPACK, as the ridge solve (`ops/linear._quadratic_form` has why).
+
+    Returns (mean, components (k,d), explained_variance,
+    explained_variance_ratio, singular_values), float64: signs by
+    `_svd_flip`, the variances over sw - 1, the ratio over the covariance's
+    exact trace.  The streamed, fused and CSR fits finish here too, with
+    no shift (`models/feature.PCA._attrs_from_moments`): their ratio was
+    over the sum of ALL the eigenvalues, clipped at zero, which is the
+    trace but for the negative ones rounding leaves (1e-16 of it)."""
+    import numpy as np
+    from scipy.linalg import eigh
+
+    from .linear import _fortran_view, _normal_system
+
+    sw = float(sw)
+    delta = np.asarray(s1, np.float64) / sw
+    mean = np.asarray(shift, np.float64) + delta
+    A = _normal_system(np.asarray(scatter), sw, delta, None, 0.0)
+    d = A.shape[0]
+    total = float(np.trace(A))
+    evals, evecs = eigh(
+        _fortran_view(A), lower=True, overwrite_a=True, check_finite=False,
+        subset_by_index=(d - k, d - 1), driver="evr",
+    )
+    components = _svd_flip(evecs[:, ::-1].T, xp=np)
+    scattered = np.clip(evals[::-1], 0.0, None)
+    ev = scattered / (sw - 1.0)
+    evr = scattered / max(total, 1e-300)
+    return mean, components, ev, evr, np.sqrt(scattered)
 
 
 # ---------------------------------------------------------------------------
@@ -63,7 +112,8 @@ def pca_fit(X: jax.Array, w: jax.Array, k: int):
 # (auto|full|randomized) + `pca_oversamples` + `pca_power_iters`.
 # ---------------------------------------------------------------------------
 
-def resolve_pca_solver(d: int, k: int, streamed: bool = False):
+def resolve_pca_solver(d: int, k: int, streamed: bool = False,
+                       resident_rows: int = None):
     """(solver, l, power_iters, reason) from the `pca_solver` conf.
 
     "auto" picks the randomized range-finder when its total Gram work —
@@ -72,8 +122,16 @@ def resolve_pca_solver(d: int, k: int, streamed: bool = False):
     otherwise the exact full solver (identical to cuML PCAMG).
     `streamed=True` (the fused/streaming paths, where every randomized
     pass RE-READS the source — chunk decode is not free like a resident
-    array) demands a 16x margin before auto switches.  The decision is
-    the run's `pca_solver` fact, the fit report's `solver_decision`."""
+    array) demands a 16x margin before auto switches.  `resident_rows`
+    (the rows one device holds of a resident fit): while its share of the
+    exact Gram, rows x d^2, is within `_EXACT_RESIDENT_GRAM` and d within
+    `_EXACT_RESIDENT_COLS` (the (d,d) matrix and the host's d^3
+    eigensolve do not shrink with the rows), both a fraction of a second,
+    auto gives the reference's exact answer and not a 13-column sketch's
+    (on the reference's low-rank rows that sketch is 1e-3 off where
+    float32 is 1e-6, PERF.md §4); wider or longer resident rows keep the
+    threshold.  The decision is the run's `pca_solver` fact, the fit
+    report's `solver_decision`; its `reason` carries both bounds."""
     from ..config import get_config
     from ..tracing import fact
 
@@ -91,6 +149,11 @@ def resolve_pca_solver(d: int, k: int, streamed: bool = False):
         solver, reason = "randomized", "forced"
     elif mode == "full":
         solver, reason = "full", "forced"
+    elif (resident_rows is not None and d <= _EXACT_RESIDENT_COLS
+          and resident_rows * d * d <= _EXACT_RESIDENT_GRAM):
+        solver, reason = "full", (
+            f"auto:resident {int(resident_rows)}x{d}^2"
+            f"<=2^{_EXACT_RESIDENT_GRAM.bit_length() - 1},d<={_EXACT_RESIDENT_COLS}")
     elif l < d and d >= threshold:
         solver, reason = "randomized", f"auto:d>={threshold}"
     else:
@@ -122,7 +185,10 @@ def pca_fit_randomized(
 ):
     """Randomized PCA fit on staged (row-sharded) data.
 
-    Same contract and return signature as `pca_fit`, but the spectrum is
+    X: (N_pad, d) rows sharded over the data axis, zero-padded; w: (N_pad,)
+    validity weights (0 for padded rows).  Returns (mean (d,), components
+    (k,d), explained_variance (k,), explained_variance_ratio (k,),
+    singular_values (k,)), as the exact route does, but the spectrum is
     extracted from an l-dimensional sketch: Y = (A^T A) Ω for a fixed
     Gaussian Ω (deterministic seed — same data, same components), then
     `power_iters` QR-renormalized subspace iterations, a final

@@ -152,9 +152,15 @@ def test_randomized_vs_full_parity_across_settings(rng):
     for solver in ("full", "randomized", "auto"):
         set_config(pca_solver=solver, fused_stage_solve="off")
         models[solver] = PCA(k=3).setInputCol("features").fit(X)
-    # auto at d=256, k=3, l=13, p=2: threshold 4*13*4=208 <= 256
+    # auto at d=256, k=3, l=13, p=2: the threshold 4*13*4=208 <= 256 would
+    # sketch, but resident rows whose Gram is a fraction of a second get the
+    # exact answer (ops/pca.py `_EXACT_RESIDENT_GRAM`); the streamed paths
+    # keep the threshold (test_pca.py's
+    # test_auto_is_exact_on_resident_rows_whose_gram_is_cheap)
     decision = models["auto"].fit_report()["solver_decision"]
-    assert decision["solver"] == "randomized"
+    assert decision["solver"] == "full"
+    assert decision["reason"].startswith("auto:resident")
+    assert models["randomized"].fit_report()["solver_decision"]["solver"] == "randomized"
     _assert_pca_parity(models["randomized"], models["full"], ev_rtol=0.01)
     _assert_pca_parity(models["auto"], models["full"], ev_rtol=0.01)
     # ratios stay exact: total variance comes from the true trace, not

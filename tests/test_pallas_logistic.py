@@ -414,3 +414,58 @@ def test_four_chip_fused_fit_reads_its_shards_in_place(topo):
     # one all-reduce of [gradient, sum r, loss] per evaluation site
     reduces = [l for l in compiled.as_text().splitlines() if " all-reduce(" in l]
     assert len([l for l in reduces if f"f32[{d + 2}]" in l]) == 3, reduces
+
+
+# -- PCA's covariance (ops/pca.py `pca_scatter`), compiled for the same chip: the
+# programs a resident fit of 1M x 3000 runs hold no second copy of the rows
+# among their temporaries (a centred copy would be 12 GB of them)
+
+def _covariance_programs(mesh, spec, rows, d):
+    """(the mean pass, the shifted block program without labels, the finish),
+    lowered for `rows` x `d` resident rows."""
+    from spark_rapids_ml_tpu.ops import linear
+    from spark_rapids_ml_tpu.ops import pca as pca_ops
+
+    lead = () if mesh is None else (mesh.devices.size,)
+    acc = tuple(spec(lead + shape, lead_axis=bool(lead)) for shape in ((d, d), (d,), ()))
+    at = spec((), dtype=jnp.int32)
+    X, w = spec((rows, d), rows_axis=True), spec((rows,), rows_axis=True)
+    block = linear._split_block_program(mesh, 62_500, 512, False, True)
+    return (
+        pca_ops._pca_covariance_shift.lower(X, w),
+        block.lower(acc, X, w, None, at, at, spec((d,))),
+        linear._linreg_sufficient_stats_finish.lower(acc),
+    )
+
+
+def test_pca_covariance_reads_1m_x_3000_in_place_on_one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype=jnp.float32, **_):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    shift, block, finish = (
+        low.compile() for low in _covariance_programs(None, spec, 1_000_000, 3000))
+    assert shift.memory_analysis().temp_size_in_bytes < 1 << 20
+    # the block's bfloat16 parts and panels: 0.79 GB beside 12 GB of rows
+    assert block.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert finish.memory_analysis().temp_size_in_bytes < 128 << 20
+
+
+def test_pca_covariance_crosses_four_chips_once(topo):
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+
+    def spec(shape, dtype=jnp.float32, rows_axis=False, lead_axis=False):
+        pspec = P("data") if rows_axis or lead_axis else P()
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, pspec))
+
+    shift, block, finish = (
+        low.compile() for low in _covariance_programs(mesh, spec, 1_000_000, 3000))
+    assert shift.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert block.memory_analysis().temp_size_in_bytes < 1 << 30
+    # the blocks add into each chip's own accumulators; the mean pass and
+    # the finish are the sums that cross chips
+    assert " all-reduce(" not in block.as_text()
+    assert " all-reduce(" in shift.as_text() and " all-reduce(" in finish.as_text()

@@ -76,10 +76,15 @@ FOREST_READERS = ["forest_bin_s", "forest_grow_s", "forest_fetch_s", "forest_nod
                   "forest_level_roofline", "forest_bin_roofline"]
 
 
+# PR 35's, appended after them: the readers of the resident PCA's spans
+PCA_READERS = ["pca_covariance_s", "pca_eigensolve_s", "pca_fetch_s"]
+
+
 def test_the_manifest_lists_the_readers_last_and_finds_them():
-    # each PR appends: PR 26's eight readers in their order, PR 29's four, PR 33's six
+    # each PR appends: PR 26's eight readers in their order, PR 29's four,
+    # PR 33's six, PR 35's three
     names = [m["name"] for m in MANIFEST["per_layer"]]
-    appended = READERS + KMEANS_READERS + FOREST_READERS
+    appended = READERS + KMEANS_READERS + FOREST_READERS + PCA_READERS
     assert names[-len(appended):] == appended
     assert mf.problems(MANIFEST) == []
     for m in MANIFEST["per_layer"][-len(appended):]:
@@ -256,3 +261,51 @@ def test_forest_readers_read_nothing_where_there_is_nothing(name):
         assert read(name, forest_ctx([FOREST_1, FOREST_2], None)) is None  # untraced
     else:
         assert read(name, ctx_of([FIT_1, FIT_2], MODULES)) is None
+
+
+# two fits of the resident exact PCA: the covariance's passes, the fetch, the
+# host's eigensolve; the second fit's eigensolve runs a little longer
+PCA_1 = [
+    ("fit_kernel", 0.0, 2.0), ("pca_covariance", 0.0, 0.4),
+    ("linreg_gram_kernel[symmetric_split]", 0.0, 0.0), ("pca_fetch", 0.4, 0.5),
+    ("pca_eigensolve", 0.5, 1.9), ("pca_eigensolver[host_lapack]", 0.5, 0.5),
+]
+PCA_2 = [
+    ("fit_kernel", 10.0, 12.2), ("pca_covariance", 10.0, 10.4), ("pca_fetch", 10.4, 10.6),
+    ("pca_eigensolve", 10.6, 12.2),
+]
+# the shift pass, 16 row-block programs and a finish, a fit
+PCA_MODULES = {"jit__pca_covariance_shift": (0.04, 2), "jit__label_check_kernel": (0.3, 2),
+               "jit__linreg_sufficient_stats_block": (0.7, 32),
+               "jit__linreg_sufficient_stats_finish": (0.01, 2)}
+
+
+def pca_ctx(fits, modules=PCA_MODULES):
+    from chipbench import roofline
+
+    pca = mf.adapter("pca")
+    ctx = ctx_of(fits, modules, programs=pca.PROGRAMS)
+    ctx.update(work=pca.work(1_000_000, 3_000, 1, {"k": 3}), reference={},
+               traced_fits=len(fits) if modules is not None else 0,
+               peaks=roofline.peaks_for("TPU v5 lite"))
+    return ctx
+
+
+@pytest.mark.parametrize("name, by_hand", [
+    ("pca_covariance_s", 0.4),
+    ("pca_fetch_s", (0.1 + 0.2) / 2),
+    ("pca_eigensolve_s", (1.4 + 1.6) / 2),
+    # 1.8e13 FLOP at 197e12 a second over (0.04 + 0.7 + 0.01) / 2 device seconds
+    # a fit: the shift pass counts against the covariance, the label check does not
+    ("gram_roofline", 100 * (1.8e13 / 197e12) / 0.375),
+])
+def test_pca_readers_by_hand(name, by_hand):
+    assert read(name, pca_ctx([PCA_1, PCA_2])) == pytest.approx(by_hand, rel=1e-12)
+
+
+@pytest.mark.parametrize("name", PCA_READERS)
+def test_pca_readers_read_nothing_where_there_is_nothing(name):
+    """The parent (no such span): None, never a raise."""
+    bare = [("fit_kernel", 0.0, 5.0)]
+    assert read(name, pca_ctx([bare, bare])) is None
+    assert read(name, ctx_of([RIDGE, RIDGE], {})) is None

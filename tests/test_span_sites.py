@@ -23,6 +23,7 @@ from spark_rapids_ml_tpu import tracing
 from spark_rapids_ml_tpu.classification import LogisticRegression, RandomForestClassifier
 from spark_rapids_ml_tpu.clustering import KMeans
 from spark_rapids_ml_tpu.config import reset_config, set_config
+from spark_rapids_ml_tpu.feature import PCA
 from spark_rapids_ml_tpu.regression import LinearRegression
 from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 from spark_rapids_ml_tpu.telemetry.report import span_tree
@@ -65,6 +66,13 @@ ROUTES = {
         (131_072, 64), "fit_kernel",
         {"linreg_gram", "linreg_fetch", "linreg_host_solve", "linreg_residual",
          "linreg_solver[cholesky]"},
+    ),
+    # the resident exact PCA: the covariance's passes, the fetch, the host's
+    # eigensolve, and which Gram and which eigensolver ran
+    "pca": (
+        lambda: PCA(k=3, num_workers=1), (131_072, 64), "fit_kernel",
+        {"pca_covariance", "pca_fetch", "pca_eigensolve",
+         "linreg_gram_kernel[xla]", "pca_eigensolver[host_lapack]"},
     ),
     # the host-dispatched Lloyd, the route of rows a device cannot hold twice
     "kmeans_stepwise": (
@@ -151,6 +159,12 @@ def test_route_records_its_spans(route, monkeypatch):
         # which factorisation solved the system is a fact of the host solve
         solve = _find(first, "linreg_host_solve")
         assert [c["name"] for c in solve["children"]] == ["linreg_solver[cholesky]"]
+    if route == "pca":
+        # each instant under the span whose work it names
+        for span, instant in (("pca_covariance", "linreg_gram_kernel[xla]"),
+                              ("pca_eigensolve", "pca_eigensolver[host_lapack]")):
+            inside = [c["name"] for c in _find(first, span)["children"]]
+            assert [n for n in inside if not n.startswith("compile[")] == [instant]
 
     # a child lies inside its parent in time
     for node, parent in _walk(first["spans"]):
@@ -261,6 +275,7 @@ def test_programs_keep_their_scopes_and_module_names(monkeypatch):
     from chipbench import manifest as mf
 
     from spark_rapids_ml_tpu.ops import linear
+    from spark_rapids_ml_tpu.ops import pca as pca_ops
     from spark_rapids_ml_tpu.parallel import mesh as mesh_mod
 
     X, y = _rows(256, 8)
@@ -286,6 +301,10 @@ def test_programs_keep_their_scopes_and_module_names(monkeypatch):
             {"linreg_gram"}),
         "jit__linreg_sufficient_stats_finish": (
             linear._linreg_sufficient_stats_finish.lower(_gram_acc(8)), {"linreg_gram"}),
+        # PCA's covariance: the pass that makes the shift; its products are
+        # the Gram's programs above, without labels and with the shift
+        "jit__pca_covariance_shift": (
+            pca_ops._pca_covariance_shift.lower(Xd, w), {"pca_covariance"}),
         "jit_linreg_residual_sse": (
             linear.linreg_residual_sse.lower(Xd, w, yd, jnp.zeros(8), 0.0),
             {"linreg_residual"}),
@@ -297,8 +316,13 @@ def test_programs_keep_their_scopes_and_module_names(monkeypatch):
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",))
     on_a_mesh = linear._split_block_program(mesh, 64, 4).lower(
         _gram_acc(8, lead=(2,)), Xd, w, yd, at0, at0)
+    unlabelled = tuple(_gram_acc(8, lead=(2,))[i] for i in (0, 2, 3))
+    shifted_on_a_mesh = linear._split_block_program(mesh, 64, 4, False, True).lower(
+        unlabelled, Xd, w, None, at0, at0, jnp.zeros(8))
     for module, (low, scopes) in [
-        *lowered.items(), ("jit__linreg_sufficient_stats_block", (on_a_mesh, {"linreg_gram"})),
+        *lowered.items(),
+        ("jit__linreg_sufficient_stats_block", (on_a_mesh, {"linreg_gram"})),
+        ("jit__linreg_sufficient_stats_block", (shifted_on_a_mesh, {"linreg_gram"})),
     ]:
         # the name XLA gives the program, which the profiler's "XLA Modules"
         # line and chipbench/estimators/*.PROGRAMS go by
@@ -313,7 +337,7 @@ def test_programs_keep_their_scopes_and_module_names(monkeypatch):
     (gram_pattern,) = mf.adapter("ridge").PROGRAMS["gram"]
     assert sum(gram_pattern in module for module in lowered) == 3
     # every pattern the benchmark's adapters match finds its program
-    for adapter in ("logreg", "ridge"):
+    for adapter in ("logreg", "ridge", "pca"):
         for patterns in mf.adapter(adapter).PROGRAMS.values():
             for pattern in patterns:
                 assert any(pattern in module for module in lowered), pattern
@@ -345,14 +369,27 @@ def test_trace_opens_an_annotation_only_where_jax_is_loaded(monkeypatch):
 
 
 def test_a_span_costs_microseconds_without_a_profiler_session():
-    """A loose ceiling, not a speed claim: a fit records 50-150 of these."""
-    def spans(k):
+    """A loose ceiling, not a speed claim: a fit records 50-150 of these.
+    The machine is shared (five other test workers load it), so the span is
+    read against the same loop with a bare clock read in the span's place,
+    taken in turn with it: a host that runs everything five times slower
+    moves both.  The fastest of many short batches on each side."""
+    def per_pass(body, k=500):
         t0 = time.perf_counter()
         for _ in range(k):
-            with tracing.trace("cost"):
-                pass
+            body()
         return (time.perf_counter() - t0) / k
 
-    spans(200)
-    assert min(spans(2000) for _ in range(5)) < 20e-6
+    def span():
+        with tracing.trace("cost"):
+            pass
+
+    per_pass(span, 200)
+    spans, bare = [], []
+    for _ in range(20):
+        spans.append(per_pass(span))
+        bare.append(per_pass(time.perf_counter))
+    # 6.6 us a span on an idle host (PERF.md §6, PR 26) against ~0.05 us a
+    # clock read: 20 us, or 400 bare reads where those are slower than that
+    assert min(spans) < max(20e-6, 400 * min(bare)), (min(spans), min(bare))
     assert tracing.get_trace_events()[-1].thread_id == threading.get_ident()
