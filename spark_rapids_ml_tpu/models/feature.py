@@ -103,12 +103,12 @@ class PCA(PCAClass, _TpuEstimator, _PCATpuParams):
         import jax
 
         from ..ops.pca import (
-            pca_eigensolve_host,
+            pca_eigensolve_resident,
             pca_fit_randomized,
             pca_scatter,
             resolve_pca_solver,
         )
-        from ..tracing import event, trace
+        from ..tracing import trace
 
         d = fit_input.pdesc.n
         k = fit_input.params.get("n_components") or d
@@ -128,21 +128,16 @@ class PCA(PCAClass, _TpuEstimator, _PCATpuParams):
         if solver == "randomized":
             out = pca_fit_randomized(X, w, k, int(l), int(power_iters))
         else:
-            # the device's two passes, the copy to the host and the host's
-            # eigensolve each under a span of its own, as a ridge fit's
+            # the device's two passes, the copy to the host and the
+            # eigensolve (a device program of milliseconds, then the host)
+            # each under a span of its own, as a ridge fit's
             with trace("pca_covariance"):
                 stats = jax.block_until_ready(pca_scatter(X, w))
             with trace("pca_fetch"):
                 scatter, s1, sw, shift = (np.asarray(a) for a in stats)
             with trace("pca_eigensolve"):
-                event(
-                    "pca_eigensolver[host_lapack]",
-                    detail=f"dsyevr in float64 on the fetched ({d},{d}) "
-                    f"{scatter.dtype} covariance, top {k}: exact and never "
-                    "compiled (a v5e's own eigh of 3,000 columns is slower, "
-                    "less exact and compiles for minutes: PERF.md §6, PR 35)",
-                )
-                out = pca_eigensolve_host(scatter, s1, float(sw), shift, k)
+                out = pca_eigensolve_resident(
+                    stats[0], scatter, s1, float(sw), shift, k)
         dtype = np.dtype(fit_input.dtype)
         mean, components, ev, evr, sv = (np.asarray(a).astype(dtype) for a in out)
         return {

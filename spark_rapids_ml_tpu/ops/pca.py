@@ -5,7 +5,9 @@
 # reductions; here the second moments of the row-sharded data come from the
 # rows as they lie (the linear-regression Gram's programs, about a shift
 # near the mean, one sum across chips) and the top-k eigenpairs of the full
-# (d,d) covariance from LAPACK in float64 on the host.
+# (d,d) covariance from a block iteration that starts on the devices, where
+# those moments lie, and ends in float64 on the host, where a residual test
+# accepts the pairs or hands the matrix to LAPACK (`pca_eigensolve_resident`).
 #
 from __future__ import annotations
 
@@ -103,6 +105,208 @@ def pca_eigensolve_host(scatter, s1, sw: float, shift, k: int):
     ev = scattered / (sw - 1.0)
     evr = scattered / max(total, 1e-300)
     return mean, components, ev, evr, np.sqrt(scattered)
+
+
+# ---------------------------------------------------------------------------
+# The top k pairs of a RESIDENT covariance without a tridiagonalisation:
+# subspace iteration in float32 on the devices that hold it, then a
+# Rayleigh-Ritz polish in float64 on the host over the (d, b) block alone,
+# held to a residual test; LAPACK (`pca_eigensolve_host`) is the fallback.
+# ---------------------------------------------------------------------------
+
+# The block is the k pairs and as many again, at least `_SUBSPACE_BLOCK`
+# columns: the iteration gains lambda_{b+1} / lambda_k a step, so a wider
+# block is fewer float64 sweeps of more columns each.  On a v5e's host at
+# 3,000 columns with the reference's low-rank spectrum (PERF.md §6, PR 36)
+# the whole eigensolve reads 50-54 ms at 16 columns (four sweeps), 47-51 at
+# 32 (three; a seed in ten takes a fourth), 59-64 at 48 and 80-85 at 64
+# (three): a sweep is 10-12 ms whatever the block, bound by the widened
+# matrix's bytes, and the block's float64 QR grows from 1 to 10 ms.  The
+# device loop is 1.9 ms for 16 steps at 32 columns (1.2 for 8, 3.2 for 32):
+# float32 rounding over the gaps is reached by the eighth, and a spectrum
+# that needs more than 16 does not finish inside the polish's cap either.
+_SUBSPACE_BLOCK = 32
+_SUBSPACE_DEVICE_STEPS = 16
+# The route is tried from the width on at which it beat LAPACK on that host,
+# k = 3 (dsyevr and its float64 copy against the device loop, the block's
+# fetch and the polish): 2.4 ms against 3.9 at 256 columns, 4.9 against 5.5
+# at 384, 8.5 against 5.8 at 512, 12.2 against 8.5 at 640, 32 against 11 at
+# 1,024, 360 against 48 at 3,000.
+_SUBSPACE_MIN_COLS = 512
+# A pair is accepted when its residual over its gap to the nearest other Ritz
+# value, which bounds the sine of its angle to the eigenvector, is at most
+# this: a quarter of the float32 rounding the answers are cast to.  The
+# polish makes at most `_SUBSPACE_POLISH_CAP` sweeps.
+_SUBSPACE_TOL = 1e-8
+_SUBSPACE_POLISH_CAP = 6
+# float64 elements to a block of the polish's widening: 4 MB, which that
+# host's cache holds between the block's three products (a sweep at 32
+# columns: 13.7 ms with 2 MB blocks, 11.5 with 4, 10.9 with 8, 37 with 16)
+_WIDEN_BLOCK = 1 << 19
+
+
+def subspace_plan(d: int, k: int):
+    """(block, device steps, why not) for the top k pairs of a (d,d)
+    covariance, from k and d alone: the block iteration's shape, or block
+    0 where LAPACK keeps the matrix and the reason its instant carries.
+    The route is tried where the matrix is `_SUBSPACE_MIN_COLS` wide and
+    the block (whole sublanes of 8) at most a quarter of it: k = None, k
+    near d and every narrow matrix, whose tridiagonalisation is micro- to
+    milliseconds, never trace the device program."""
+    block = max(_SUBSPACE_BLOCK, 8 * -(-2 * k // 8))
+    if d < _SUBSPACE_MIN_COLS:
+        return 0, 0, f"narrow: d={d}<{_SUBSPACE_MIN_COLS}"
+    if 4 * block > d:
+        return 0, 0, f"k>d/8: k={k}, a block of {block}>d/4={d / 4:g}"
+    return block, _SUBSPACE_DEVICE_STEPS, ""
+
+
+@partial(jax.jit, static_argnames=("block", "steps"))
+def _pca_subspace_iterate(scatter: jax.Array, block: int, steps: int):
+    """`steps` of Q <- orth(scatter Q) from a fixed-key Gaussian (d, block)
+    start (the same rows give the same bits), on the devices that hold
+    `pca_scatter`'s (d,d) matrix, in float32 whatever its dtype (a start
+    needs no more), the products at `highest`: an orthonormal (d, block)
+    basis whose span holds the top eigenvectors to float32 rounding over
+    their gaps.  The scatter is about the float32 mean, so the rank-one
+    centring it lacks is rounding-sized; the polish applies it exactly."""
+    with jax.named_scope("pca_subspace"):
+        scatter = scatter.astype(jnp.float32)
+        start = jax.random.normal(
+            jax.random.PRNGKey(0), (scatter.shape[0], block), jnp.float32)
+
+        def step(_, Q):
+            return jnp.linalg.qr(
+                jnp.matmul(scatter, Q, precision=jax.lax.Precision.HIGHEST))[0]
+
+        return jax.lax.fori_loop(0, steps, step, start)
+
+
+def _centred_product(G, sw: float, delta, Qt):
+    """(A Q)^T for Q^T = `Qt` (b,d), Fortran-ordered float64, where
+    A = sym(G) - sw delta delta^T and sym(G) is the symmetric matrix whose
+    lower triangle is the Fortran-ordered view `G`'s: the triangle LAPACK
+    reads in `pca_eigensolve_host`, so a Gram whose [i,j] and [j,i] were
+    rounded apart (XLA's product) is one matrix on both routes.  `G` is
+    widened a block of columns at a time from the diagonal down
+    (`_WIDEN_BLOCK`): never a (d,d) float64 array, and half the widening.
+    Through scipy's BLAS (`ops/linear._quadratic_form` has why)."""
+    import numpy as np
+    from scipy.linalg.blas import dgemm, dgemv, dger, dsymm
+
+    d = G.shape[0]
+    Wt = np.zeros_like(Qt)
+    cols = max(1, _WIDEN_BLOCK // d)
+    for lo in range(0, d, cols):
+        hi = min(lo + cols, d)
+        # the diagonal block, of which BLAS reads the lower triangle ...
+        D = np.array(G[lo:hi, lo:hi], dtype=np.float64, order="F")
+        dsymm(1.0, D, Qt[:, lo:hi], beta=1.0, c=Wt[:, lo:hi], side=1, lower=1,
+              overwrite_c=1)
+        if hi < d:
+            # ... and the rows below it, as they lie and mirrored
+            L = np.array(G[hi:, lo:hi], dtype=np.float64, order="F")
+            dgemm(1.0, Qt[:, lo:hi], L, beta=1.0, c=Wt[:, hi:], trans_b=1, overwrite_c=1)
+            dgemm(1.0, Qt[:, hi:], L, beta=1.0, c=Wt[:, lo:hi], overwrite_c=1)
+    return dger(-sw, dgemv(1.0, Qt, delta), delta, a=Wt, overwrite_a=1)
+
+
+def pca_eigensolve_polished(scatter, s1, sw: float, shift, k: int, start):
+    """`pca_eigensolve_host`'s pairs of the same statistics, value for
+    value, from a (d,b) block `start` whose span is near the top
+    eigenvectors (`_pca_subspace_iterate`'s, as fetched), or None: in
+    float64, repeat Q = orth(Q), W = A Q (`_centred_product`: the exact
+    centring as a rank-one term), the (b,b) Rayleigh-Ritz problem of
+    Q^T W, next Q = W, until each of the top k Ritz pairs' residual
+    |A v - theta v| over its gap to the nearest other Ritz value is at
+    most `_SUBSPACE_TOL`.  dsyevr is an iteration run to a tolerance too;
+    this one is held to a float64 residual of the same matrix.
+
+    Returns (answer or None, sweeps made, the last bound).  None where
+    `_SUBSPACE_POLISH_CAP` sweeps do not reach the tolerance, or cannot at
+    the rate the bound falls: eigenvalues without gaps (iid rows:
+    lambda_{b+1} / lambda_k near 1; a multiple top eigenvalue) are
+    LAPACK's."""
+    import numpy as np
+    from scipy.linalg import eigh, qr
+    from scipy.linalg.blas import dgemm
+
+    from .linear import _fortran_view
+
+    sw = float(sw)
+    delta = np.asarray(s1, np.float64) / sw
+    G = _fortran_view(np.asarray(scatter))
+    Q = np.asarray(start, np.float64)
+    bound = np.float64(np.inf)
+    for sweep in range(1, _SUBSPACE_POLISH_CAP + 1):
+        Q = qr(Q, mode="economic", overwrite_a=True, check_finite=False)[0]
+        Qt = np.asfortranarray(Q.T)
+        Wt = _centred_product(G, sw, delta, Qt)
+        theta, S = eigh(dgemm(1.0, Qt, Wt, trans_b=1), check_finite=False)
+        theta, top = theta[::-1], S[:, ::-1][:, :k]
+        Vt = dgemm(1.0, top, Qt, trans_a=1)  # (k,d): the Ritz vectors
+        Rt = dgemm(1.0, top, Wt, trans_a=1) - theta[:k, None] * Vt
+        apart = np.abs(theta[:k, None] - theta[None, :])
+        apart[np.arange(k), np.arange(k)] = np.inf
+        with np.errstate(all="ignore"):
+            last, bound = bound, np.max(np.linalg.norm(Rt, axis=1) / apart.min(axis=1))
+            # The bound falls by `bound / last` a sweep at best (the fast
+            # modes die first): where the sweeps left cannot reach the
+            # tolerance at that rate the try ends here, which is at the
+            # cap, at a bound that stopped falling (or is no number), and
+            # two sweeps into a spectrum without gaps.
+            reach = bound * (bound / last) ** (_SUBSPACE_POLISH_CAP - sweep)
+        if bound <= _SUBSPACE_TOL:
+            break
+        if not reach <= _SUBSPACE_TOL:
+            return None, sweep, float(bound)
+        Q = Wt.T
+    total = float(np.asarray(G.diagonal(), np.float64).sum() - sw * (delta @ delta))
+    scattered = np.clip(theta[:k], 0.0, None)
+    mean = np.asarray(shift, np.float64) + delta
+    return (
+        (mean, _svd_flip(Vt, xp=np), scattered / (sw - 1.0),
+         scattered / max(total, 1e-300), np.sqrt(scattered)),
+        sweep, float(bound),
+    )
+
+
+def pca_eigensolve_resident(device_scatter: jax.Array, scatter, s1, sw: float,
+                            shift, k: int):
+    """The top-k eigenpairs of the full covariance from `pca_scatter`'s
+    statistics, on the devices (`device_scatter`) and as fetched: the
+    block iteration where `subspace_plan` tries it and its polish accepts,
+    else LAPACK on the same fetched matrix, the failed try paid (a device
+    program of milliseconds and at most `_SUBSPACE_POLISH_CAP` sweeps).
+    Which one answered is the fit's instant
+    `pca_eigensolver[subspace_polished|host_lapack]`; no conf key and no
+    name of a data model decides it.  Returns `pca_eigensolve_host`'s
+    tuple."""
+    import numpy as np
+
+    from ..tracing import event
+
+    d = int(scatter.shape[0])
+    block, steps, why = subspace_plan(d, k)
+    if block:
+        start = np.asarray(_pca_subspace_iterate(device_scatter, block, steps))
+        out, sweeps, bound = pca_eigensolve_polished(scatter, s1, sw, shift, k, start)
+        tried = (f"block={block} device_steps={steps} polish_steps={sweeps} "
+                 f"estimate={bound:.3g}")
+        if out is not None:
+            event(
+                "pca_eigensolver[subspace_polished]",
+                detail=f"{tried}: top {k} of the ({d},{d}) {scatter.dtype} covariance, "
+                f"residual over gap <= {_SUBSPACE_TOL:g} in float64",
+            )
+            return out
+        why = f"not_converged: {tried}"
+    event(
+        "pca_eigensolver[host_lapack]",
+        detail=f"{why}: dsyevr in float64 on the fetched ({d},{d}) "
+        f"{scatter.dtype} covariance, top {k}",
+    )
+    return pca_eigensolve_host(scatter, s1, sw, shift, k)
 
 
 # ---------------------------------------------------------------------------

@@ -469,3 +469,31 @@ def test_pca_covariance_crosses_four_chips_once(topo):
     # the finish are the sums that cross chips
     assert " all-reduce(" not in block.as_text()
     assert " all-reduce(" in shift.as_text() and " all-reduce(" in finish.as_text()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_pca_subspace_iteration_compiles_for_the_resident_covariance(topo, chips):
+    """The eigensolve's device half at the cell's shape (ops/pca.py
+    `_pca_subspace_iterate`, 3,000 columns, the block and steps
+    `subspace_plan` gives k = 3): it compiles for the chip, beside the
+    (d,d) matrix it holds megabytes, its loop stays a loop, and over four
+    chips, where every chip holds the matrix, nothing crosses them."""
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops import pca as pca_ops
+
+    block, steps, why = pca_ops.subspace_plan(3000, 3)
+    assert block and not why
+    if chips == 1:
+        held = SingleDeviceSharding(topo.devices[0])
+    else:
+        held = NamedSharding(Mesh(np.array(topo.devices).reshape(4), ("data",)), P())
+    compiled = pca_ops._pca_subspace_iterate.lower(
+        jax.ShapeDtypeStruct((3000, 3000), jnp.float32, sharding=held),
+        block=block, steps=steps).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 8 << 20
+    assert 3000 * block * 4 <= memory.output_size_in_bytes < 1 << 20  # rows padded to tiles
+    text = compiled.as_text()
+    assert " while(" in text
+    assert " all-reduce(" not in text and " all-gather(" not in text

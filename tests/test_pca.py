@@ -225,13 +225,17 @@ def _low_rank_rows(n_dev, rows, cols, seed=2**31 + 11):
     return mesh, datagen.make_rows(mesh, rows, cols, seed, LOW_RANK, 256)
 
 
-def _span_names(model):
+def _span_nodes(model):
     def walk(nodes):
         for n in nodes:
-            yield n["name"]
+            yield n
             yield from walk(n.get("children", []))
 
     return list(walk(model.fit_report()["spans"]))
+
+
+def _span_names(model):
+    return [n["name"] for n in _span_nodes(model)]
 
 
 @pytest.fixture
@@ -246,10 +250,11 @@ def pca_adapter(monkeypatch):
     reset_config()
 
 
-@pytest.mark.parametrize("cols,kernel", [(48, "xla"), (640, "symmetric_split")])
+@pytest.mark.parametrize("cols,kernel,eigensolver", [
+    (48, "xla", "host_lapack"), (640, "symmetric_split", "subspace_polished")])
 @pytest.mark.parametrize("n_dev", [1, 2])
-def test_resident_fit_agrees_with_the_plain_reference(n_dev, cols, kernel, pca_adapter,
-                                                       monkeypatch):
+def test_resident_fit_agrees_with_the_plain_reference(n_dev, cols, kernel, eigensolver,
+                                                       pca_adapter, monkeypatch):
     from spark_rapids_ml_tpu.data import DeviceDataset
     from spark_rapids_ml_tpu.ops import linear
 
@@ -262,7 +267,7 @@ def test_resident_fit_agrees_with_the_plain_reference(n_dev, cols, kernel, pca_a
     assert [n for n in names if n.startswith("linreg_gram_kernel[")] == [
         f"linreg_gram_kernel[{kernel}]"]
     assert [n for n in names if n.startswith("pca_eigensolver[")] == [
-        "pca_eigensolver[host_lapack]"]
+        f"pca_eigensolver[{eigensolver}]"]
     decision = model.fit_report()["solver_decision"]
     assert decision["solver"] == "full" and decision["reason"].startswith("auto:resident")
     ref = pca_adapter.reference(X, y, K3)
@@ -271,6 +276,155 @@ def test_resident_fit_agrees_with_the_plain_reference(n_dev, cols, kernel, pca_a
     low = pca_adapter.compare(pca_adapter.reference(X, y, K3, lowered=True), ref)
     assert any(low[k] > SMALL_LIMITS[k] for k in SMALL_LIMITS), low
     assert set(got) == set(SMALL_LIMITS)
+
+
+# ---------------------------------------------------------------------------
+# The block route (ops/pca.py `_pca_subspace_iterate` + `pca_eigensolve_polished`)
+# against LAPACK's (`pca_eigensolve_host`) on the SAME statistics, and the
+# rule that chooses between them (`subspace_plan`, the polish's residual test).
+# ---------------------------------------------------------------------------
+
+def _eigensolver_instants(model):
+    return [n for n in _span_names(model) if n.startswith("pca_eigensolver[")]
+
+
+def _instant_detail(model, name):
+    (detail,) = [n.get("detail", "") for n in _span_nodes(model) if n["name"] == name]
+    return detail
+
+
+@pytest.mark.parametrize("rows_are", ["plain", "weighted", "offset_column"])
+@pytest.mark.parametrize("n_dev", [1, 2])
+def test_the_block_route_gives_lapacks_pairs_of_the_same_statistics(n_dev, rows_are):
+    """640 columns of the low-rank rows, k = 3, XLA's product (whose [i,j]
+    and [j,i] are rounded apart: both routes read one triangle).  Before
+    the cast: components to 1e-8 with the same signs, variances, ratios and
+    singular values to 1e-10, the mean to the bit; then two fits of the same
+    rows through the estimator, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu.data import DeviceDataset
+    from spark_rapids_ml_tpu.ops import pca
+    from spark_rapids_ml_tpu.ops.linear import linreg_sufficient_stats
+
+    mesh, (X, y, w) = _low_rank_rows(n_dev, 4096, 640)
+    if rows_are == "weighted":
+        w = w * jax.random.uniform(jax.random.key(3), w.shape, w.dtype, 0.25, 4.0)
+    if rows_are == "offset_column":
+        X = X.at[:, 5].add(3.0)
+    scatter, s1, sw, shift = pca.pca_scatter(X, w)
+    if rows_are == "offset_column":
+        # ... and second moments about a shift 1e-3 off the mean in every
+        # column: the rank-one centring is no rounding any more (it moves
+        # the top eigenvalue by 6e-4 of itself, the gaps are 2e-2)
+        shift = shift + 1e-3
+        scatter, s1, sw = linreg_sufficient_stats(X, w, None, shift=shift)
+        assert abs(float(s1[0] / sw)) > 5e-4 and abs(float(shift[5])) > 2.9
+    if rows_are == "weighted":
+        # (X w)^T X: the two triangles are different roundings
+        assert float(jnp.max(jnp.abs(scatter - scatter.T))) > 0.0
+    block, steps, why = pca.subspace_plan(640, 3)
+    assert (block, why) == (pca._SUBSPACE_BLOCK, "")
+    host = [np.asarray(a) for a in (scatter, s1, sw, shift)]
+    start = np.asarray(pca._pca_subspace_iterate(scatter, block, steps))
+    got, sweeps, bound = pca.pca_eigensolve_polished(*host, 3, start)
+    want = pca.pca_eigensolve_host(*host, 3)
+    assert 1 <= sweeps <= pca._SUBSPACE_POLISH_CAP and bound <= pca._SUBSPACE_TOL
+    assert all(a.dtype == np.float64 for a in got)
+    np.testing.assert_array_equal(got[0], want[0])
+    assert np.max(np.linalg.norm(got[1] - want[1], axis=1)) <= 1e-8
+    for g, h in zip(got[2:], want[2:]):
+        np.testing.assert_allclose(g, h, rtol=1e-10, atol=0.0)
+
+    rows = DeviceDataset(mesh, X, 4096, y=y, weight=w)
+    first, again = PCA(k=3, num_workers=n_dev).fit(rows), PCA(k=3, num_workers=n_dev).fit(rows)
+    assert _eigensolver_instants(first) == ["pca_eigensolver[subspace_polished]"]
+    for name in ("mean_", "components_", "explained_variance_",
+                 "explained_variance_ratio_", "singular_values_"):
+        np.testing.assert_array_equal(getattr(first, name), getattr(again, name))
+    detail = _instant_detail(first, "pca_eigensolver[subspace_polished]")
+    assert f"block={block} device_steps={steps} polish_steps=" in detail
+    assert float(detail.split("estimate=")[1].split(":")[0]) <= pca._SUBSPACE_TOL
+
+
+def _double_top_rows(rng, n=4096, d=640):
+    """Rows whose two largest eigenvalues are equal but for float32
+    rounding: orthonormal columns with no mean, scaled 2, 2, 1.5, 1.4, ..."""
+    U = rng.standard_normal((n, d))
+    U -= U.mean(axis=0)
+    U = np.linalg.qr(U)[0]
+    s = np.concatenate([[2.0, 2.0], 1.5 * 0.93 ** np.arange(d - 2)])
+    V = np.linalg.qr(rng.standard_normal((d, d)))[0]
+    return ((U * s) @ V.T * np.sqrt(n)).astype(np.float32)
+
+
+@pytest.mark.parametrize("case", [
+    "iid_rows", "narrow", "k_none", "k_over_d_8", "double_top", "float64_rows"])
+def test_which_eigensolver_answers_is_read_from_shape_and_spectrum(case, rng, monkeypatch):
+    from spark_rapids_ml_tpu.data import DeviceDataset
+    from spark_rapids_ml_tpu.ops import pca
+
+    n, d, k = 4096, 640, 3
+    exact = 2e-6  # answers cast to float32
+    if case == "narrow":
+        d = 48
+    if case == "k_none":
+        k = None
+    if case == "k_over_d_8":
+        k = 81
+    if case == "double_top":
+        X = _double_top_rows(rng)
+    elif case == "float64_rows":
+        # float64 second moments: the device half is float32 all the same,
+        # and the polish ends at float64's pairs
+        X = rng.standard_normal((n, d)) * 0.9 ** np.arange(d) + 5.0
+        exact = 1e-9
+    else:
+        # iid rows: a Marchenko-Pastur bulk, lambda_{b+1} / lambda_k near 1
+        X = rng.standard_normal((n, d)).astype(np.float32)
+    if case in ("narrow", "k_none", "k_over_d_8"):
+        # by shape, before any device program is traced
+        def never(*a, **kw):
+            raise AssertionError("the block iteration was dispatched")
+
+        monkeypatch.setattr(pca, "_pca_subspace_iterate", never)
+    seen = []
+    real = pca.pca_eigensolve_host
+    monkeypatch.setattr(pca, "pca_eigensolve_host",
+                        lambda *a: seen.append(real(*a)) or seen[-1])
+    model = PCA(k=k, float32_inputs=case != "float64_rows").fit(
+        DeviceDataset.from_host(X, num_workers=1, dtype=X.dtype))
+    (instant,) = _eigensolver_instants(model)
+    detail = _instant_detail(model, instant)
+    if case == "float64_rows":
+        assert instant == "pca_eigensolver[subspace_polished]" and "float64 covariance" in detail
+        assert model.components_.dtype == np.float64
+    elif case == "double_top":
+        # either route: the polish's residual test decides, and does not hang
+        assert instant in ("pca_eigensolver[subspace_polished]", "pca_eigensolver[host_lapack]")
+        assert (instant == "pca_eigensolver[host_lapack]") == detail.startswith("not_converged")
+    else:
+        assert instant == "pca_eigensolver[host_lapack]"
+        assert detail.startswith({
+            "iid_rows": "not_converged: block=32 device_steps=", "narrow": "narrow: d=48<",
+            "k_none": "k>d/8: k=640,", "k_over_d_8": "k>d/8: k=81,"}[case]), detail
+        # LAPACK's own answer, not a polished one
+        for name, at in (("mean_", 0), ("components_", 1), ("explained_variance_", 2)):
+            np.testing.assert_array_equal(getattr(model, name), seen[0][at].astype(np.float32))
+    if case == "iid_rows":
+        sweeps = int(detail.split("polish_steps=")[1].split()[0])
+        assert 2 <= sweeps <= pca._SUBSPACE_POLISH_CAP
+        assert float(detail.split("estimate=")[1].split(":")[0]) > pca._SUBSPACE_TOL
+    # whoever answered, the pairs are eigenpairs of the rows' covariance
+    top = 3 if k is None else min(k, 3)
+    cov = np.cov(X.astype(np.float64), rowvar=False)
+    v = np.asarray(model.components_[:top], np.float64)
+    Cv = v @ cov
+    rayleigh = np.einsum("ij,ij->i", Cv, v)
+    evals = np.linalg.eigvalsh(cov)[::-1]
+    assert np.max(np.linalg.norm(Cv - rayleigh[:, None] * v, axis=1)) <= exact * evals[0]
+    np.testing.assert_allclose(model.explained_variance_[:top], evals[:top], rtol=exact)
 
 
 def _fault(name, pca_adapter):

@@ -74,6 +74,13 @@ ROUTES = {
         {"pca_covariance", "pca_fetch", "pca_eigensolve",
          "linreg_gram_kernel[xla]", "pca_eigensolver[host_lapack]"},
     ),
+    # ... and at 640 columns with gaps between the eigenvalues, where the block
+    # iteration and its float64 polish answer in LAPACK's place
+    "pca_subspace": (
+        lambda: PCA(k=3, num_workers=1), (8_192, 640), "fit_kernel",
+        {"pca_covariance", "pca_fetch", "pca_eigensolve",
+         "linreg_gram_kernel[xla]", "pca_eigensolver[subspace_polished]"},
+    ),
     # the host-dispatched Lloyd, the route of rows a device cannot hold twice
     "kmeans_stepwise": (
         lambda: KMeans(k=16, maxIter=4, tol=1e-20, initMode="random", seed=2,
@@ -134,6 +141,8 @@ def test_route_records_its_spans(route, monkeypatch):
         # 16.8 MB of rows, and a device that cannot hold them twice
         set_config(hbm_bytes=30_000_000)
     X, y = _rows(n, d)
+    if route == "pca_subspace":
+        X *= 0.9 ** np.arange(d, dtype=np.float32)  # eigenvalues 1, 0.81, 0.66, ...
 
     evaluations = []
     real = lbfgs_mod.lbfgs_minimize_host
@@ -159,10 +168,12 @@ def test_route_records_its_spans(route, monkeypatch):
         # which factorisation solved the system is a fact of the host solve
         solve = _find(first, "linreg_host_solve")
         assert [c["name"] for c in solve["children"]] == ["linreg_solver[cholesky]"]
-    if route == "pca":
-        # each instant under the span whose work it names
+    if route in ("pca", "pca_subspace"):
+        # each instant under the span whose work it names, the eigensolver's
+        # the one child of its span that is no compile, on either route
+        solver = "host_lapack" if route == "pca" else "subspace_polished"
         for span, instant in (("pca_covariance", "linreg_gram_kernel[xla]"),
-                              ("pca_eigensolve", "pca_eigensolver[host_lapack]")):
+                              ("pca_eigensolve", f"pca_eigensolver[{solver}]")):
             inside = [c["name"] for c in _find(first, span)["children"]]
             assert [n for n in inside if not n.startswith("compile[")] == [instant]
 
@@ -305,6 +316,10 @@ def test_programs_keep_their_scopes_and_module_names(monkeypatch):
         # the Gram's programs above, without labels and with the shift
         "jit__pca_covariance_shift": (
             pca_ops._pca_covariance_shift.lower(Xd, w), {"pca_covariance"}),
+        # ... and the eigensolve's device half, which is no Gram work
+        "jit__pca_subspace_iterate": (
+            pca_ops._pca_subspace_iterate.lower(jnp.zeros((8, 8)), block=4, steps=2),
+            {"pca_subspace"}),
         "jit_linreg_residual_sse": (
             linear.linreg_residual_sse.lower(Xd, w, yd, jnp.zeros(8), 0.0),
             {"linreg_residual"}),
@@ -336,6 +351,8 @@ def test_programs_keep_their_scopes_and_module_names(monkeypatch):
     # matches: Gram work under another name would flatter it
     (gram_pattern,) = mf.adapter("ridge").PROGRAMS["gram"]
     assert sum(gram_pattern in module for module in lowered) == 3
+    assert not any(pattern in "jit__pca_subspace_iterate"
+                   for pattern in mf.adapter("pca").PROGRAMS["gram"])
     # every pattern the benchmark's adapters match finds its program
     for adapter in ("logreg", "ridge", "pca"):
         for patterns in mf.adapter(adapter).PROGRAMS.values():
