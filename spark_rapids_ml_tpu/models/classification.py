@@ -1034,9 +1034,12 @@ class RandomForestClassifier(
     def _num_stat_classes(self, fit_input: FitInput) -> int:
         import jax
 
+        from ..tracing import trace
+
         # labels are validated >= 0; padded rows are 0, so a plain max works
         # (one scalar device->host fetch)
-        C = int(jax.device_get(fit_input.y.max())) + 1
+        with trace("label_range"):
+            C = int(jax.device_get(fit_input.y.max())) + 1
         self._n_classes_ = C
         return C
 

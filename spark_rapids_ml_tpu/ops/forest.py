@@ -748,7 +748,7 @@ def forest_fit(
 
     Spans (docs/observability.md): `forest_bin` (edges and bins), one
     `forest_grow` per dispatched chunk (ended when the chunk is built),
-    `forest_fetch`; one `fact[forest]` a fit."""
+    `forest_fetch`, `forest_assemble`; one `fact[forest]` a fit."""
     import numpy as np
 
     from ..parallel.device_cache import bytes_beside
@@ -818,20 +818,23 @@ def forest_fit(
         cat = np.concatenate(parts, axis=1)  # (ndev, trees_per_worker, ...)
         return cat.reshape((ndev * trees_per_worker,) + cat.shape[2:])
 
-    trees = TreeArrays(*(reassemble(f) for f in TreeArrays._fields))
-    internal = trees.feature >= 0
-    fact(
-        "forest",
-        trees=int(trees.feature.shape[0]),
-        internal_nodes=int(internal.sum()),
-        depth_reached=_depth_reached(trees.left_child, internal),
-        widest_frontier=int(min(2 ** (max_depth - 1), width)),
-        bins=int(n_bins),
-        features_per_node=int(max_features),
-        chunk_trees=int(size),
-        tree_bytes=int(per_tree),
-        edge_rule=EDGE_RULE,
-    )
+    # the host at work on the fetched tables: their order, and the walk of
+    # every tree that the fact's `depth_reached` costs
+    with trace("forest_assemble", detail="work"):
+        trees = TreeArrays(*(reassemble(f) for f in TreeArrays._fields))
+        internal = trees.feature >= 0
+        fact(
+            "forest",
+            trees=int(trees.feature.shape[0]),
+            internal_nodes=int(internal.sum()),
+            depth_reached=_depth_reached(trees.left_child, internal),
+            widest_frontier=int(min(2 ** (max_depth - 1), width)),
+            bins=int(n_bins),
+            features_per_node=int(max_features),
+            chunk_trees=int(size),
+            tree_bytes=int(per_tree),
+            edge_rule=EDGE_RULE,
+        )
     return trees
 
 

@@ -412,8 +412,20 @@ def solve_linear_host(
     l2 = reg_param * (1.0 - elasticnet_param)
     n_iter = 0
 
+    # every branch builds its float64 system under `linreg_solve_assemble`
+    # (the widening copy and first touch of the one (d,d) buffer) and
+    # solves it under `linreg_solve_factor`: both the host working, inside
+    # the caller's `linreg_host_solve`
+    from ..tracing import event, trace
+
+    def assembled(ridge: float) -> np.ndarray:
+        with trace("linreg_solve_assemble", detail="work"):
+            return system(ridge)
+
     if reg_param == 0.0:
-        coef_s = np.linalg.lstsq(system(0.0), sxy_s, rcond=None)[0]
+        A = assembled(0.0)
+        with trace("linreg_solve_factor", detail="work"):
+            coef_s = np.linalg.lstsq(A, sxy_s, rcond=None)[0]
     elif l1 == 0.0:
         # ridge closed form; penalty in 1/(2n) objective units -> n·λ₂ on
         # the un-normalized Gram (the reference's alpha×=m, regression.py:575-580).
@@ -427,19 +439,23 @@ def solve_linear_host(
         # coefficients against the LU's by about as much.
         from scipy.linalg import LinAlgError, cho_factor, cho_solve
 
-        from ..tracing import event
-
-        try:
-            factor = cho_factor(
-                _fortran_view(system(sw * l2)),
-                lower=True, overwrite_a=True, check_finite=False,
-            )
-        except LinAlgError:
+        A = assembled(sw * l2)
+        with trace("linreg_solve_factor", detail="work"):
+            try:
+                factor = cho_factor(
+                    _fortran_view(A),
+                    lower=True, overwrite_a=True, check_finite=False,
+                )
+            except LinAlgError:
+                coef_s = None  # the factorisation wrote over its system
+            else:
+                event("linreg_solver[cholesky]")
+                coef_s = cho_solve(factor, sxy_s, check_finite=False)
+        if coef_s is None:
             event("linreg_solver[lu_fallback]")
-            coef_s = np.linalg.solve(system(sw * l2), sxy_s)
-        else:
-            event("linreg_solver[cholesky]")
-            coef_s = cho_solve(factor, sxy_s, check_finite=False)
+            A = assembled(sw * l2)
+            with trace("linreg_solve_factor", detail="work"):
+                coef_s = np.linalg.solve(A, sxy_s)
     else:
         # FISTA on f(β)=1/(2n)(βᵀGβ - 2bᵀβ) + λ₂/2‖β‖², prox for λ₁‖β‖₁
         from ..resilience import maybe_inject
@@ -449,60 +465,67 @@ def solve_linear_host(
             save_checkpoint,
         )
 
-        G = system(0.0)
-        G /= sw
-        b = sxy_s / sw
-        L = float(np.linalg.eigvalsh(G)[-1]) + l2
-        L = max(L, 1e-12)
-        beta = np.zeros(d)
-        z = beta.copy()
-        t_mom = 1.0
-        start_it = 0
-        resumed = (
-            load_checkpoint(checkpoint_path, checkpoint_tag)
-            if checkpoint_path
-            else None
-        )
-        if resumed is not None:
-            beta = np.asarray(resumed["beta"])
-            z = np.asarray(resumed["z"])
-            t_mom = float(resumed["t_mom"])
-            start_it = int(resumed["it"])
-            # a checkpoint saved at it==max_iter (crash between the final
-            # save and clear) skips the loop entirely — the diag count
-            # must still report the iterations already run
-            n_iter = start_it
-            from ..tracing import event
+        with trace("linreg_solve_assemble", detail="work"):
+            G = system(0.0)
+            G /= sw
+            b = sxy_s / sw
+        # the solve of this branch: the step size (an eigenvalue of the
+        # system) and the proximal iterations
+        with trace("linreg_solve_factor", detail="work"):
+            L = float(np.linalg.eigvalsh(G)[-1]) + l2
+            L = max(L, 1e-12)
+            beta = np.zeros(d)
+            z = beta.copy()
+            t_mom = 1.0
+            start_it = 0
+            resumed = (
+                load_checkpoint(checkpoint_path, checkpoint_tag)
+                if checkpoint_path
+                else None
+            )
+            if resumed is not None:
+                beta = np.asarray(resumed["beta"])
+                z = np.asarray(resumed["z"])
+                t_mom = float(resumed["t_mom"])
+                start_it = int(resumed["it"])
+                # a checkpoint saved at it==max_iter (crash between the final
+                # save and clear) skips the loop entirely — the diag count
+                # must still report the iterations already run
+                n_iter = start_it
+                event("fista_resume", detail=f"it={start_it}")
+            from ..telemetry import Heartbeat
 
-            event("fista_resume", detail=f"it={start_it}")
-        from ..telemetry import Heartbeat
-
-        hb = Heartbeat("fista", total=max_iter)
-        for it in range(start_it, max_iter):
-            maybe_inject("linreg_fista")
-            grad = G @ z - b + l2 * z
-            beta_new = _soft_threshold(z - grad / L, l1 / L)
-            t_new = 0.5 * (1.0 + np.sqrt(1.0 + 4.0 * t_mom * t_mom))
-            z = beta_new + ((t_mom - 1.0) / t_new) * (beta_new - beta)
-            delta = float(np.max(np.abs(beta_new - beta)))
-            beta = beta_new
-            t_mom = t_new
-            n_iter = it + 1
-            hb.beat(n_iter, detail=f"delta={delta:.3e}")
+            hb = Heartbeat("fista", total=max_iter)
+            for it in range(start_it, max_iter):
+                maybe_inject("linreg_fista")
+                grad = G @ z - b + l2 * z
+                beta_new = _soft_threshold(z - grad / L, l1 / L)
+                t_new = 0.5 * (1.0 + np.sqrt(1.0 + 4.0 * t_mom * t_mom))
+                z = beta_new + ((t_mom - 1.0) / t_new) * (beta_new - beta)
+                delta = float(np.max(np.abs(beta_new - beta)))
+                beta = beta_new
+                t_mom = t_new
+                n_iter = it + 1
+                hb.beat(n_iter, detail=f"delta={delta:.3e}")
+                if checkpoint_path:
+                    save_checkpoint(
+                        checkpoint_path, checkpoint_tag,
+                        {"beta": beta, "z": z, "t_mom": t_mom, "it": n_iter},
+                    )
+                if delta <= tol * max(1.0, float(np.max(np.abs(beta)))):
+                    break
+            # end-mark on normal completion (Heartbeat.close): a scrape
+            # after the fit shows no live fista series
+            hb.close()
             if checkpoint_path:
-                save_checkpoint(
-                    checkpoint_path, checkpoint_tag,
-                    {"beta": beta, "z": z, "t_mom": t_mom, "it": n_iter},
-                )
-            if delta <= tol * max(1.0, float(np.max(np.abs(beta)))):
-                break
-        # end-mark on normal completion (Heartbeat.close): a scrape
-        # after the fit shows no live fista series
-        hb.close()
-        if checkpoint_path:
-            clear_checkpoint(checkpoint_path)
+                clear_checkpoint(checkpoint_path)
         coef_s = beta
 
+    # the system's one (d,d) float64 buffer is dropped here, under a span,
+    # and not at the return, where it ran under none: unmapping 72 MB at
+    # 3,000 columns is milliseconds of the host's
+    with trace("linreg_solve_release", detail="work"):
+        A = factor = G = None  # whichever this branch made
     coef = coef_s / scale
     intercept = float(ymean - mean @ coef) if fit_intercept else 0.0
     # training-summary statistics from the same sufficient stats (Spark's
@@ -512,13 +535,14 @@ def solve_linear_host(
     # f32-accumulated inputs the absolute error is ~eps32·syy/sw, so
     # callers holding the data should overwrite with `summary_stats`'s
     # cancellation-free residual pass (models/regression.py does).
-    sse = (
-        syy
-        - 2.0 * (coef @ sxy + intercept * sy)
-        + _quadratic_form(gram, coef)
-        + 2.0 * intercept * (s1 @ coef)
-        + intercept * intercept * sw
-    )
+    with trace("linreg_solve_summary", detail="work"):
+        sse = (
+            syy
+            - 2.0 * (coef @ sxy + intercept * sy)
+            + _quadratic_form(gram, coef)
+            + 2.0 * intercept * (s1 @ coef)
+            + intercept * intercept * sw
+        )
     sse = max(float(sse), 0.0)
     diag = {"n_iter": float(n_iter)}
     diag.update(_summary_from_sse(sse, sw, sy, syy, fit_intercept))

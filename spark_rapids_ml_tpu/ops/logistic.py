@@ -393,9 +393,13 @@ def logreg_fit_host_dispatch(
     mfn = margin_fn or (lambda dat, beta: dat @ beta)
     lfn = logits_fn or (lambda dat, Wm: dat @ Wm.T)
 
-    _, n_param, l1_mask, unpack = _theta_layout(
-        1 if binomial else n_classes, d, dtype, fit_intercept
-    )
+    # the packed layout's mask is made on the device and read back: a few
+    # small programs and a fetch before the first evaluation
+    with trace("lbfgs_layout", detail="work"):
+        _, n_param, l1_mask, unpack = _theta_layout(
+            1 if binomial else n_classes, d, dtype, fit_intercept
+        )
+        l1_mask = np.asarray(l1_mask, np.float64)
 
     @jax.jit
     def vg_fn(theta, dat, w_, y_):
@@ -415,11 +419,15 @@ def logreg_fit_host_dispatch(
 
     def oracle(theta_np: np.ndarray):
         # one span per evaluation, dispatch to fetch: their count IS the
-        # fit's evaluation count, and the first one holds the re-jit
+        # fit's evaluation count, and the first one holds the re-jit.
+        # Beneath it the host works until the program is enqueued (the
+        # arguments; in each fit's first the re-jit), then waits for its
+        # value and gradient
         with trace("lbfgs_eval"):
-            f, g = jax.device_get(
-                vg_fn(jnp.asarray(theta_np, dtype), operands, w, y)
-            )
+            with trace("lbfgs_eval_dispatch", detail="work"):
+                out = vg_fn(jnp.asarray(theta_np, dtype), operands, w, y)
+            with trace("lbfgs_eval_wait", detail="wait"):
+                f, g = jax.device_get(out)
         return float(f), np.asarray(g, np.float64)
 
     theta, n_iter, converged, hist = lbfgs_minimize_host(
@@ -429,15 +437,26 @@ def logreg_fit_host_dispatch(
         tol=tol,
         history=history,
         l1=l1,
-        l1_mask=np.asarray(l1_mask, np.float64),
+        l1_mask=l1_mask,
         ls_max=ls_max,
         checkpoint_path=checkpoint_path,
         checkpoint_tag=checkpoint_tag,
     )
-    coef, b = unpack(jnp.asarray(theta, dtype))
-    # hist already carries the FULL (penalty-inclusive) objective per
-    # iteration; hist[-1] is the final loss — no recomputation pass
-    return coef, b, hist[-1], n_iter, jnp.asarray(hist, dtype)
+    # the host's answer goes back to the device in the fused kernels'
+    # shapes (a put, the slices of `unpack`, the history's put), from where
+    # the caller's `solve_fetch` brings it home again
+    with trace("lbfgs_unpack", detail="work"):
+        coef, b = unpack(jnp.asarray(theta, dtype))
+        # hist already carries the FULL (penalty-inclusive) objective per
+        # iteration; hist[-1] is the final loss — no recomputation pass
+        out = coef, b, hist[-1], n_iter, jnp.asarray(hist, dtype)
+    # this fit's evaluation program is dropped here, under a span, and not
+    # at the return, where it ran under none: every fit jits its own, and
+    # dropping one (the loaded executable with it) is milliseconds of the
+    # host's
+    with trace("lbfgs_release", detail="work"):
+        del oracle, vg_fn
+    return out
 
 
 @jax.jit

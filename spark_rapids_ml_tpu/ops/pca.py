@@ -231,6 +231,7 @@ def pca_eigensolve_polished(scatter, s1, sw: float, shift, k: int, start):
     from scipy.linalg import eigh, qr
     from scipy.linalg.blas import dgemm
 
+    from ..tracing import trace
     from .linear import _fortran_view
 
     sw = float(sw)
@@ -239,23 +240,25 @@ def pca_eigensolve_polished(scatter, s1, sw: float, shift, k: int, start):
     Q = np.asarray(start, np.float64)
     bound = np.float64(np.inf)
     for sweep in range(1, _SUBSPACE_POLISH_CAP + 1):
-        Q = qr(Q, mode="economic", overwrite_a=True, check_finite=False)[0]
-        Qt = np.asfortranarray(Q.T)
-        Wt = _centred_product(G, sw, delta, Qt)
-        theta, S = eigh(dgemm(1.0, Qt, Wt, trans_b=1), check_finite=False)
-        theta, top = theta[::-1], S[:, ::-1][:, :k]
-        Vt = dgemm(1.0, top, Qt, trans_a=1)  # (k,d): the Ritz vectors
-        Rt = dgemm(1.0, top, Wt, trans_a=1) - theta[:k, None] * Vt
-        apart = np.abs(theta[:k, None] - theta[None, :])
-        apart[np.arange(k), np.arange(k)] = np.inf
-        with np.errstate(all="ignore"):
-            last, bound = bound, np.max(np.linalg.norm(Rt, axis=1) / apart.min(axis=1))
-            # The bound falls by `bound / last` a sweep at best (the fast
-            # modes die first): where the sweeps left cannot reach the
-            # tolerance at that rate the try ends here, which is at the
-            # cap, at a bound that stopped falling (or is no number), and
-            # two sweeps into a spectrum without gaps.
-            reach = bound * (bound / last) ** (_SUBSPACE_POLISH_CAP - sweep)
+        # one span a sweep (their count is the sweeps'): the host working
+        with trace("pca_polish_sweep", detail="work"):
+            Q = qr(Q, mode="economic", overwrite_a=True, check_finite=False)[0]
+            Qt = np.asfortranarray(Q.T)
+            Wt = _centred_product(G, sw, delta, Qt)
+            theta, S = eigh(dgemm(1.0, Qt, Wt, trans_b=1), check_finite=False)
+            theta, top = theta[::-1], S[:, ::-1][:, :k]
+            Vt = dgemm(1.0, top, Qt, trans_a=1)  # (k,d): the Ritz vectors
+            Rt = dgemm(1.0, top, Wt, trans_a=1) - theta[:k, None] * Vt
+            apart = np.abs(theta[:k, None] - theta[None, :])
+            apart[np.arange(k), np.arange(k)] = np.inf
+            with np.errstate(all="ignore"):
+                last, bound = bound, np.max(np.linalg.norm(Rt, axis=1) / apart.min(axis=1))
+                # The bound falls by `bound / last` a sweep at best (the fast
+                # modes die first): where the sweeps left cannot reach the
+                # tolerance at that rate the try ends here, which is at the
+                # cap, at a bound that stopped falling (or is no number), and
+                # two sweeps into a spectrum without gaps.
+                reach = bound * (bound / last) ** (_SUBSPACE_POLISH_CAP - sweep)
         if bound <= _SUBSPACE_TOL:
             break
         if not reach <= _SUBSPACE_TOL:
@@ -284,12 +287,14 @@ def pca_eigensolve_resident(device_scatter: jax.Array, scatter, s1, sw: float,
     tuple."""
     import numpy as np
 
-    from ..tracing import event
+    from ..tracing import event, trace
 
     d = int(scatter.shape[0])
     block, steps, why = subspace_plan(d, k)
     if block:
-        start = np.asarray(_pca_subspace_iterate(device_scatter, block, steps))
+        # dispatch of the device loop to its block on the host: a wait
+        with trace("pca_subspace_device", detail="wait"):
+            start = np.asarray(_pca_subspace_iterate(device_scatter, block, steps))
         out, sweeps, bound = pca_eigensolve_polished(scatter, s1, sw, shift, k, start)
         tried = (f"block={block} device_steps={steps} polish_steps={sweeps} "
                  f"estimate={bound:.3g}")
@@ -306,7 +311,8 @@ def pca_eigensolve_resident(device_scatter: jax.Array, scatter, s1, sw: float,
         detail=f"{why}: dsyevr in float64 on the fetched ({d},{d}) "
         f"{scatter.dtype} covariance, top {k}",
     )
-    return pca_eigensolve_host(scatter, s1, sw, shift, k)
+    with trace("pca_lapack", detail="work"):
+        return pca_eigensolve_host(scatter, s1, sw, shift, k)
 
 
 # ---------------------------------------------------------------------------

@@ -545,19 +545,23 @@ class ShardedRowWriter:
         # serialization.  Waiting for an older piece to be applied IS
         # transfer time and counts.
         prep_s = time.perf_counter() - t0
-        # one `stage_put` span per piece: the interval `put_s` times
-        with self._dev_locks[d], trace("stage_put"):
-            t1 = time.perf_counter()
+        # one `stage_put` span per piece, and its clock is `put_seconds`'
+        # too.  Beneath it the host waits for an older piece's update
+        # (a span only where one is waited for), works in the runtime's
+        # two `device_put` calls, and dispatches the update
+        with self._dev_locks[d], trace("stage_put") as put:
             done = self._done[d]
             if len(done) >= _MAX_INFLIGHT_PIECES:
-                done.popleft().block_until_ready()
-            pj = jax.device_put(piece, dev)
-            off = jax.device_put(np.asarray(lo, np.int32), dev)
-            self._bufs[d], applied = upd(self._bufs[d], pj, off)
+                with trace("stage_put_wait", detail="wait"):
+                    done.popleft().block_until_ready()
+            with trace("stage_put_call", detail="work"):
+                pj = jax.device_put(piece, dev)
+                off = jax.device_put(np.asarray(lo, np.int32), dev)
+            with trace("stage_put_update", detail="work"):
+                self._bufs[d], applied = upd(self._bufs[d], pj, off)
             done.append(applied)
-            put_s = time.perf_counter() - t1
         with self._mu:
-            self.put_seconds += prep_s + put_s
+            self.put_seconds += prep_s + put.seconds
             self.bytes_written += piece.nbytes
             self.pieces += 1
         return applied
@@ -568,7 +572,8 @@ class ShardedRowWriter:
         # this returns, so the caller may overwrite its array
         for done in self._done.values():
             while done:
-                done.popleft().block_until_ready()
+                with trace("stage_put_wait", detail="wait"):
+                    done.popleft().block_until_ready()
         if self.sharding is None:
             out = self._bufs[0]
         else:
@@ -701,37 +706,38 @@ def run_staging_pipeline(
             applied = writer.write_shard(int(dev), int(lo), rows)
             if on_put is not None:
                 on_put(rows, applied)
-        t_finish = time.time()
-        out = writer.finish()
-    wall = time.perf_counter() - t0
-    mb = writer.bytes_written / 1e6
-    busy = prep["s"] + writer.put_seconds
-    overlap = 0.0
-    if depth > 1 and min(prep["s"], writer.put_seconds) > 1e-9:
-        overlap = max(0.0, min(
-            (busy - wall) / min(prep["s"], writer.put_seconds), 1.0
-        ))
-    _PUT_RATE["mb_per_s"] = round(mb / max(wall, 1e-9), 1)
-    fact(
-        "staging",
-        label=label,
-        bytes=writer.bytes_written,
-        seconds=round(wall, 4),
-        mb_per_s=_PUT_RATE["mb_per_s"],
-        overlap_ratio=round(overlap, 4),
-        pieces=writer.pieces,
-        pieces_viewed=writer.pieces_viewed,
-        depth=depth,
-        n_dev=writer.n_dev,
-    )
-    # the staging engine's prep + wall windows feed the run's
-    # utilization timeline: host->device transfer time is "stage"
-    # activity (gap evidence), chunk prep is "host_prep"
-    from ..telemetry import utilization
+        # the drain of the last pieces (a `stage_put_wait` each), the
+        # assembly and the bookkeeping after the last put
+        with trace("stage_finish"):
+            out = writer.finish()
+            wall = time.perf_counter() - t0
+            mb = writer.bytes_written / 1e6
+            busy = prep["s"] + writer.put_seconds
+            overlap = 0.0
+            if depth > 1 and min(prep["s"], writer.put_seconds) > 1e-9:
+                overlap = max(0.0, min(
+                    (busy - wall) / min(prep["s"], writer.put_seconds), 1.0
+                ))
+            _PUT_RATE["mb_per_s"] = round(mb / max(wall, 1e-9), 1)
+            fact(
+                "staging",
+                label=label,
+                bytes=writer.bytes_written,
+                seconds=round(wall, 4),
+                mb_per_s=_PUT_RATE["mb_per_s"],
+                overlap_ratio=round(overlap, 4),
+                pieces=writer.pieces,
+                pieces_viewed=writer.pieces_viewed,
+                depth=depth,
+                n_dev=writer.n_dev,
+            )
+            # the staging engine's prep + wall windows feed the run's
+            # utilization timeline: host->device transfer time is "stage"
+            # activity (gap evidence), chunk prep is "host_prep"
+            from ..telemetry import utilization
 
-    utilization.note_intervals("host_prep", prep["iv"], cause="stage_prep")
-    utilization.note_interval("stage", t0, t0 + wall, cause=label)
-    record_span("stage_finish", t_finish, time.time())
+            utilization.note_intervals("host_prep", prep["iv"], cause="stage_prep")
+            utilization.note_interval("stage", t0, t0 + wall, cause=label)
     return out
 
 

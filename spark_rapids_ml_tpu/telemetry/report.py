@@ -431,11 +431,19 @@ class FitTelemetry:
         """Build the report, expose it as `model.fit_report()`, and write
         the JSON artifact when `telemetry_dir` is set.  Never raises —
         observability must not fail the fit it observed."""
+        # the report's own assembly is a span of the report, a root beside
+        # `fit[<Est>]`: what the instrumentation costs each fit, and the
+        # name an idle device's time between two fits goes under
+        t0, c0 = time.time(), time.perf_counter()
         try:
             report = self.build(model)
         except Exception as e:  # pragma: no cover - defensive
             _warn(log, f"fit report build failed ({type(e).__name__}: {e})")
             return
+        report["spans"].append({
+            "name": "fit_report", "t0": round(t0, 6),
+            "seconds": round(time.perf_counter() - c0, 6), "detail": "work",
+        })
         try:
             model._fit_report = report
         except Exception:

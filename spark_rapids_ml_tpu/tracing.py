@@ -334,11 +334,29 @@ def last_fact(
     return dict(newest.fields) if newest is not None else {}
 
 
+class OpenSpan:
+    """What `trace` yields: once the stage has ended, `seconds` is the
+    duration its span recorded, so a caller that also decides by that
+    interval (the staging engine's put rate) reads the span's clock and
+    keeps none of its own."""
+
+    __slots__ = ("seconds",)
+
+    def __init__(self) -> None:
+        self.seconds = 0.0
+
+
 @contextlib.contextmanager
-def trace(name: str, log: Optional[object] = None) -> Iterator[None]:
+def trace(
+    name: str, log: Optional[object] = None, detail: str = ""
+) -> Iterator[OpenSpan]:
     """Time a stage.  Nested stages indent; `verbose >= 1` logs on exit.
     The recorded span carries absolute t0/t1, the recording thread id and
-    the active run id (see `run_context`).
+    the active run id (see `run_context`).  `detail` rides on the span as
+    it does on an instant: the spans beneath a fit's leaf spans say there
+    whether the host `wait`s (for the device, for a transfer) or `work`s
+    (docs/observability.md, "Span vocabulary").  Yields the span's
+    `OpenSpan`.
 
     Where jax is already imported the stage is also a
     `jax.profiler.TraceAnnotation`: nothing without a profiler session;
@@ -350,22 +368,24 @@ def trace(name: str, log: Optional[object] = None) -> Iterator[None]:
     # no jax import at module scope, and none caused here either
     jax = sys.modules.get("jax")
     annotation = jax.profiler.TraceAnnotation(name) if jax is not None else None
+    span = OpenSpan()
     t0_abs = time.time()
     t0 = time.perf_counter()
     if annotation is not None:
         annotation.__enter__()
     try:
-        yield
+        yield span
     finally:
         if annotation is not None:
             annotation.__exit__(None, None, None)
-        dt = time.perf_counter() - t0
+        dt = span.seconds = time.perf_counter() - t0
         _tls.depth = depth
         _append(
             TraceEvent(
                 name,
                 dt,
                 depth,
+                detail,
                 t0=t0_abs,
                 t1=t0_abs + dt,
                 thread_id=threading.get_ident(),

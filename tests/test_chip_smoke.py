@@ -4,6 +4,7 @@
 # OOM-to-streaming refit that now says it happened, the device budget
 # read from the device, and the native library that rebuilds itself.
 #
+import gc
 import json
 import os
 import subprocess
@@ -36,6 +37,10 @@ def test_smoke_body_passes_on_cpu_mesh(tmp_path):
     """Same phases, same result checks, same sharding assertion as on the
     chip: 4096 x 64 through parquet (8 row groups, so the parallel range
     readers run) on all 8 devices."""
+    # the CPU mesh's memory provider is a census of the process's live
+    # arrays: what an earlier test file of this worker left for the
+    # collector must not be freed between the smoke's two readings
+    gc.collect()
     facts = chip_smoke.run_smoke(
         4096, 64, str(tmp_path / "out"), data_home=str(tmp_path),
         slab_rows=512,

@@ -15,11 +15,24 @@ READERS = ["stage_prep_s", "stage_put_s", "lbfgs_eval_gap_ms", "lbfgs_host_step_
            "lbfgs_evals_per_fit", "compile_s", "linreg_fetch_s", "linreg_host_solve_s"]
 
 
+def parents_of(spans):
+    """Each span's parent as `run.py` flattens the report's tree: the
+    innermost earlier span that holds it in time (the hand-made fits below
+    list a parent before its children), -1 for none."""
+    out = []
+    for i, (_, t0, t1) in enumerate(spans):
+        holders = [j for j in range(i) if spans[j][1] <= t0 and t1 <= spans[j][2]
+                   and spans[j][2] > spans[j][1]]
+        out.append(max(holders, key=lambda j: (spans[j][1], -spans[j][2]), default=-1))
+    return out
+
+
 def ctx_of(fits, modules=None, compiles=0.0, programs=None):
     adapter = types.SimpleNamespace(
         PROGRAMS={"lbfgs_eval": ("vg_fn", "logreg_fit")} if programs is None else programs)
     trace = None if modules is None else {"modules": modules}
-    return {"adapter": adapter, "fits": [{"spans": s} for s in fits],
+    return {"adapter": adapter,
+            "fits": [{"spans": s, "span_parents": parents_of(s)} for s in fits],
             "trace": trace, "compiles_in_window": compiles}
 
 
@@ -29,34 +42,49 @@ FIT_1 = [
     ("fit[LogisticRegression]", 0.0, 10.0),
     ("stage", 0.0, 4.0),
     ("stage_prep", 0.1, 1.1), ("stage_put", 0.6, 2.1),      # 1.0 and 1.5
+    ("stage_put_call", 0.6, 1.9), ("stage_put_update", 1.9, 2.0),   # none to wait for yet
     ("stage_prep", 1.2, 2.2), ("stage_put", 2.2, 3.7),      # 1.0 and 1.5
-    ("stage_finish", 3.7, 3.9),
+    ("stage_put_wait", 2.2, 2.9), ("stage_put_call", 2.9, 3.5), ("stage_put_update", 3.5, 3.6),
+    ("stage_finish", 3.7, 3.9), ("stage_put_wait", 3.7, 3.8),       # the drain
     ("fit_kernel", 4.0, 10.0),
     ("lbfgs_host_step", 4.0, 4.1),
     ("lbfgs_eval", 4.1, 6.1),                                # the re-jit: 2.0
+    ("lbfgs_eval_dispatch", 4.1, 5.7),
     ("compile[trace]", 4.2, 4.5), ("compile[lower]", 4.5, 4.6),
     ("compile[backend_compile]", 4.6, 5.6), ("compile[cache_read]", 4.7, 5.5),
+    ("lbfgs_eval_wait", 5.7, 6.1),
     ("lbfgs_host_step", 6.1, 6.3),
     ("lbfgs_eval", 6.3, 6.8),                                # 0.5
+    ("lbfgs_eval_dispatch", 6.3, 6.4), ("lbfgs_eval_wait", 6.4, 6.8),
     ("lbfgs_host_step", 6.8, 7.1),
     ("lbfgs_eval", 7.1, 7.8),                                # 0.7
+    ("lbfgs_eval_dispatch", 7.1, 7.3), ("lbfgs_eval_wait", 7.3, 7.8),
     ("lbfgs_host_step", 7.8, 7.9),
     ("solve_fetch", 7.9, 8.0),
 ]
 FIT_2 = [
+    ("fit[LogisticRegression]", 20.0, 26.0),
     ("stage", 20.0, 23.0),
     ("stage_prep", 20.0, 20.5), ("stage_put", 20.5, 22.5),  # 0.5 and 2.0
+    ("stage_put_wait", 20.5, 20.9), ("stage_put_call", 20.9, 22.3),
+    ("stage_put_update", 22.3, 22.4),
     ("fit_kernel", 23.0, 26.0),
     ("lbfgs_host_step", 23.0, 23.2),
     ("lbfgs_eval", 23.2, 24.2),                              # first: left out
+    ("lbfgs_eval_dispatch", 23.2, 23.9), ("lbfgs_eval_wait", 23.9, 24.2),
     ("lbfgs_host_step", 24.2, 24.3),
     ("lbfgs_eval", 24.3, 24.9),                              # 0.6
+    ("lbfgs_eval_dispatch", 24.3, 24.4), ("lbfgs_eval_wait", 24.4, 24.9),
     ("lbfgs_host_step", 24.9, 25.0),
     ("lbfgs_eval", 25.0, 25.6),                              # 0.6
+    ("lbfgs_eval_dispatch", 25.0, 25.3), ("lbfgs_eval_wait", 25.3, 25.6),
 ]
 RIDGE = [
     ("fit_kernel", 0.0, 2.0), ("linreg_gram", 0.0, 0.6), ("linreg_fetch", 0.6, 0.7),
-    ("linreg_host_solve", 0.7, 1.9), ("linreg_residual", 1.9, 2.0),
+    ("linreg_host_solve", 0.7, 1.9),
+    ("linreg_solve_assemble", 0.75, 1.55), ("linreg_solve_factor", 1.55, 1.85),
+    ("linreg_solver[cholesky]", 1.7, 1.7),
+    ("linreg_residual", 1.9, 2.0),
 ]
 # device seconds and runs by program, as trace_reduce.reduce gives them
 MODULES = {"jit_vg_fn": (2.4, 6), "jit__dus_rows_done": (0.3, 3)}
@@ -80,16 +108,29 @@ FOREST_READERS = ["forest_bin_s", "forest_grow_s", "forest_fetch_s", "forest_nod
 PCA_READERS = ["pca_covariance_s", "pca_eigensolve_s", "pca_fetch_s"]
 
 
+# PR 37's, appended after them: the readers of the wait-or-work spans beneath
+# four leaf spans, then the two that read spans every program has (a span's
+# self time, one fit among the window's)
+CHILD_READERS = ["stage_put_wait_s", "stage_put_call_s", "stage_put_longest_ms",
+                 "linreg_solve_assemble_s", "linreg_solve_factor_s", "pca_subspace_device_s",
+                 "pca_polish_sweeps_per_fit", "lbfgs_eval_dispatch_ms"]
+WHOLE_FIT_READERS = ["fit_kernel_self_ms", "fit_longest_x"]
+
+
 def test_the_manifest_lists_the_readers_last_and_finds_them():
     # each PR appends: PR 26's eight readers in their order, PR 29's four,
-    # PR 33's six, PR 35's three
+    # PR 33's six, PR 35's three, PR 37's ten
     names = [m["name"] for m in MANIFEST["per_layer"]]
-    appended = READERS + KMEANS_READERS + FOREST_READERS + PCA_READERS
+    appended = (READERS + KMEANS_READERS + FOREST_READERS + PCA_READERS
+                + CHILD_READERS + WHOLE_FIT_READERS)
     assert names[-len(appended):] == appended
     assert mf.problems(MANIFEST) == []
     for m in MANIFEST["per_layer"][-len(appended):]:
         assert m["moves"] == "fit_s" and m["workloads"]
         assert m["better"] == ("higher" if m["name"].endswith("_roofline") else "lower")
+    every_cell = [w["name"] for w in MANIFEST["workloads"]]
+    for m in MANIFEST["per_layer"][-len(WHOLE_FIT_READERS):]:
+        assert m["workloads"] == every_cell and m["source"] == "program_span"
 
 
 @pytest.mark.parametrize("name, by_hand", [
@@ -102,6 +143,16 @@ def test_the_manifest_lists_the_readers_last_and_finds_them():
     # fit 1: trace, lower and backend_compile end to end, the cache read
     # inside the last counted once; fit 2 compiled nothing
     ("compile_s", (1.4 + 0.0) / 2),
+    # fit 1 waits 0.7 under a put and 0.1 in the drain, fit 2 0.4
+    ("stage_put_wait_s", (0.8 + 0.4) / 2),
+    ("stage_put_call_s", (1.3 + 0.6 + 1.4) / 2),
+    ("stage_put_longest_ms", 2000.0),
+    # the later dispatches: 0.1, 0.2, 0.1, 0.3 s; each fit's first holds the re-jit
+    ("lbfgs_eval_dispatch_ms", 1e3 * 0.7 / 4),
+    # the kernels' 6.0 and 3.0 s less what their children cover, 4.0 and 2.6
+    ("fit_kernel_self_ms", 1e3 * (2.0 + 0.4) / 2),
+    # fits of 10 and 6 s: the longest over their median
+    ("fit_longest_x", 10.0 / 8.0),
 ])
 def test_logistic_readers_by_hand(name, by_hand):
     ctx = ctx_of([FIT_1, FIT_2], MODULES, compiles=1.0)
@@ -109,12 +160,15 @@ def test_logistic_readers_by_hand(name, by_hand):
 
 
 @pytest.mark.parametrize("name, by_hand", [
-    ("linreg_fetch_s", 0.1), ("linreg_host_solve_s", 1.2), ("compile_s", 0.0)])
+    ("linreg_fetch_s", 0.1), ("linreg_host_solve_s", 1.2), ("compile_s", 0.0),
+    ("linreg_solve_assemble_s", 0.8), ("linreg_solve_factor_s", 0.3),
+    # 2.0 s less gram, fetch, solve and residual end to end: nothing left
+    ("fit_kernel_self_ms", 0.0)])
 def test_ridge_readers_by_hand(name, by_hand):
     assert read(name, ctx_of([RIDGE, RIDGE], {})) == pytest.approx(by_hand, abs=1e-12)
 
 
-@pytest.mark.parametrize("name", READERS)
+@pytest.mark.parametrize("name", READERS + CHILD_READERS)
 def test_a_program_without_the_spans_reads_nothing(name):
     """The parent commit: the spans the benchmark already read, none of the
     new ones, and a counter that says the window compiled."""
@@ -274,6 +328,15 @@ PCA_2 = [
     ("fit_kernel", 10.0, 12.2), ("pca_covariance", 10.0, 10.4), ("pca_fetch", 10.4, 10.6),
     ("pca_eigensolve", 10.6, 12.2),
 ]
+# two fits whose eigensolve the block iteration answers, in three sweeps and
+# in four; a third where it gives up after two and LAPACK answers
+PCA_SUBSPACE = [
+    [("fit_kernel", t, t + 2.0), ("pca_eigensolve", t + 0.5, t + 1.9),
+     ("pca_subspace_device", t + 0.5, t + 0.5 + device),
+     *[("pca_polish_sweep", t + 0.8 + 0.2 * i, t + 1.0 + 0.2 * i) for i in range(sweeps)],
+     *([("pca_lapack", t + 1.3, t + 1.9)] if lapack else [])]
+    for t, device, sweeps, lapack in ((0.0, 0.1, 3, False), (10.0, 0.2, 4, False),
+                                      (20.0, 0.3, 2, True))]
 # the shift pass, 16 row-block programs and a finish, a fit
 PCA_MODULES = {"jit__pca_covariance_shift": (0.04, 2), "jit__label_check_kernel": (0.3, 2),
                "jit__linreg_sufficient_stats_block": (0.7, 32),
@@ -303,9 +366,59 @@ def test_pca_readers_by_hand(name, by_hand):
     assert read(name, pca_ctx([PCA_1, PCA_2])) == pytest.approx(by_hand, rel=1e-12)
 
 
+@pytest.mark.parametrize("name, by_hand", [
+    ("pca_subspace_device_s", (0.1 + 0.2 + 0.3) / 3),
+    ("pca_polish_sweeps_per_fit", (3 + 4 + 2) / 3),
+    # the kernel's 2.0 s less the eigensolve's 1.4, whatever lies inside that
+    ("fit_kernel_self_ms", 600.0),
+])
+def test_pca_subspace_readers_by_hand(name, by_hand):
+    assert read(name, pca_ctx(PCA_SUBSPACE)) == pytest.approx(by_hand, rel=1e-12)
+    if name != "fit_kernel_self_ms":
+        assert read(name, pca_ctx([PCA_1, PCA_2])) is None  # LAPACK by shape: no try
+
+
 @pytest.mark.parametrize("name", PCA_READERS)
 def test_pca_readers_read_nothing_where_there_is_nothing(name):
     """The parent (no such span): None, never a raise."""
     bare = [("fit_kernel", 0.0, 5.0)]
     assert read(name, pca_ctx([bare, bare])) is None
     assert read(name, ctx_of([RIDGE, RIDGE], {})) is None
+
+
+def test_self_time_counts_an_overlap_of_children_once():
+    """Children on two threads overlap under one parent (a prefetch thread's
+    spans beside the caller's); a grandchild is its parent's to count."""
+    fit = [
+        ("fit_kernel", 0.0, 10.0),
+        ("a", 1.0, 4.0), ("inside_a", 1.5, 2.5),
+        ("b", 2.0, 6.0),                      # another thread: overlaps `a`
+        ("instant", 6.5, 6.5),
+        ("c", 7.0, 8.0),
+    ]
+    ctx = ctx_of([fit])
+    assert ctx["fits"][0]["span_parents"] == [-1, 0, 1, 0, 0, 0]
+    # 10 s less the union (1, 6) and (7, 8)
+    assert read("fit_kernel_self_ms", ctx) == pytest.approx(4000.0)
+    # a kernel with no child is all self time; one the children fill has none
+    assert read("fit_kernel_self_ms", ctx_of([[("fit_kernel", 0.0, 1.5)]])) == pytest.approx(1500.0)
+    full = [("fit_kernel", 0.0, 1.0), ("a", 0.0, 0.5), ("b", 0.5, 1.0)]
+    assert read("fit_kernel_self_ms", ctx_of([full])) == pytest.approx(0.0)
+
+
+def test_one_long_fit_among_many_shows():
+    fits = [[("fit[LogisticRegression]", 10.0 * i, 10.0 * i + wall), ("stage", 10.0 * i, 10.0 * i + 1)]
+            for i, wall in enumerate((2.1, 2.2, 2.1, 5.58, 2.2, 2.15, 2.1))]
+    assert read("fit_longest_x", ctx_of(fits)) == pytest.approx(5.58 / 2.15)
+    even = [[("fit[PCA]", float(i), i + 0.5)] for i in range(5)]
+    assert read("fit_longest_x", ctx_of(even)) == pytest.approx(1.0)
+
+
+@pytest.mark.parametrize("name", WHOLE_FIT_READERS)
+def test_whole_fit_readers_read_nothing_where_there_is_nothing(name):
+    """No window, fits that recorded no such span, a harness that kept no
+    record of parents: None, never a raise."""
+    assert read(name, ctx_of([])) is None
+    assert read(name, ctx_of([[("stage", 0.0, 1.0)], [("stage", 2.0, 3.0)]])) is None
+    if name == "fit_kernel_self_ms":
+        assert read(name, {"fits": [{"spans": [("fit_kernel", 0.0, 1.0)]}]}) is None

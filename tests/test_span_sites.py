@@ -6,6 +6,7 @@
 # benchmark matches, and a span costs microseconds and opens a profiler
 # annotation only where jax is loaded.
 #
+import os
 import re
 import sys
 import threading
@@ -54,7 +55,8 @@ def _logistic(**kw):
 ROUTES = {
     "logistic_host_dispatch": (
         lambda: _logistic(num_workers=1), (65_536, 64), "fit_kernel",
-        {"label_range", "lbfgs_eval", "lbfgs_host_step", "solve_fetch",
+        {"label_range", "lbfgs_layout", "lbfgs_eval", "lbfgs_eval_dispatch",
+         "lbfgs_eval_wait", "lbfgs_host_step", "lbfgs_unpack", "lbfgs_release", "solve_fetch",
          "compile[trace]", "compile[lower]", "compile[backend_compile]"},
     ),
     "logistic_fused": (
@@ -64,22 +66,24 @@ ROUTES = {
     "ridge": (
         lambda: LinearRegression(regParam=1e-5, num_workers=1),
         (131_072, 64), "fit_kernel",
-        {"linreg_gram", "linreg_fetch", "linreg_host_solve", "linreg_residual",
-         "linreg_solver[cholesky]"},
+        {"linreg_gram", "linreg_fetch", "linreg_host_solve", "linreg_solve_assemble",
+         "linreg_solve_factor", "linreg_solve_release", "linreg_solve_summary",
+         "linreg_residual", "linreg_release", "linreg_solver[cholesky]"},
     ),
     # the resident exact PCA: the covariance's passes, the fetch, the host's
     # eigensolve, and which Gram and which eigensolver ran
     "pca": (
         lambda: PCA(k=3, num_workers=1), (131_072, 64), "fit_kernel",
-        {"pca_covariance", "pca_fetch", "pca_eigensolve",
+        {"pca_covariance", "pca_fetch", "pca_eigensolve", "pca_lapack", "pca_release",
          "linreg_gram_kernel[xla]", "pca_eigensolver[host_lapack]"},
     ),
     # ... and at 640 columns with gaps between the eigenvalues, where the block
     # iteration and its float64 polish answer in LAPACK's place
     "pca_subspace": (
         lambda: PCA(k=3, num_workers=1), (8_192, 640), "fit_kernel",
-        {"pca_covariance", "pca_fetch", "pca_eigensolve",
-         "linreg_gram_kernel[xla]", "pca_eigensolver[subspace_polished]"},
+        {"pca_covariance", "pca_fetch", "pca_eigensolve", "pca_subspace_device",
+         "pca_polish_sweep", "pca_release", "linreg_gram_kernel[xla]",
+         "pca_eigensolver[subspace_polished]"},
     ),
     # the host-dispatched Lloyd, the route of rows a device cannot hold twice
     "kmeans_stepwise": (
@@ -93,14 +97,29 @@ ROUTES = {
     "forest": (
         lambda: RandomForestClassifier(numTrees=4, maxDepth=8, seed=1, num_workers=1),
         (32_768, 16), "fit_kernel",
-        {"forest_bin", "forest_grow", "forest_fetch"},
+        {"label_range", "forest_bin", "forest_grow", "forest_fetch", "forest_assemble"},
     ),
     # 256 MB of rows, far over `_PIPELINED_MIN_BYTES`: the staging engine
     "pipelined_stage": (
         lambda: _logistic(num_workers=1), (1_048_576, 64), "stage",
-        {"stage_alloc", "stage_prep", "stage_put", "stage_finish"},
+        {"stage_alloc", "stage_prep", "stage_put", "stage_put_wait", "stage_put_call",
+         "stage_put_update", "stage_finish"},
     ),
 }
+
+
+def _wait_or_work():
+    """{span: (`wait` | `work`, the spans it may lie beneath)}: the rows of
+    docs/observability.md's table of the spans under the leaf spans."""
+    docs = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "docs", "observability.md")
+    with open(docs) as f:
+        rows = re.findall(r"^\| `(\w+)`[^|]*\| (WAIT|WORK) \| ([^|]+) \|", f.read(), re.M)
+    return {name: (host.lower(), set(re.findall(r"`(\w+)`", beneath)))
+            for name, host, beneath in rows}
+
+
+WAIT_OR_WORK = _wait_or_work()
 
 
 def _walk(nodes, parent=None):
@@ -140,6 +159,9 @@ def test_route_records_its_spans(route, monkeypatch):
     if route == "kmeans_stepwise":
         # 16.8 MB of rows, and a device that cannot hold them twice
         set_config(hbm_bytes=30_000_000)
+    if route == "pipelined_stage":
+        # eight pieces of 32 MB, so that puts wait for older pieces
+        set_config(staging_chunk_bytes=32 * 1024 * 1024)
     X, y = _rows(n, d)
     if route == "pca_subspace":
         X *= 0.9 ** np.arange(d, dtype=np.float32)  # eigenvalues 1, 0.81, 0.66, ...
@@ -165,17 +187,67 @@ def test_route_records_its_spans(route, monkeypatch):
         # this route jits its evaluation anew in every fit
         assert names <= under, under
     if route == "ridge":
-        # which factorisation solved the system is a fact of the host solve
+        # the host solve builds its system once and factors it once; which
+        # factorisation solved it is a fact of the factoring
         solve = _find(first, "linreg_host_solve")
-        assert [c["name"] for c in solve["children"]] == ["linreg_solver[cholesky]"]
+        assert [c["name"] for c in solve["children"]] == [
+            "linreg_solve_assemble", "linreg_solve_factor", "linreg_solve_release",
+            "linreg_solve_summary"]
+        assert [c["name"] for c in solve["children"][1]["children"]] == [
+            "linreg_solver[cholesky]"]
     if route in ("pca", "pca_subspace"):
-        # each instant under the span whose work it names, the eigensolver's
-        # the one child of its span that is no compile, on either route
-        solver = "host_lapack" if route == "pca" else "subspace_polished"
-        for span, instant in (("pca_covariance", "linreg_gram_kernel[xla]"),
-                              ("pca_eigensolve", f"pca_eigensolver[{solver}]")):
-            inside = [c["name"] for c in _find(first, span)["children"]]
-            assert [n for n in inside if not n.startswith("compile[")] == [instant]
+        # each instant under the span whose work it names; the eigensolve
+        # holds the device loop and one span a float64 sweep, as many as the
+        # instant's `polish_steps` says, or LAPACK where the shape rules the
+        # block iteration out
+        inside = [c["name"] for c in _find(first, "pca_covariance")["children"]]
+        assert [n for n in inside if not n.startswith("compile[")] == [
+            "linreg_gram_kernel[xla]"]
+        solve = _find(first, "pca_eigensolve")["children"]
+        if route == "pca":
+            assert [c["name"] for c in solve] == [
+                "pca_eigensolver[host_lapack]", "pca_lapack"]
+        else:
+            sweeps = int(re.search(r"polish_steps=(\d+)", solve[-1]["detail"]).group(1))
+            assert sweeps >= 1 and [c["name"] for c in solve] == [
+                "pca_subspace_device", *["pca_polish_sweep"] * sweeps,
+                "pca_eigensolver[subspace_polished]"]
+    if route == "pipelined_stage":
+        # a put is the wait for an older piece (from the third piece on: two
+        # may be in flight), the runtime's call and the update's dispatch
+        # ... and the drain under `stage_finish` waits for those still in
+        # flight.  A writer an array: X's eight pieces, then y's and w's one
+        puts, most = 0, 0
+        for node in _find(first, "stage")["children"]:
+            inside = [c["name"] for c in node.get("children", [])
+                      if not c["name"].startswith("compile[")]
+            if node["name"] == "stage_alloc":
+                puts = 0
+            elif node["name"] == "stage_put":
+                wait = ["stage_put_wait"] if puts >= 2 else []
+                assert inside == [*wait, "stage_put_call", "stage_put_update"], (puts, inside)
+                puts += 1
+                most = max(most, puts)
+            elif node["name"] == "stage_finish":
+                assert inside.count("stage_put_wait") == min(puts, 2), (puts, inside)
+        assert most == 8
+
+    # the report times its own assembly: a root after the fit's
+    assert [n["name"] for n in first["spans"]] == [
+        f"fit[{type(est).__name__}]", "fit_report"]
+
+    # a span beneath a leaf span says whether the host waits or works, as
+    # the vocabulary's table has it, under the parent the table names
+    documented = names & set(WAIT_OR_WORK)
+    for node, parent in _walk(first["spans"]):
+        if node["name"] in WAIT_OR_WORK:
+            host, beneath = WAIT_OR_WORK[node["name"]]
+            assert node.get("detail") == host, node
+            assert (parent["name"] in beneath) if parent else not beneath, (node, parent)
+            documented.discard(node["name"])
+        else:
+            assert node.get("detail") not in ("wait", "work"), node
+    assert not documented, documented
 
     # a child lies inside its parent in time
     for node, parent in _walk(first["spans"]):
@@ -202,6 +274,16 @@ def test_route_records_its_spans(route, monkeypatch):
         assert len(spans) == compiled
     assert best >= 0.9, f"{holder} covered to {best:.2f}"
 
+    # the children tile every put (of a millisecond or more: under a put of
+    # 4 MB the spans' own microseconds show), on the same reading of a
+    # loaded host
+    if route == "pipelined_stage":
+        tiled = max(
+            min(_covered(n) for n, _ in _walk(r["spans"])
+                if n["name"] == "stage_put" and n["seconds"] >= 1e-3)
+            for r in reports)
+        assert tiled >= 0.9, f"a stage_put covered to {tiled:.2f}"
+
     # one iteration span an iteration, the rest of the vocabulary once a fit
     if route == "kmeans_stepwise":
         for report in reports:
@@ -220,6 +302,11 @@ def test_route_records_its_spans(route, monkeypatch):
                      if n["name"] == "lbfgs_host_step"]
             assert len(evals) == made >= 6
             assert len(steps) == made + 1  # before, between and after
+            # each evaluation is one dispatch and one wait, and nothing else
+            # but the first's compiles
+            for e in evals:
+                assert [c["name"] for c in e["children"]] == [
+                    "lbfgs_eval_dispatch", "lbfgs_eval_wait"]
     else:
         assert not any(n["name"] == "lbfgs_eval"
                        for r in reports for n, _ in _walk(r["spans"]))
