@@ -240,13 +240,15 @@ _DEFAULTS: Dict[str, Any] = {
     # Per-dispatched-program FLOP budget of the L-BFGS solvers (dense and
     # sparse logistic regression): a solve whose total fitted work
     # exceeds this switches from the fused single-program fit to one
-    # host-dispatched program per evaluation.  KMeans Lloyd no longer
-    # reads it: its route and its block size read device memory
+    # host-dispatched program per evaluation.  A dense fit whose
+    # evaluation is the one-pass kernel does not read it (its route reads
+    # device memory alone, models/classification.py), nor does KMeans
+    # Lloyd: its route and its block size read device memory
     # (ops/kmeans.py kmeans_fit_auto).  2e12 FLOPs (~40 s at v5e f32
     # matmul throughput) was sized for a development link that failed
     # transfers behind long programs; the link is gone and the value is
     # inherited, to be re-justified on the chip or deleted (ROADMAP
-    # Design 3).
+    # Design 2).
     "dispatch_flops_limit": 2e12,
     # MXU precision for sufficient-statistics matmuls feeding a matrix
     # inversion/eigendecomposition (PCA covariance, LinReg Gram) —

@@ -567,40 +567,64 @@ class LogisticRegression(
                 # f32 (the MXU consumes bf16 natively).  Opt-in: costs ~3
                 # decimal digits of feature precision.
                 X = X.astype(jnp.bfloat16)
-            # fused single-program L-BFGS until the whole solve would
-            # exceed the per-program budget (`dispatch_flops_limit`; the
-            # reference 1M x 3000 maxIter=200 config crosses it) or the
-            # program's copy of the features would not fit the device
-            # (`device_cache.fused_program_fits`, the memory test KMeans'
-            # router reads too) — then host-driven L-BFGS, one
-            # evaluation per program.  The FLOP budget is inherited from
-            # a development link that is gone (ROADMAP Design 3)
-            C_eff = 1 if binomial else n_classes
-            per_eval = 4.0 * X.shape[0] * X.shape[1] * C_eff
-            fused_flops = per_eval * max_iter * 2.0  # ~2 evals/iter
-            budget = float(get_config("dispatch_flops_limit"))
-            from ..parallel.device_cache import fused_program_fits
+            # which evaluation either route runs is read from the rows
+            # alone: one read of them an evaluation (a Pallas kernel) for
+            # dense float32 binomial rows on TPUs, autodiff's two otherwise
+            from ..ops.logistic import one_pass_program_bytes
+            from ..ops.pallas_logistic import one_pass_plan
+            from ..parallel.device_cache import bytes_beside, fused_program_fits
 
-            fits = fused_program_fits(X)
-            host_dispatch = fused_flops > budget or bool(ckpt_path) or not fits
-            why = (
-                f"{fused_flops:.2e} fused FLOPs vs budget {budget:.0e}, "
-                f"checkpointing {'on' if ckpt_path else 'off'}, fused "
-                f"program's second copy of the features "
-                f"{'fits' if fits else 'does NOT fit'} the device"
-            )
+            one_pass, why_kernel = one_pass_plan(X, binomial)
+            # fused single-program L-BFGS while the device holds what that
+            # program holds beside the resident rows
+            # (`device_cache.fused_program_fits`, the memory test KMeans'
+            # router reads too), which the evaluation decides: the kernel
+            # reads the rows where they lie, so the program holds a few
+            # rows-length vectors; autodiff's `while_loop` holds a second
+            # copy of the rows.  Else, and for a checkpointed fit (its
+            # state persists per iteration), host-driven L-BFGS, one
+            # evaluation per program.
+            checkpointing = f"checkpointing {'on' if ckpt_path else 'off'}"
+            if one_pass is not None:
+                # no FLOP budget here: the program's length is no memory
+                # question, and the budget's reason was the dispatch
+                # timeout of a development link that is gone (the
+                # published depth on the chip: PERF.md §6, PR 38)
+                held = one_pass_program_bytes(
+                    *X.addressable_shards[0].data.shape, kwargs["history"]
+                )
+                fits = fused_program_fits(X, held, rows_in_place=True)
+                host_dispatch = bool(ckpt_path) or not fits
+                why = (
+                    f"the kernel reads the rows in place: the fused program "
+                    f"holds at most {held:.3g} B beside them, "
+                    f"{bytes_beside(X):.3g} B are free, so it "
+                    f"{'fits' if fits else 'does NOT fit'} the device; "
+                    f"{checkpointing}"
+                )
+            else:
+                # autodiff keeps the per-program budget too
+                # (`dispatch_flops_limit`; the reference 1M x 3000
+                # maxIter=200 config crosses it), inherited from a
+                # development link that is gone (ROADMAP Design 2)
+                C_eff = 1 if binomial else n_classes
+                per_eval = 4.0 * X.shape[0] * X.shape[1] * C_eff
+                fused_flops = per_eval * max_iter * 2.0  # ~2 evals/iter
+                budget = float(get_config("dispatch_flops_limit"))
+                fits = fused_program_fits(X)
+                host_dispatch = fused_flops > budget or bool(ckpt_path) or not fits
+                why = (
+                    f"{fused_flops:.2e} fused FLOPs vs budget {budget:.0e}, "
+                    f"{checkpointing}, fused "
+                    f"program's second copy of the features "
+                    f"{'fits' if fits else 'does NOT fit'} the device"
+                )
             # which solver ran is a fact of the fit: it goes in the fit
             # report's span tree, not only in the log
             event(
                 f"lbfgs_route[{'host_dispatch' if host_dispatch else 'fused'}]",
                 detail=why,
             )
-            # which evaluation either route runs is read from the rows
-            # alone: one read of them an evaluation (a Pallas kernel) for
-            # dense float32 binomial rows on TPUs, autodiff's two otherwise
-            from ..ops.pallas_logistic import one_pass_plan
-
-            one_pass, why_kernel = one_pass_plan(X, binomial)
             event(
                 f"lbfgs_eval_kernel[{'one_pass' if one_pass else 'autodiff'}]",
                 detail=why_kernel,

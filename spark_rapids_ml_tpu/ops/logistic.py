@@ -238,6 +238,19 @@ def _one_pass_builder(one_pass, X):
     return partial(one_pass_data_term, one_pass, X)
 
 
+def one_pass_program_bytes(shard_rows: int, d: int, history: int) -> int:
+    """Upper bound of the bytes one device holds beside its `shard_rows` x
+    `d` shard of the rows while the fused fit runs with a one-pass plan
+    (`logreg_fit_binary(..., one_pass=plan)`), from its shapes: eight
+    rows-length 4-byte vectors (the arguments `w` and `y`; `sgn`, their
+    (1, rows) views and the margins' row as temporaries), the kernel's two
+    (d, 128) accumulators and the L-BFGS state (2 x `history` pairs and a
+    few vectors of d + 1).  Compiled for a v5e the temporaries read 14 B a
+    row: 14.7 MB at 1M x 3000, 23.5 MB with `w` and `y`, where this gives
+    35.4 MB (`tests/test_pallas_logistic.py` holds every compile under it)."""
+    return 4 * (8 * shard_rows + 2 * 128 * d + (2 * history + 8) * (d + 1))
+
+
 @partial(
     jax.jit,
     static_argnames=("fit_intercept", "max_iter", "history", "ls_max", "one_pass"),

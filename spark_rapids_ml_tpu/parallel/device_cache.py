@@ -115,19 +115,27 @@ def bytes_beside(X) -> int:
     return max(0, device_hbm_bytes(shard.device) - shard.data.nbytes)
 
 
-def fused_program_fits(X, temp_bytes: int = 0) -> bool:
-    """Whether one device can hold its shard of `X` TWICE beside
-    `temp_bytes` of the program's own temporaries, which a solver fused
-    into one `while_loop` program needs: XLA copies the loop-invariant
-    operands of a `while_loop` out of the read-only entry parameters into
-    the loop's own state, so the program carries a second resident copy
-    of the features as a temp.  Measured on a v5e at the reference's
-    1M x 3000 (the fused L-BFGS): 11.78 GB of HLO temp beside 11.51 GB
-    of arguments — 23.3 GB asked of a 15.75 GB chip, a compile-time
-    RESOURCE_EXHAUSTED.  A host-dispatched program has no loop and no
-    copy.  The ONE memory test every fused-vs-host-dispatched router
-    reads (logistic L-BFGS, KMeans Lloyd)."""
-    return X.addressable_shards[0].data.nbytes + temp_bytes <= bytes_beside(X)
+def fused_program_fits(X, temp_bytes: int = 0, rows_in_place: bool = False) -> bool:
+    """Whether one device can hold what a solver fused into one
+    `while_loop` program holds beside its shard of the resident rows `X`:
+    `temp_bytes` of the program's own temporaries and, unless the loop
+    reads the rows in place, the shard a SECOND time.  XLA copies the
+    loop-invariant operands of a `while_loop` out of the read-only entry
+    parameters into the loop's own state, so an autodiff or matmul
+    program carries a second resident copy of the features as a temp.
+    Measured on a v5e at the reference's 1M x 3000 (the fused L-BFGS with
+    autodiff): 11.78 GB of HLO temp beside 11.51 GB of arguments, 23.3 GB
+    asked of a 15.75 GB chip, a compile-time RESOURCE_EXHAUSTED.
+    `rows_in_place`: the loop reads the rows through a kernel from a
+    bitcast of the resident buffer (the one-pass logistic evaluation,
+    `ops/pallas_logistic.py`), and no copy is made: compiled for a v5e at
+    that shape the fused program holds 14.7 MB of temp beside 12.01 GB of
+    arguments (`tests/test_pallas_logistic.py` keeps it).  A
+    host-dispatched program has no loop and no copy.  The ONE memory test
+    every fused-vs-host-dispatched router reads (logistic L-BFGS, KMeans
+    Lloyd)."""
+    copy = 0 if rows_in_place else X.addressable_shards[0].data.nbytes
+    return copy + temp_bytes <= bytes_beside(X)
 
 
 def device_data_budget_bytes() -> float:

@@ -23,6 +23,7 @@ from spark_rapids_ml_tpu.ops.logistic import (
     _binary_problem,
     logreg_fit_binary,
     logreg_fit_host_dispatch,
+    one_pass_program_bytes,
 )
 
 TILE = 128
@@ -175,14 +176,18 @@ def as_on_tpu(monkeypatch):
     _pass_for_tpu(monkeypatch)
 
 
-def _eval_kernel_events(model):
+def _events(model, prefix):
     def find(nodes):
         for n in nodes:
-            if n["name"].startswith("lbfgs_eval_kernel["):
+            if n["name"].startswith(prefix):
                 yield n["name"], n.get("detail", "")
             yield from find(n.get("children", []))
 
     return list(find(model.fit_report()["spans"]))
+
+
+def _eval_kernel_events(model):
+    return _events(model, "lbfgs_eval_kernel[")
 
 
 def _dense(rng, n=640, d=16):
@@ -232,7 +237,22 @@ def test_every_other_input_keeps_autodiff(rng, monkeypatch, fit, why):
     assert why in detail
 
 
-def test_dense_f32_binomial_on_tpu_takes_the_kernel(rng, monkeypatch):
+@pytest.fixture
+def interpreted_plan(monkeypatch):
+    """A backend that passes for a TPU, and the plan a fit makes there
+    turned to Pallas' interpreter."""
+    _pass_for_tpu(monkeypatch)
+    planned = pk.one_pass_plan
+
+    def interpreted(X, binomial):
+        plan, why = planned(X, binomial)
+        assert plan is None or not plan.interpret  # production never interprets
+        return plan and plan._replace(interpret=True), why
+
+    monkeypatch.setattr(pk, "one_pass_plan", interpreted)
+
+
+def test_dense_f32_binomial_on_tpu_takes_the_kernel(rng, request):
     """The backend and layout checks stubbed, a dense float32 binomial fit
     records `lbfgs_eval_kernel[one_pass]` and runs the kernel (the plan it
     made, turned to the interpreter here): the same model as the autodiff
@@ -240,21 +260,72 @@ def test_dense_f32_binomial_on_tpu_takes_the_kernel(rng, monkeypatch):
     X, y = _dense(rng)
     kw = dict(regParam=0.01, maxIter=25, tol=1e-9)
     plain = LogisticRegression(**kw).fit((X, y))
-    _pass_for_tpu(monkeypatch)
-    planned = pk.one_pass_plan
-
-    def interpreted(X, binomial):
-        plan, why = planned(X, binomial)
-        assert not plan.interpret  # production never interprets
-        return plan._replace(interpret=True), why
-
-    monkeypatch.setattr(pk, "one_pass_plan", interpreted)
+    request.getfixturevalue("interpreted_plan")
     model = LogisticRegression(**kw).fit((X, y))
     ((name, detail),) = _eval_kernel_events(model)
     assert name == "lbfgs_eval_kernel[one_pass]"
     assert "float32" in detail and "binomial" in detail
     np.testing.assert_allclose(model.coef_, plain.coef_, atol=1e-5)
     np.testing.assert_allclose(model.intercept_, plain.intercept_, atol=1e-5)
+
+
+@pytest.fixture
+def conf():
+    """`set_config` for one test."""
+    yield set_config
+    reset_config()
+
+
+@pytest.mark.parametrize("budget", [None, 1.0, 1e6], ids=["default", "1", "1e6"])
+def test_a_kernel_fit_is_routed_by_memory_alone(rng, interpreted_plan, conf, budget):
+    """A fit with a one-pass plan takes the fused program whatever the
+    per-program FLOP budget says: its program holds a few rows-length
+    vectors beside the rows, and the instant says so in bytes."""
+    if budget is not None:
+        conf(dispatch_flops_limit=budget)
+    model = LogisticRegression(regParam=0.01, maxIter=10).fit(_dense(rng))
+    ((route, detail),) = _events(model, "lbfgs_route[")
+    assert route == "lbfgs_route[fused]"
+    assert "in place" in detail and "fits the device" in detail
+    assert "budget" not in detail and "checkpointing off" in detail
+    assert [n for n, _ in _eval_kernel_events(model)] == ["lbfgs_eval_kernel[one_pass]"]
+
+
+def test_a_checkpointed_kernel_fit_stays_host_dispatched(
+        rng, interpreted_plan, conf, tmp_path):
+    """Its state must persist per iteration: one program a fit has no
+    iteration boundary to write it at."""
+    conf(checkpoint_dir=str(tmp_path))
+    model = LogisticRegression(regParam=0.01, maxIter=10).fit(_dense(rng))
+    ((route, detail),) = _events(model, "lbfgs_route[")
+    assert route == "lbfgs_route[host_dispatch]" and "checkpointing on" in detail
+    assert [n for n, _ in _eval_kernel_events(model)] == ["lbfgs_eval_kernel[one_pass]"]
+
+
+def test_a_kernel_fit_whose_program_does_not_fit_stays_host_dispatched(
+        rng, interpreted_plan, conf):
+    """By arithmetic, not by a compile-time OOM: the rows, padded to 768,
+    are 49,152 bytes on their one device, the program's bound beside them
+    42,864, and the device has 31,808 left."""
+    conf(hbm_bytes=49_152 + 31_808)
+    est = LogisticRegression(regParam=0.01, maxIter=10, num_workers=1)
+    ((route, detail),) = _events(est.fit(_dense(rng)), "lbfgs_route[")
+    assert route == "lbfgs_route[host_dispatch]" and "does NOT fit" in detail
+    assert "at most 4.29e+04 B beside them, 3.18e+04 B are free" in detail
+
+
+@pytest.mark.parametrize("fit", [_fit_multinomial, _fit_dense, _fit_bf16],
+                         ids=["multinomial", "cpu_backend", "bf16_features"])
+def test_a_fit_without_a_plan_keeps_the_budget(rng, monkeypatch, conf, fit):
+    """Autodiff's program holds the rows twice and keeps the per-program
+    budget: the old rule, in the old words."""
+    if fit is not _fit_dense:
+        _pass_for_tpu(monkeypatch)
+    assert _events(fit(rng), "lbfgs_route[")[0][0] == "lbfgs_route[fused]"
+    conf(dispatch_flops_limit=1e4)
+    ((route, detail),) = _events(fit(rng), "lbfgs_route[")
+    assert route == "lbfgs_route[host_dispatch]"
+    assert "fused FLOPs vs budget 1e+04" in detail and "second copy" in detail
 
 
 @pytest.mark.parametrize(
@@ -414,6 +485,57 @@ def test_four_chip_fused_fit_reads_its_shards_in_place(topo):
     # one all-reduce of [gradient, sum r, loss] per evaluation site
     reduces = [l for l in compiled.as_text().splitlines() if " all-reduce(" in l]
     assert len([l for l in reduces if f"f32[{d + 2}]" in l]) == 3, reduces
+
+
+@pytest.mark.parametrize(
+    "rows,max_iter", [(1_000_000, 20), (1_048_576, 20), (1_000_000, 200)],
+    ids=["1m", "ingest_bucket", "published_depth"],
+)
+def test_one_chip_fused_fit_reads_its_rows_in_place(topo, rows, max_iter):
+    """The whole fit as ONE program on one chip, at the cached cell's rows,
+    at the padded bucket a staged fit of them gets, and at the published
+    depth: no second copy of the rows in the `while_loop`'s state, and what
+    the program holds beside them within the bound its router counts."""
+    from jax.sharding import SingleDeviceSharding
+
+    one, d = SingleDeviceSharding(topo.devices[0]), 3000
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = logreg_fit_binary.lower(
+        spec((rows, d)), spec((rows,)), spec((rows,), jnp.int32), l2=1e-5, l1=0.0,
+        fit_intercept=True, tol=1e-30, max_iter=max_iter, one_pass=pk.OnePass(None),
+    ).compile()
+    _no_copy_of_the_rows(compiled, rows)
+    # the first evaluation, the line search's first trial and its retries
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    memory = compiled.memory_analysis()
+    beside = memory.temp_size_in_bytes + memory.argument_size_in_bytes - rows * d * 4
+    assert beside <= one_pass_program_bytes(rows, d, 10) < 64 << 20
+
+
+@pytest.mark.parametrize(
+    "hbm_bytes,in_place,fits",
+    [(16_909_336_064, True, True), (12_010_000_000, True, False),
+     (16_909_336_064, False, False), (24_100_000_000, False, True)],
+    ids=["kernel_15.75GiB", "kernel_12.01GB", "autodiff_15.75GiB", "autodiff_24.1GB"],
+)
+def test_the_memory_test_counts_what_the_program_holds(conf, hbm_bytes, in_place, fits):
+    """12 GB of rows on one chip, more than the CPU holds, so a stand-in for
+    them: a kernel fit's program holds 35 MB beside them, an autodiff
+    fit's a second 12 GB."""
+    from types import SimpleNamespace as NS
+
+    from spark_rapids_ml_tpu.parallel.device_cache import fused_program_fits
+
+    conf(hbm_bytes=hbm_bytes)
+    rows, d = 1_000_000, 3000
+    X = NS(addressable_shards=[NS(
+        device=jax.devices()[0], data=NS(nbytes=rows * d * 4, shape=(rows, d)))])
+    held = one_pass_program_bytes(rows, d, 10)
+    assert 30e6 < held < 40e6
+    assert fused_program_fits(X, held if in_place else 0, rows_in_place=in_place) is fits
 
 
 # -- PCA's covariance (ops/pca.py `pca_scatter`), compiled for the same chip: the

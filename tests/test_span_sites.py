@@ -153,8 +153,8 @@ def _backend_compiles():
 def test_route_records_its_spans(route, monkeypatch):
     build, (n, d), holder, names = ROUTES[route]
     if route == "logistic_host_dispatch":
-        # the per-program budget sends a toy fit down the route the one-chip
-        # cells take
+        # the per-program budget sends a toy autodiff fit down the route that
+        # checkpointed fits and the fits the kernel declines take
         set_config(dispatch_flops_limit=1.0)
     if route == "kmeans_stepwise":
         # 16.8 MB of rows, and a device that cannot hold them twice
