@@ -628,6 +628,16 @@ class RandomForestRegressor(_RandomForestEstimator):
     (each device fits numTrees/num_workers trees on its local rows,
     reference tree.py:330-341, docstring regression.py:895-899).
 
+    `featureSubsetStrategy="auto"` is a third of the columns a node
+    (Spark's rule for regression).  A level's (node, feature, bin)
+    histogram of (w, w y, w y^2) is real-valued: float32 sums of labels
+    less one constant per worker (its weighted mean label), added a panel
+    of features at a time where a node reads many, and a split's gain is
+    ranked as (S_l - n_l S/n)^2 / (n_l n_r), so that labels with a mean of
+    thousands of standard deviations split as float64 would split them.
+    The fitted model's leaves hold (w, sum y, sum y^2) of the labels as
+    given; a forest is bit-identical from fit to fit (`ops/forest.py`).
+
     Examples
     --------
     >>> import numpy as np, pandas as pd

@@ -18,17 +18,39 @@
 #     its rows in a TILED, NODE-SORTED layout: (tiles, T) row ids, every
 #     tile the rows of ONE frontier node (a node's rows padded to whole
 #     tiles with weightless pads).  Per level: the tiles' packed rows are
-#     gathered, the node's K features selected out of them by a one-hot
-#     (features x K) matmul (bin ids are exact in bfloat16), the
-#     (node, K, bin, stat) histogram accumulated by a one-hot (rows x
-#     K*bins) matmul against the rows' statistics split into three exact
-#     bfloat16 parts (integer counts stay exact, real sums f32), cumulative
-#     sums over bins give every candidate split's left/right statistics,
-#     an argmax picks the best (feature, bin) per node, and ONE stable sort
-#     by next-level node (with a pool of pads that rounds every node up to
-#     whole tiles) is the next level's layout.  Rows of nodes that stop
-#     leave the layout; a leaf's statistics are its parent's histogram at
-#     the chosen split.  No per-row scatter, no per-row table gather.
+#     gathered, the node's K features selected out of them (a few: by a
+#     one-hot (features x K) matmul, bin ids being exact in bfloat16; from
+#     a quarter of a packed row's words on: the words laid rows-minor and
+#     each feature's word taken as a row, `selects_by_take`), the (node, K,
+#     bin, stat) histogram accumulated by a one-hot (rows x K*bins) matmul
+#     against the rows' statistics split into three exact bfloat16 parts
+#     (integer counts stay exact, real sums f32), cumulative sums over bins
+#     give every candidate split's left/right statistics, an argmax picks
+#     the best (feature, bin) per node, and ONE stable sort by next-level
+#     node (with a pool of pads that rounds every node up to whole tiles)
+#     is the next level's layout.  Rows of nodes that stop leave the
+#     layout; a leaf's statistics are its parent's histogram at the chosen
+#     split.  No per-row scatter, no per-row table gather.
+#   - PANELS.  A selection wider than `feature_panel` features (8,192
+#     one-hot columns: 64 features at 128 bins) is multiplied a panel of
+#     features at a time inside each scan step, the ids padded to whole
+#     panels with the node's last feature (its columns dropped): no array
+#     of rows x K x bins is alive at once, and a column's sum is the same
+#     dot over a tile's rows whatever the panel's width.  The classifier's
+#     floor(sqrt(d)) features are one panel, the product taken whole.
+#   - REAL-VALUED STATISTICS (variance).  A regression tree's channels are
+#     (w, w y', w y'^2) with y' = y - c, c ONE constant per worker (THE
+#     DRAWS, below): every f32 sum of the build is of labels less the
+#     shift.  A split's gain is (S_l - n_l S/n)^2 / (n_l n_r)
+#     (`_variance_gain`): the variance decrease with nothing of the size of
+#     mean^2 cancelling, and no part for sum y^2, which is carried for the
+#     leaves alone.  A child's statistics are added up from its own bins of
+#     the split's feature, never the node's less its sibling's.  The sums
+#     run in a fixed order (a tile's rows in one dot, tiles in scan order),
+#     so a forest is bit-identical from fit to fit and for any chunking.
+#     The node table's leaves are put back at the end of `_grow_one_tree`,
+#     in float32: (n, S1' + c n, S2' + c (2 S1' + c n)), the model's
+#     contract (w, sum y, sum y^2) of the labels as given.
 #   - Children are allocated in an explicit node TABLE (`left_child`
 #     pointers) whose size is 1 + sum_l 2*min(2^l, max_active).  The
 #     default `max_active` is the worker's row count, which no frontier can
@@ -43,12 +65,14 @@
 #     by cuML's GPU forest).
 #   - Trees are dispatched in equal chunks sized from the shapes and the
 #     memory the device has left (`chunk_trees_for`), a chunk's trees under
-#     one vmap; across the mesh, trees are embarrassingly parallel
+#     one vmap (one after another where a level is panelled: the panels
+#     count on the chip's fast memory, which a vmap over trees multiplies
+#     out of it); across the mesh, trees are embarrassingly parallel
 #     (shard_map with no collectives — the analog of reference tree.py's
 #     barrier-allGather-only pattern).
 #
 # THE DRAWS, stated so that a reference can re-derive them with jax.random
-# alone (chipbench/estimators/rfc.py does).  Worker `i` (its position on
+# alone (chipbench/estimators/rfc.py and rfr.py do).  Worker `i` (its position on
 # the mesh's data axis) holds m rows (padding included) and
 # base = fold_in(PRNGKey(seed), i).
 #   edges   q = max(1, m // edge_sample_rows(n_bins)), S = m // q; sample
@@ -63,6 +87,11 @@
 #           gumbel(fold_in(kf, l), (A_l, d), the rows' dtype)[s], A_l =
 #           min(2^l, max_active).  Uncapped, slot s of level l is table node
 #           2^l - 1 + s.
+#   shift   (regression) c = float32(sum_i v_i y_i / sum_i v_i) over the
+#           worker's rows, v the row's validity times its weight
+#           (`label_shift`, one small program beside the bins).  Any value
+#           near the mean does: gains and leaves do not depend on it in
+#           exact arithmetic, so a reference needs none.
 #
 from __future__ import annotations
 
@@ -84,6 +113,9 @@ EDGE_RULE = "stratified sample of max(n_bins^2, 10000) rows per worker"
 _BIN_BLOCK_ROWS = 32_768
 _SCAN_ROWS = 8_192
 _MAX_TILE_ROWS = 512
+# one-hot columns (features x bins) to one product of a scan step: wider
+# selections are multiplied a panel of features at a time
+_PANEL_COLUMNS = 8_192
 
 
 def edge_sample_rows(n_bins: int) -> int:
@@ -191,6 +223,23 @@ def _impurity(stats: jax.Array, criterion: int) -> jax.Array:
     return jnp.where(n > 0, imp, 0.0), n
 
 
+def _variance_gain(node, left, right):
+    """Variance decrease of every candidate split from (weight, sum y,
+    sum y^2) statistics: node (A, 3), left and right (A, K, B-1, 3).
+    Returns (gain, n, n_left, n_right).
+
+    var - (n_l var_l + n_r var_r) / n is (S_l - n_l S/n)^2 / (n_l n_r):
+    what cancels is a child's sum against its share of the node's, a
+    difference whose square IS the gain, and never sum y^2 / n against
+    mean^2 (6e-8 of mean^2, which passes the gains' own size once the mean
+    is a few hundred standard deviations).  sum y^2 takes no part: it is
+    carried for the leaves alone."""
+    n, n_left, n_right = node[:, 0], left[..., 0], right[..., 0]
+    mean = (node[:, 1] / jnp.maximum(n, 1e-12))[:, None, None]
+    dev = left[..., 1] - n_left * mean
+    return dev * dev / jnp.maximum(n_left * n_right, 1e-12), n, n_left, n_right
+
+
 class TreeArrays(NamedTuple):
     feature: jax.Array  # (T, n_nodes) int32 split feature, -1 = leaf
     threshold: jax.Array  # (T, n_nodes) f32 raw-value threshold (go left if <=)
@@ -217,6 +266,25 @@ def _level_shape(m: int, A: int):
     tiles = -(-m // T) + A
     step = max(1, min(_SCAN_ROWS // T, tiles))
     return T, -(-tiles // step) * step, step
+
+
+def feature_panel(max_features: int, n_bins: int) -> int:
+    """Features to one panel of a level's histogram: as many as keep a
+    panel's one-hot within `_PANEL_COLUMNS` columns (64 at 128 bins).  A
+    selection no wider than that is one panel, the product taken whole."""
+    return max(1, min(max_features, _PANEL_COLUMNS // n_bins))
+
+
+def selects_by_take(max_features: int, d: int) -> bool:
+    """Whether a node's features are taken out of the gathered rows as
+    columns (a transpose of the packed words, then one row gather a
+    feature) or by the one-hot (features x words) product.  The product's
+    cost grows with the features a node, the take's hardly: on a v5e at
+    3,000 columns the take is 9.0 ms a level of 336,000 rows at 1,000
+    features where the product is 17.2 (PERF.md, PR 39), and the product
+    wins under a few hundred.  By the shapes: the take from the width at
+    which a node's features fill a quarter of a packed row's words."""
+    return 4 * max_features >= pack_words(d)
 
 
 def _layout(key, rowid, w, y, counts, m: int, A: int, T: int, tiles: int):
@@ -268,13 +336,20 @@ def _row_stats(w, y, criterion: int, n_stats: int):
 
 def _three_parts(x, dtype):
     """f32 (…, S, T) -> (…, 3S, T): three parts of `dtype` (bfloat16 on a
-    TPU) that add up to it exactly, so a one-hot product with them is as
-    exact as f32."""
-    hi = x.astype(dtype)
-    r1 = x - hi.astype(jnp.float32)
-    mid = r1.astype(dtype)
-    lo = (r1 - mid.astype(jnp.float32)).astype(dtype)
-    return jnp.concatenate([hi, mid, lo], axis=-2)
+    TPU) that add up to it (to its last bit or two: 24 significand bits in
+    three of 8), so a one-hot product with them is as exact as f32.  Each
+    part is cut by `reduce_precision`, not by a cast there and back: XLA
+    may elide that pair on a TPU (xla_allow_excess_precision) and leave no
+    remainder, which made every real-valued sum a one-part bfloat16 sum
+    (2e-3 of a leaf's sum y on the chip, PERF.md PR 39; counts never
+    showed it: they are exact in the first part)."""
+    info = jnp.finfo(dtype)
+    parts, rest = [], x
+    for _ in range(3):
+        part = jax.lax.reduce_precision(rest, exponent_bits=info.nexp, mantissa_bits=info.nmant)
+        parts.append(part.astype(dtype))
+        rest = rest - part
+    return jnp.concatenate(parts, axis=-2)
 
 
 def _grow_one_tree(
@@ -283,6 +358,7 @@ def _grow_one_tree(
     edges: jax.Array,  # (B-1, d) raw edge values
     y: jax.Array,  # (m,) labels
     valid: jax.Array,  # (m,) row validity * user weight
+    shift,  # () the worker's label shift (`label_shift`); 0 for classes
     max_depth: int,
     n_bins: int,
     criterion: int,
@@ -299,6 +375,9 @@ def _grow_one_tree(
     m, W = packed.shape
     d = edges.shape[1]
     S, K, B = n_stats, max_features, n_bins
+    panel = feature_panel(K, B)
+    panels = -(-K // panel)
+    K_sel = panels * panel  # features selected a node: K, padded to whole panels
     dtype = edges.dtype
     n_nodes = table_nodes(max_depth, max_active)
 
@@ -310,6 +389,10 @@ def _grow_one_tree(
     else:
         w = jnp.ones((m,), dtype)
     w = w * valid.astype(dtype)
+    if criterion == VARIANCE:
+        # every sum of the build is of labels less the shift; the node
+        # table's leaves are put back below
+        y = y.astype(dtype) - shift
 
     # node-table arrays carry ONE trash row at index n_nodes: writes for
     # empty frontier slots land there instead of corrupting real nodes
@@ -336,16 +419,28 @@ def _grow_one_tree(
         else:
             feats = jnp.broadcast_to(jnp.arange(d, dtype=jnp.int32), (A_l, d))
 
+        # whole panels: the last feature again, its columns dropped
+        feats_sel = jnp.pad(feats, ((0, 0), (0, K_sel - K)), mode="edge")
+
         def chunks(a):
             return a.reshape((tiles // step, step) + a.shape[1:])
 
         # the rows' bin ids of their node's features, rows minor: (tiles, K, T)
         def select(args):
-            rows, node_feats = args  # (step, T), (step, K)
-            planes = _unpack_planes(jnp.take(packed, jnp.maximum(rows, 0), axis=0), operand)
+            rows, node_feats = args  # (step, T), (step, K_sel)
+            words = jnp.take(packed, jnp.maximum(rows, 0), axis=0)  # (step, T, W)
             if K == d:
-                ids = jnp.concatenate(planes, axis=-1)[..., :d]
+                ids = jnp.concatenate(_unpack_planes(words, operand), axis=-1)[..., :d]
+                ids = jnp.pad(ids, ((0, 0), (0, 0), (0, K_sel - K)))
                 return jnp.swapaxes(ids, 1, 2).astype(jnp.uint8)
+            if selects_by_take(K, d):
+                # the words laid rows-minor, then each feature's word taken
+                # as one row of T and its byte shifted out: no product
+                lanes = jnp.swapaxes(words, 1, 2).reshape(step * W, T)
+                at = jnp.arange(step, dtype=jnp.int32)[:, None] * W + node_feats % W
+                col = jnp.take(lanes, at.reshape(-1), axis=0).reshape(step, K_sel, T)
+                return ((col >> (8 * (node_feats // W))[:, :, None]) & 0xFF).astype(jnp.uint8)
+            planes = _unpack_planes(words, operand)
             words = jnp.arange(W, dtype=jnp.int32)
             sel = sum(
                 jnp.einsum(
@@ -356,24 +451,45 @@ def _grow_one_tree(
             return sel.astype(jnp.uint8)
 
         with jax.named_scope("forest_hist"):
-            sel = jax.lax.map(select, (chunks(rowid), chunks(feats[slot_c])))
-            sel = sel.reshape(tiles, K, T)
+            sel = jax.lax.map(select, (chunks(rowid), chunks(feats_sel[slot_c])))
+            sel = sel.reshape(tiles, K_sel, T)
             stats3 = _three_parts(_row_stats(wt, yt, criterion, S), operand)  # (tiles, 3S, T)
+
+            def three_in_one(part):  # (step, 3S, ...) -> (step, S, ...)
+                return (part[:, :S] + part[:, S:2 * S] + part[:, 2 * S:]).astype(dtype)
 
             # (A_l + 1, S, K*B): a tile's one-hot product, added to its node
             def accumulate(hist, args):
                 s, st, node = args  # (step, K, T), (step, 3S, T), (step,)
-                onehot = (s[:, :, None, :] == jnp.arange(B, dtype=jnp.uint8)[:, None]).astype(
-                    operand).reshape(step, K * B, T)
-                part = jnp.einsum("cst,cqt->csq", st, onehot,
-                                  preferred_element_type=jnp.float32)
-                part = part[:, :S] + part[:, S:2 * S] + part[:, 2 * S:]
-                return hist.at[node].add(part.astype(dtype)), None
+                bins = jnp.arange(B, dtype=jnp.uint8)[:, None]
+                if panels == 1:
+                    onehot = (s[:, :, None, :] == bins).astype(operand).reshape(step, K * B, T)
+                    part = jnp.einsum("cst,cqt->csq", st, onehot,
+                                      preferred_element_type=jnp.float32)
+                    return hist.at[node].add(three_in_one(part)), None
+
+                # a panel's product keeps features and bins apart: merged into
+                # one axis, the ids' broadcast along the bins is an array of
+                # its own (a third of a level on a v5e); apart, XLA makes it
+                # inside the product (41 ms a level for 100, PERF.md PR 39)
+                def one_panel(p, hist):  # (panels, A_l + 1, S, panel, B)
+                    sp = jax.lax.dynamic_slice_in_dim(s, p * panel, panel, axis=1)
+                    onehot = (sp[:, :, None, :] == bins).astype(operand)
+                    part = jnp.einsum("cst,ckbt->cskb", st, onehot,
+                                      preferred_element_type=jnp.float32)
+                    return hist.at[p, node].add(three_in_one(part))
+
+                return jax.lax.fori_loop(0, panels, one_panel, hist), None
 
             hist, _ = jax.lax.scan(
-                accumulate, jnp.zeros((A_l + 1, S, K * B), dtype),
+                accumulate,
+                jnp.zeros((A_l + 1, S, K * B) if panels == 1
+                          else (panels, A_l + 1, S, panel, B), dtype),
                 (chunks(sel), chunks(stats3), chunks(jnp.where(live, tile_slot, A_l))))
-            hist = hist[:A_l].reshape(A_l, S, K, B).transpose(0, 2, 3, 1)  # (A,K,B,S)
+            if panels == 1:
+                hist = hist[:A_l].reshape(A_l, S, K, B).transpose(0, 2, 3, 1)  # (A,K,B,S)
+            else:
+                hist = hist[:, :A_l].transpose(1, 0, 3, 4, 2).reshape(A_l, K_sel, B, S)[:, :K]
 
         with jax.named_scope("forest_split"):
             cum = jnp.cumsum(hist, axis=2)
@@ -381,14 +497,17 @@ def _grow_one_tree(
             left = cum[:, :, : B - 1, :]  # (A_l, K, B-1, S)
             right = total[:, :, None, :] - left
 
-            imp_parent, n_parent = _impurity(total[:, 0, :], criterion)  # (A_l,)
-            imp_l, n_left = _impurity(left, criterion)  # (A_l, K, B-1)
-            imp_r, n_right = _impurity(right, criterion)
-            safe_np = jnp.maximum(n_parent, 1e-12)[:, None, None]
-            gain = (
-                imp_parent[:, None, None]
-                - (n_left * imp_l + n_right * imp_r) / safe_np
-            )
+            if criterion == VARIANCE:
+                gain, n_parent, n_left, n_right = _variance_gain(total[:, 0, :], left, right)
+            else:
+                imp_parent, n_parent = _impurity(total[:, 0, :], criterion)  # (A_l,)
+                imp_l, n_left = _impurity(left, criterion)  # (A_l, K, B-1)
+                imp_r, n_right = _impurity(right, criterion)
+                safe_np = jnp.maximum(n_parent, 1e-12)[:, None, None]
+                gain = (
+                    imp_parent[:, None, None]
+                    - (n_left * imp_l + n_right * imp_r) / safe_np
+                )
             ok = (n_left >= min_instances) & (n_right >= min_instances)
             gain = jnp.where(ok, gain, -jnp.inf)
 
@@ -420,8 +539,17 @@ def _grow_one_tree(
                 jnp.where(can_split[:, None], 0.0, node_stats))
 
             # the children, as candidates 2*slot (left) and 2*slot + 1
-            child_stats = jnp.stack(
-                [left_stats, node_stats - left_stats], axis=1).reshape(2 * A_l, S)
+            if criterion == VARIANCE:
+                # real-valued sums: each child's added up from its own bins
+                # of the split's feature; the node's less the left child's
+                # would leave a small right child the node's rounding
+                picked = jnp.take_along_axis(hist, bj[:, None, None, None], axis=1)[:, 0]
+                to_left = (jnp.arange(B, dtype=jnp.int32) <= bb[:, None])[:, :, None]
+                left_stats = jnp.where(to_left, picked, 0.0).sum(axis=1)
+                right_stats = jnp.where(to_left, 0.0, picked).sum(axis=1)
+            else:
+                right_stats = node_stats - left_stats
+            child_stats = jnp.stack([left_stats, right_stats], axis=1).reshape(2 * A_l, S)
             cand_counts = _impurity(child_stats, criterion)[1]
             cand_valid = jnp.repeat(can_split, 2)
             cand_ids = base + jnp.arange(2 * A_l, dtype=jnp.int32)
@@ -454,7 +582,7 @@ def _grow_one_tree(
         if not last:
             with jax.named_scope("forest_route"):
                 # left child if bin id <= split bin, read off the selected ids
-                pick = jnp.arange(K, dtype=jnp.int32)[:, None] == bj[slot_c][:, None, None]
+                pick = jnp.arange(K_sel, dtype=jnp.int32)[:, None] == bj[slot_c][:, None, None]
                 row_bin = jnp.where(pick, sel, 0).max(axis=1)  # (tiles, T)
                 go_left = row_bin <= bb[slot_c][:, None].astype(jnp.uint8)
                 held = live[:, None] & (rowid >= 0)
@@ -504,6 +632,12 @@ def _grow_one_tree(
         # final level: no next-frontier bookkeeping (nothing grows past it)
         state, layout = level_step(max_depth - 1, max_active, state, layout, last=True)
     feature, threshold, gain_arr, count_arr, left_arr, leaf_stats = state[:6]
+    if criterion == VARIANCE:
+        # the model's contract is (w, sum y, sum y^2) of the labels as given:
+        # sum (y' + c) = S1' + c n, sum (y' + c)^2 = S2' + 2 c S1' + c^2 n
+        n, s1, s2 = leaf_stats[:, 0], leaf_stats[:, 1], leaf_stats[:, 2]
+        leaf_stats = jnp.stack(
+            [n, s1 + shift * n, s2 + shift * (2.0 * s1 + shift * n)], axis=1)
     return TreeArrays(
         feature[:n_nodes],
         threshold[:n_nodes],
@@ -580,6 +714,29 @@ def _forest_bin_block(packed, X, edges, k, rows: int, mesh=None):
     return _local(kernel, mesh, 3, 1)(packed, X, edges, jnp.asarray(k, jnp.int32))
 
 
+@partial(jax.jit, static_argnames=("mesh",))
+def _forest_label_shift(y, valid, mesh=None):
+    """(n_dev,) each worker's weighted mean label, float32."""
+
+    def kernel(yl, validl):
+        v = validl.astype(jnp.float32)
+        return (jnp.sum(v * yl.astype(jnp.float32)) / jnp.maximum(jnp.sum(v), 1e-12))[None]
+
+    return _local(kernel, mesh, 2)(y, valid)
+
+
+def label_shift(y, valid, criterion: int, mesh):
+    """The constant each worker takes off its labels before a regression
+    tree's sums are formed (THE DRAWS, above): its weighted mean label.
+    Zeros, and no program, for a classifier's classes."""
+    from jax.sharding import NamedSharding
+
+    if criterion != VARIANCE:
+        return jnp.zeros((int(mesh.devices.size),), jnp.float32,
+                         device=NamedSharding(mesh, P(DATA_AXIS)))
+    return _forest_label_shift(y, valid, mesh=mesh)
+
+
 def forest_bins(X, valid, seed, n_bins: int, mesh):
     """(packed bin ids (N_pad, W) int32, edges (n_dev * (n_bins-1), d)),
     both sharded like the rows: the edge sample gathered and the rows
@@ -615,7 +772,7 @@ def forest_bins(X, valid, seed, n_bins: int, mesh):
     ),
 )
 def _forest_fit_chunk(
-    packed, edges, y, valid, seed, lo,
+    packed, edges, y, valid, shift, seed, lo,
     count: int,
     trees_per_worker: int,
     max_depth: int,
@@ -637,7 +794,7 @@ def _forest_fit_chunk(
     forest is identical for any chunking.  Returns (TreeArrays, per tree
     whether its rows of positive weight passed `room`)."""
 
-    def kernel(packedl, edgesl, yl, validl, lo_):
+    def kernel(packedl, edgesl, yl, validl, shiftl, lo_):
         widx = jax.lax.axis_index(DATA_AXIS)
         base = jax.random.fold_in(jax.random.PRNGKey(seed), widx)
         keys = jax.lax.dynamic_slice_in_dim(
@@ -649,6 +806,7 @@ def _forest_fit_chunk(
             edges=edgesl,
             y=yl,
             valid=validl,
+            shift=shiftl[0],
             max_depth=max_depth,
             n_bins=n_bins,
             criterion=criterion,
@@ -664,11 +822,17 @@ def _forest_fit_chunk(
             operand=(jnp.bfloat16 if mesh.devices.flat[0].platform == "tpu"
                      else jnp.float32),
         )
+        if feature_panel(max_features, n_bins) < max_features:
+            # one tree after another: a panelled level counts on a scan
+            # step's one-hot lying in the chip's fast memory, which a vmap
+            # over the chunk's trees multiplies out of it (1.08 s a tree for
+            # 0.67 at five trees a dispatch on a v5e, PERF.md PR 39)
+            return jax.lax.map(grow, keys)
         return jax.vmap(lambda k: grow(k))(keys)
 
     return _local(
-        kernel, mesh, 4, 1, out_specs=(TreeArrays(*([P(DATA_AXIS)] * 6)), P(DATA_AXIS))
-    )(packed, edges, y, valid, jnp.asarray(lo, jnp.int32))
+        kernel, mesh, 5, 1, out_specs=(TreeArrays(*([P(DATA_AXIS)] * 6)), P(DATA_AXIS))
+    )(packed, edges, y, valid, shift, jnp.asarray(lo, jnp.int32))
 
 
 def rows_room(m: int, bootstrap: bool, subsample: float) -> int:
@@ -694,19 +858,27 @@ def tree_bytes(room: int, d: int, max_depth: int, n_bins: int, n_stats: int,
     """Device bytes one tree's build holds at its widest level, from the
     shapes alone: the histogram, its cumulative sums and the gains derived
     from it (three arrays of its size at a time), the selected bin ids, the
-    layout and its sort, a scan step's gathered rows and one-hots, and the
-    node table.  For the benchmark's tree this says 1.3 GB where a v5e's
-    compiler asks 0.75."""
+    layout and its sort, a scan step's gathered rows and the one-hot of ONE
+    panel of features (`feature_panel`), and the node table.  For the
+    classifier's benchmark tree this says 1.3 GB where a v5e's compiler
+    asks 0.75; for the regressor's (1,000 features a node in 16 panels of
+    64) 0.79 GB where it asks 0.51, and where the one-hot taken whole would
+    be 2.1 GB alone."""
     A = min(2 ** (max_depth - 1), max_active)
     T, tiles, step = _level_shape(room, A)
-    hist = 4 * A * n_stats * max_features * n_bins
+    panel = feature_panel(max_features, n_bins)
+    selected = -(-max_features // panel) * panel
+    hist = 4 * A * n_stats * selected * n_bins
     rows = tiles * T
-    scan = step * T * (4 * pack_words(d) * 3 + 2 * max_features * n_bins)
+    scan = step * T * (4 * pack_words(d) * 3 + 2 * panel * n_bins)
     if max_features < d:
-        scan += 2 * step * 4 * pack_words(d) * max_features
+        if selects_by_take(max_features, d):  # the words rows-minor, the taken words
+            scan += 4 * step * T * (pack_words(d) + selected)
+        else:  # the selection's one-hots
+            scan += 2 * step * 4 * pack_words(d) * selected
         hist += 8 * A * d  # the Gumbel draws and their top-k
     table = 4 * table_nodes(max_depth, max_active) * (5 + n_stats)
-    return 3 * hist + rows * (max_features + 4 * 4 * 3 + 6 * n_stats) + scan + table
+    return 3 * hist + rows * (selected + 4 * 4 * 3 + 6 * n_stats) + scan + table
 
 
 def chunk_trees_for(trees: int, per_tree: int, free: int) -> int:
@@ -763,9 +935,11 @@ def forest_fit(
 
     with trace("forest_bin"):
         packed, edges = forest_bins(X, valid, seed, n_bins, mesh)
-        jax.block_until_ready(packed)
+        shift = label_shift(y, valid, criterion, mesh)
+        jax.block_until_ready((packed, shift))
 
     room = rows_room(m_local, bootstrap, subsample)
+    panel = feature_panel(max_features, n_bins)
     per_tree = tree_bytes(room, d, max_depth, n_bins, n_stats, max_features, width)
     if chunk_trees is not None:
         size = max(1, min(chunk_trees, trees_per_worker))
@@ -775,7 +949,7 @@ def forest_fit(
 
     def grow(lo, room):
         return _forest_fit_chunk(
-            packed, edges, y, valid, seed, lo,
+            packed, edges, y, valid, shift, seed, lo,
             count=min(size, trees_per_worker - lo),
             trees_per_worker=trees_per_worker,
             max_depth=max_depth,
@@ -831,6 +1005,9 @@ def forest_fit(
             widest_frontier=int(min(2 ** (max_depth - 1), width)),
             bins=int(n_bins),
             features_per_node=int(max_features),
+            criterion=("gini", "entropy", "variance")[criterion],
+            feature_panel=int(panel),
+            panels_per_level=int(-(-max_features // panel)),
             chunk_trees=int(size),
             tree_bytes=int(per_tree),
             edge_rule=EDGE_RULE,

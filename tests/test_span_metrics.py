@@ -117,20 +117,33 @@ CHILD_READERS = ["stage_put_wait_s", "stage_put_call_s", "stage_put_longest_ms",
 WHOLE_FIT_READERS = ["fit_kernel_self_ms", "fit_longest_x"]
 
 
+# PR 39's, appended after them: the reader of the forest fact's trees to a dispatch
+DISPATCH_READERS = ["forest_trees_per_dispatch"]
+
+
 def test_the_manifest_lists_the_readers_last_and_finds_them():
     # each PR appends: PR 26's eight readers in their order, PR 29's four,
-    # PR 33's six, PR 35's three, PR 37's ten
+    # PR 33's six, PR 35's three, PR 37's ten, PR 39's one
     names = [m["name"] for m in MANIFEST["per_layer"]]
     appended = (READERS + KMEANS_READERS + FOREST_READERS + PCA_READERS
-                + CHILD_READERS + WHOLE_FIT_READERS)
+                + CHILD_READERS + WHOLE_FIT_READERS + DISPATCH_READERS)
     assert names[-len(appended):] == appended
     assert mf.problems(MANIFEST) == []
     for m in MANIFEST["per_layer"][-len(appended):]:
         assert m["moves"] == "fit_s" and m["workloads"]
-        assert m["better"] == ("higher" if m["name"].endswith("_roofline") else "lower")
+        # a share of a roofline and the trees a dispatch holds are the better the higher
+        rises = m["name"].endswith("_roofline") or m["name"] in DISPATCH_READERS
+        assert m["better"] == ("higher" if rises else "lower")
     every_cell = [w["name"] for w in MANIFEST["workloads"]]
-    for m in MANIFEST["per_layer"][-len(WHOLE_FIT_READERS):]:
+    whole_fit = [m for m in MANIFEST["per_layer"] if m["name"] in WHOLE_FIT_READERS]
+    assert [m["name"] for m in whole_fit] == WHOLE_FIT_READERS
+    for m in whole_fit:
         assert m["workloads"] == every_cell and m["source"] == "program_span"
+    forest_cells = ["rfc_fit_cached", "rfr_fit_cached"]
+    for m in MANIFEST["per_layer"]:
+        if m["name"] in FOREST_READERS + DISPATCH_READERS:
+            assert m["workloads"] == forest_cells, m["name"]
+    assert MANIFEST["per_layer"][-1]["source"] == "program_counter"
 
 
 @pytest.mark.parametrize("name, by_hand", [
@@ -303,6 +316,39 @@ def forest_ctx(fits, modules=FOREST_MODULES, facts=(1000, 1200)):
 ])
 def test_forest_readers_by_hand(name, by_hand):
     assert read(name, forest_ctx([FOREST_1, FOREST_2])) == pytest.approx(by_hand, rel=1e-12)
+
+
+def test_forest_trees_per_dispatch_reads_the_fact():
+    ctx = forest_ctx([FOREST_1, FOREST_2])
+    for f, size in zip(ctx["fits"], (5, 3)):
+        f["answer"]["fact"]["chunk_trees"] = size
+    assert read("forest_trees_per_dispatch", ctx) == 4.0
+    # a program whose fact has no such field (the parent's has it; one before
+    # PR 33 has no fact), another family's fits: None, never a raise
+    assert read("forest_trees_per_dispatch", forest_ctx([FOREST_1, FOREST_2])) is None
+    assert read("forest_trees_per_dispatch", forest_ctx([FOREST_1], facts=(None,))) is None
+    assert read("forest_trees_per_dispatch", ctx_of([FIT_1, FIT_2], MODULES)) is None
+
+
+def test_the_regressors_adapter_feeds_the_forest_readers():
+    """The six forest readers take an adapter's `work` and `PROGRAMS` as
+    they are: with rfr's, a level is 500,000 x 1,012 bytes, a fit 15 x 6 of
+    them, and the label shift's program counts with the bins'."""
+    from chipbench import roofline
+
+    rfr = mf.adapter("rfr")
+    modules = dict(FOREST_MODULES, jit__forest_label_shift=(0.2, 2))
+    ctx = ctx_of([FOREST_1, FOREST_2], modules, programs=rfr.PROGRAMS)
+    for f in ctx["fits"]:
+        f["answer"] = {"fact": {"internal_nodes": 945, "chunk_trees": 5}}
+    ctx.update(work=rfr.work(500_000, 3_000, 1, {"numTrees": 15, "maxDepth": 6}),
+               reference={}, traced_fits=2, peaks=roofline.peaks_for("TPU v5 lite"))
+    assert read("forest_level_roofline", ctx) == pytest.approx(
+        100 * (90 * 506e6 / 819e9) / 4.0, rel=1e-12)
+    assert read("forest_bin_roofline", ctx) == pytest.approx(
+        100 * (7.5e9 / 819e9) / 0.5, rel=1e-12)  # (0.1 + 0.5 + 0.2 + 0.2) / 2 device seconds
+    assert read("forest_nodes_per_fit", ctx) == 945.0
+    assert read("forest_trees_per_dispatch", ctx) == 5.0
 
 
 @pytest.mark.parametrize("name", FOREST_READERS)

@@ -25,7 +25,7 @@ from spark_rapids_ml_tpu.classification import LogisticRegression, RandomForestC
 from spark_rapids_ml_tpu.clustering import KMeans
 from spark_rapids_ml_tpu.config import reset_config, set_config
 from spark_rapids_ml_tpu.feature import PCA
-from spark_rapids_ml_tpu.regression import LinearRegression
+from spark_rapids_ml_tpu.regression import LinearRegression, RandomForestRegressor
 from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 from spark_rapids_ml_tpu.telemetry.report import span_tree
 
@@ -98,6 +98,13 @@ ROUTES = {
         lambda: RandomForestClassifier(numTrees=4, maxDepth=8, seed=1, num_workers=1),
         (32_768, 16), "fit_kernel",
         {"label_range", "forest_bin", "forest_grow", "forest_fetch", "forest_assemble"},
+    ),
+    # ... and a regression forest's: no label maximum to read, the label shift
+    # rides `forest_bin`
+    "forest_regressor": (
+        lambda: RandomForestRegressor(numTrees=4, maxDepth=5, seed=1, num_workers=1),
+        (32_768, 16), "fit_kernel",
+        {"forest_bin", "forest_grow", "forest_fetch", "forest_assemble"},
     ),
     # 256 MB of rows, far over `_PIPELINED_MIN_BYTES`: the staging engine
     "pipelined_stage": (
@@ -310,6 +317,34 @@ def test_route_records_its_spans(route, monkeypatch):
     else:
         assert not any(n["name"] == "lbfgs_eval"
                        for r in reports for n, _ in _walk(r["spans"]))
+
+
+def test_a_regression_forest_records_one_grow_span_a_chunk_and_its_fact(monkeypatch):
+    """`forest_bin` (the label shift inside it), one `forest_grow` a
+    dispatched chunk of trees, `forest_fetch`; `fact[forest]` says what the
+    shapes decided: the criterion, the panel of features and how many a
+    level, the trees to a dispatch."""
+    from spark_rapids_ml_tpu.ops import forest as forest_ops
+
+    real = forest_ops.forest_fit
+    monkeypatch.setattr(forest_ops, "forest_fit",
+                        lambda *a, **kw: real(*a, **dict(kw, chunk_trees=2)))
+    # 32 bins: 256 of a panel's 8,192 one-hot columns hold 12 features
+    monkeypatch.setattr(forest_ops, "_PANEL_COLUMNS", 256)
+    X, y = _rows(8_192, 24)
+    y = (X[:, :3].sum(axis=1) + 0.1 * y).astype(np.float32)
+    model = RandomForestRegressor(numTrees=6, maxDepth=4, maxBins=32, seed=2,
+                                  featureSubsetStrategy="all", num_workers=1).fit((X, y))
+    report = model.fit_report()
+    names = [n["name"] for n, _ in _walk(_find(report, "fit_kernel").get("children", []))
+             if not n["name"].startswith("compile[")]
+    assert names == ["forest_bin", "forest_grow", "forest_grow", "forest_grow",
+                     "forest_fetch", "forest_assemble", "fact[forest]"], names
+    fact = report["forest"]
+    assert fact["criterion"] == "variance" and fact["chunk_trees"] == 2 and fact["trees"] == 6
+    assert (fact["features_per_node"], fact["feature_panel"], fact["panels_per_level"]) == (24, 8, 3)
+    room = forest_ops.rows_room(8_192, True, 1.0)
+    assert fact["tree_bytes"] == forest_ops.tree_bytes(room, 24, 4, 32, 3, 24, 8_192)
 
 
 def test_span_tree_keeps_concurrent_threads_apart():
