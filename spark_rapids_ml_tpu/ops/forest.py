@@ -22,7 +22,7 @@
 #     one-hot (features x K) matmul, bin ids being exact in bfloat16; from
 #     a quarter of a packed row's words on: the words laid rows-minor and
 #     each feature's word taken as a row, `selects_by_take`), the (node, K,
-#     bin, stat) histogram accumulated by a one-hot (rows x K*bins) matmul
+#     bin, stat) histogram accumulated by a one-hot (rows x K x bins) matmul
 #     against the rows' statistics split into three exact bfloat16 parts
 #     (integer counts stay exact, real sums f32), cumulative sums over bins
 #     give every candidate split's left/right statistics, an argmax picks
@@ -36,8 +36,11 @@
 #     features at a time inside each scan step, the ids padded to whole
 #     panels with the node's last feature (its columns dropped): no array
 #     of rows x K x bins is alive at once, and a column's sum is the same
-#     dot over a tile's rows whatever the panel's width.  The classifier's
-#     floor(sqrt(d)) features are one panel, the product taken whole.
+#     dot over a tile's rows whatever the panel's width.  A panel's product
+#     keeps features and bins apart (`_level_histogram`), the classifier's
+#     floor(sqrt(d)) features as one panel and the regressor's thousand as
+#     sixteen, so the one-hot is made inside the dot; under a lane's 128
+#     rows a tile (the deepest levels) the two are one axis (`split_hist`).
 #   - REAL-VALUED STATISTICS (variance).  A regression tree's channels are
 #     (w, w y', w y'^2) with y' = y - c, c ONE constant per worker (THE
 #     DRAWS, below): every f32 sum of the build is of labels less the
@@ -64,10 +67,11 @@
 #     standard large-n approximation of multinomial bootstrap, also used
 #     by cuML's GPU forest).
 #   - Trees are dispatched in equal chunks sized from the shapes and the
-#     memory the device has left (`chunk_trees_for`), a chunk's trees under
-#     one vmap (one after another where a level is panelled: the panels
-#     count on the chip's fast memory, which a vmap over trees multiplies
-#     out of it); across the mesh, trees are embarrassingly parallel
+#     memory the device has left (`chunk_trees_for`), a chunk's trees one
+#     after another (`lax.map`: one tree's program whatever the chunk, its
+#     one-hot in the chip's fast memory, where a vmap over trees moved
+#     real-valued sums with the chunk and the one-hot out of that memory);
+#     across the mesh, trees are embarrassingly parallel
 #     (shard_map with no collectives — the analog of reference tree.py's
 #     barrier-allGather-only pattern).
 #
@@ -271,8 +275,23 @@ def _level_shape(m: int, A: int):
 def feature_panel(max_features: int, n_bins: int) -> int:
     """Features to one panel of a level's histogram: as many as keep a
     panel's one-hot within `_PANEL_COLUMNS` columns (64 at 128 bins).  A
-    selection no wider than that is one panel, the product taken whole."""
+    selection no wider than that is one panel, taken by the same product
+    as any other (`_level_histogram`) without the loop over panels."""
     return max(1, min(max_features, _PANEL_COLUMNS // n_bins))
+
+
+def split_hist(T: int) -> bool:
+    """Whether a level whose tiles hold T rows takes its histogram's product
+    with features and bins apart (`cst,ckbt->cskb`), or with the two merged
+    into one one-hot axis (`cst,cqt->csq`).  Apart, XLA builds the ids'
+    broadcast along the bins and the compare inside the product's fusion;
+    merged, that broadcast is an array of its own a scan step.  Apart wins
+    from a lane's 128 rows a tile on and loses under it, where the product's
+    contraction is the tile's rows: on a v5e at the classifier's 54 features
+    and 128 bins a level's histogram took 2.8 / 3.7 / 46.5 / 50.2 ms apart
+    and 6.9 / 8.6 / 26.4 / 33.0 merged at T = 512 / 128 / 64 / 32 (PERF.md,
+    PR 40; the regressor's 100 -> 41 ms at T = 512, PR 39)."""
+    return T >= 128
 
 
 def selects_by_take(max_features: int, d: int) -> bool:
@@ -350,6 +369,50 @@ def _three_parts(x, dtype):
         parts.append(part.astype(dtype))
         rest = rest - part
     return jnp.concatenate(parts, axis=-2)
+
+
+def _level_histogram(sel, stats3, node, A: int, panel: int, n_bins: int, step: int, dtype):
+    """The (A, K_sel, B, S) histogram of a level: tile i's rows, bin ids
+    `sel[i]` (K_sel, T) and statistics `stats3[i]` (3S, T) in three parts
+    (`_three_parts`), added to node `node[i]` (A: to none), `step` tiles a
+    scan step, a panel of `panel` features at a time.  A tile's rows go
+    into one dot and tiles are added in scan order, so the sums are the
+    same whatever the panel and whatever the product's form
+    (`split_hist`)."""
+    tiles, K_sel, T = sel.shape
+    S = stats3.shape[1] // 3
+    panels = K_sel // panel
+    split = split_hist(T)
+
+    def chunks(a):
+        return a.reshape((tiles // step, step) + a.shape[1:])
+
+    def accumulate(hist, args):  # hist (panels, A + 1, S, panel, B)
+        s, st, at = args  # (step, K_sel, T), (step, 3S, T), (step,)
+        bins = jnp.arange(n_bins, dtype=jnp.uint8)[:, None]
+
+        def one_panel(p, hist):
+            sp = jax.lax.dynamic_slice_in_dim(s, p * panel, panel, axis=1)
+            onehot = (sp[:, :, None, :] == bins).astype(stats3.dtype)
+            if split:
+                part = jnp.einsum("cst,ckbt->cskb", st, onehot,
+                                  preferred_element_type=jnp.float32)
+            else:
+                part = jnp.einsum("cst,cqt->csq", st, onehot.reshape(step, panel * n_bins, T),
+                                  preferred_element_type=jnp.float32)
+            # the three parts added back: (step, 3S, ...) -> (step, S, ...); a
+            # merged axis is split after it, where a third of the rows relay
+            part = (part[:, :S] + part[:, S:2 * S] + part[:, 2 * S:]).astype(dtype)
+            return hist.at[p, at].add(part.reshape(step, S, panel, n_bins))
+
+        if panels == 1:
+            return one_panel(0, hist), None
+        return jax.lax.fori_loop(0, panels, one_panel, hist), None
+
+    hist, _ = jax.lax.scan(
+        accumulate, jnp.zeros((panels, A + 1, S, panel, n_bins), dtype),
+        (chunks(sel), chunks(stats3), chunks(node)))
+    return hist[:, :A].transpose(1, 0, 3, 4, 2).reshape(A, K_sel, n_bins, S)
 
 
 def _grow_one_tree(
@@ -454,42 +517,9 @@ def _grow_one_tree(
             sel = jax.lax.map(select, (chunks(rowid), chunks(feats_sel[slot_c])))
             sel = sel.reshape(tiles, K_sel, T)
             stats3 = _three_parts(_row_stats(wt, yt, criterion, S), operand)  # (tiles, 3S, T)
-
-            def three_in_one(part):  # (step, 3S, ...) -> (step, S, ...)
-                return (part[:, :S] + part[:, S:2 * S] + part[:, 2 * S:]).astype(dtype)
-
-            # (A_l + 1, S, K*B): a tile's one-hot product, added to its node
-            def accumulate(hist, args):
-                s, st, node = args  # (step, K, T), (step, 3S, T), (step,)
-                bins = jnp.arange(B, dtype=jnp.uint8)[:, None]
-                if panels == 1:
-                    onehot = (s[:, :, None, :] == bins).astype(operand).reshape(step, K * B, T)
-                    part = jnp.einsum("cst,cqt->csq", st, onehot,
-                                      preferred_element_type=jnp.float32)
-                    return hist.at[node].add(three_in_one(part)), None
-
-                # a panel's product keeps features and bins apart: merged into
-                # one axis, the ids' broadcast along the bins is an array of
-                # its own (a third of a level on a v5e); apart, XLA makes it
-                # inside the product (41 ms a level for 100, PERF.md PR 39)
-                def one_panel(p, hist):  # (panels, A_l + 1, S, panel, B)
-                    sp = jax.lax.dynamic_slice_in_dim(s, p * panel, panel, axis=1)
-                    onehot = (sp[:, :, None, :] == bins).astype(operand)
-                    part = jnp.einsum("cst,ckbt->cskb", st, onehot,
-                                      preferred_element_type=jnp.float32)
-                    return hist.at[p, node].add(three_in_one(part))
-
-                return jax.lax.fori_loop(0, panels, one_panel, hist), None
-
-            hist, _ = jax.lax.scan(
-                accumulate,
-                jnp.zeros((A_l + 1, S, K * B) if panels == 1
-                          else (panels, A_l + 1, S, panel, B), dtype),
-                (chunks(sel), chunks(stats3), chunks(jnp.where(live, tile_slot, A_l))))
-            if panels == 1:
-                hist = hist[:A_l].reshape(A_l, S, K, B).transpose(0, 2, 3, 1)  # (A,K,B,S)
-            else:
-                hist = hist[:, :A_l].transpose(1, 0, 3, 4, 2).reshape(A_l, K_sel, B, S)[:, :K]
+            hist = _level_histogram(
+                sel, stats3, jnp.where(live, tile_slot, A_l), A_l, panel, B, step,
+                dtype)[:, :K]  # (A_l, K, B, S)
 
         with jax.named_scope("forest_split"):
             cum = jnp.cumsum(hist, axis=2)
@@ -822,13 +852,13 @@ def _forest_fit_chunk(
             operand=(jnp.bfloat16 if mesh.devices.flat[0].platform == "tpu"
                      else jnp.float32),
         )
-        if feature_panel(max_features, n_bins) < max_features:
-            # one tree after another: a panelled level counts on a scan
-            # step's one-hot lying in the chip's fast memory, which a vmap
-            # over the chunk's trees multiplies out of it (1.08 s a tree for
-            # 0.67 at five trees a dispatch on a v5e, PERF.md PR 39)
-            return jax.lax.map(grow, keys)
-        return jax.vmap(lambda k: grow(k))(keys)
+        # one tree after another: a tree's program is then the same whatever
+        # the chunk (under a vmap its dots' real-valued sums moved with the
+        # chunk's width, PR 40), and a scan step's one-hot stays in the
+        # chip's fast memory, which a vmap over the chunk's trees multiplied
+        # out of it (1.08 s a tree for 0.67 at five trees a dispatch on a
+        # v5e, PERF.md PR 39)
+        return jax.lax.map(grow, keys)
 
     return _local(
         kernel, mesh, 5, 1, out_specs=(TreeArrays(*([P(DATA_AXIS)] * 6)), P(DATA_AXIS))
@@ -1008,6 +1038,9 @@ def forest_fit(
             criterion=("gini", "entropy", "variance")[criterion],
             feature_panel=int(panel),
             panels_per_level=int(-(-max_features // panel)),
+            split_hist_levels=sum(
+                split_hist(_level_shape(room, min(2**lv, width))[0])
+                for lv in range(max_depth)),
             chunk_trees=int(size),
             tree_bytes=int(per_tree),
             edge_rule=EDGE_RULE,

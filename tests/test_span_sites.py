@@ -345,6 +345,24 @@ def test_a_regression_forest_records_one_grow_span_a_chunk_and_its_fact(monkeypa
     assert (fact["features_per_node"], fact["feature_panel"], fact["panels_per_level"]) == (24, 8, 3)
     room = forest_ops.rows_room(8_192, True, 1.0)
     assert fact["tree_bytes"] == forest_ops.tree_bytes(room, 24, 4, 32, 3, 24, 8_192)
+    # tiles of 256 rows or more at every level: each product keeps features
+    # and bins apart, in every panel
+    assert fact["split_hist_levels"] == 4
+
+
+@pytest.mark.parametrize("depth, split", [(4, 4), (8, 5)])
+def test_a_one_panel_classifier_counts_the_levels_whose_product_keeps_features_and_bins_apart(
+        depth, split):
+    """`fact[forest]`'s `split_hist_levels`: the levels of a tree whose
+    tiles hold 128 rows or more (`forest.split_hist`).  At 8,192 rows a
+    frontier of 8 nodes has tiles of 256; one of 128 nodes tiles of 16,
+    and the levels from 32 nodes on merge features and bins."""
+    X, y = _rows(8_192, 24)
+    model = RandomForestClassifier(numTrees=2, maxDepth=depth, seed=2,
+                                   num_workers=1).fit((X, y))
+    fact = model.fit_report()["forest"]
+    assert (fact["criterion"], fact["panels_per_level"]) == ("gini", 1)
+    assert fact["split_hist_levels"] == split
 
 
 def test_span_tree_keeps_concurrent_threads_apart():
