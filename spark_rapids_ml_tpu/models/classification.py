@@ -398,7 +398,7 @@ class LogisticRegression(
 
         from ..ops.logistic import logreg_fit, logreg_fit_binary
         from ..ops.stats import standardize, weighted_moments
-        from ..tracing import event, trace
+        from ..tracing import event, fact, trace
 
         p = fit_input.params
         dtype = np.dtype(fit_input.dtype)
@@ -490,6 +490,7 @@ class LogisticRegression(
             ls_max=int(p.get("linesearch_max_iter", 20)),
         )
         mean = std = None
+        n_evals = None  # evaluations the solver ran, where it counts them
         if sparse:
             # ELL sparse path (the analog of the reference's CSR
             # LogisticRegressionMG, classification.py:1054-1055).
@@ -500,8 +501,9 @@ class LogisticRegression(
 
             vals, cols = fit_input.X, fit_input.extra["ell_cols"]
             d = fit_input.pdesc.n
+            evaluation = "autodiff"
             event(
-                "lbfgs_eval_kernel[autodiff]",
+                f"lbfgs_eval_kernel[{evaluation}]",
                 detail="ELL sparse rows: the margin is a gather-contract",
             )
             if standardization:
@@ -525,7 +527,7 @@ class LogisticRegression(
                     f"budget {budget:.0e}, checkpointing "
                     f"{'on' if ckpt_path else 'off'})"
                 )
-                coef, b, loss, n_iter, hist = logreg_fit_host_dispatch(
+                coef, b, loss, n_iter, hist, n_evals = logreg_fit_host_dispatch(
                     vals, w, fit_input.y, n_classes=n_classes,
                     binomial=binomial, d=d,
                     data=(vals, cols),
@@ -540,7 +542,7 @@ class LogisticRegression(
                     vals, cols, w, fit_input.y, d=d, **kwargs
                 )
             else:
-                coef, b, loss, n_iter, hist = logreg_fit_ell(
+                coef, b, loss, n_iter, hist, n_evals = logreg_fit_ell(
                     vals, cols, w, fit_input.y, n_classes=n_classes, d=d,
                     **kwargs
                 )
@@ -569,12 +571,23 @@ class LogisticRegression(
                 X = X.astype(jnp.bfloat16)
             # which evaluation either route runs is read from the rows
             # alone: one read of them an evaluation (a Pallas kernel) for
-            # dense float32 binomial rows on TPUs, autodiff's two otherwise
-            from ..ops.logistic import one_pass_program_bytes
+            # dense float32 binomial rows on TPUs, the rows in blocks for
+            # dense float32 multinomial rows on TPUs, autodiff otherwise
+            from ..ops.logistic import (
+                one_pass_program_bytes,
+                softmax_plan,
+                softmax_program_bytes,
+            )
             from ..ops.pallas_logistic import one_pass_plan
             from ..parallel.device_cache import bytes_beside, fused_program_fits
 
             one_pass, why_kernel = one_pass_plan(X, binomial)
+            softmax = None
+            if not binomial:
+                softmax, why_kernel = softmax_plan(X, n_classes)
+            evaluation = (
+                "one_pass" if one_pass else "softmax_blocks" if softmax else "autodiff"
+            )
             # fused single-program L-BFGS while the device holds what that
             # program holds beside the resident rows
             # (`device_cache.fused_program_fits`, the memory test KMeans'
@@ -585,18 +598,24 @@ class LogisticRegression(
             # state persists per iteration), host-driven L-BFGS, one
             # evaluation per program.
             checkpointing = f"checkpointing {'on' if ckpt_path else 'off'}"
-            if one_pass is not None:
+            if one_pass is not None or softmax is not None:
                 # no FLOP budget here: the program's length is no memory
                 # question, and the budget's reason was the dispatch
                 # timeout of a development link that is gone (the
                 # published depth on the chip: PERF.md §6, PR 38)
-                held = one_pass_program_bytes(
-                    *X.addressable_shards[0].data.shape, kwargs["history"]
-                )
+                shard_rows, d = X.addressable_shards[0].data.shape
+                if one_pass is not None:
+                    held = one_pass_program_bytes(shard_rows, d, kwargs["history"])
+                else:
+                    held = softmax_program_bytes(
+                        shard_rows, d, n_classes, kwargs["history"],
+                        softmax.block_rows,
+                    )
                 fits = fused_program_fits(X, held, rows_in_place=True)
                 host_dispatch = bool(ckpt_path) or not fits
                 why = (
-                    f"the kernel reads the rows in place: the fused program "
+                    f"the {evaluation} evaluation reads the rows in place: "
+                    f"the fused program "
                     f"holds at most {held:.3g} B beside them, "
                     f"{bytes_beside(X):.3g} B are free, so it "
                     f"{'fits' if fits else 'does NOT fit'} the device; "
@@ -625,20 +644,18 @@ class LogisticRegression(
                 f"lbfgs_route[{'host_dispatch' if host_dispatch else 'fused'}]",
                 detail=why,
             )
-            event(
-                f"lbfgs_eval_kernel[{'one_pass' if one_pass else 'autodiff'}]",
-                detail=why_kernel,
-            )
+            event(f"lbfgs_eval_kernel[{evaluation}]", detail=why_kernel)
             if host_dispatch:
                 from ..ops.logistic import logreg_fit_host_dispatch
 
                 self.logger.info(
                     f"LogisticRegression: host-dispatched L-BFGS ({why})"
                 )
-                coef, b, loss, n_iter, hist = logreg_fit_host_dispatch(
+                coef, b, loss, n_iter, hist, n_evals = logreg_fit_host_dispatch(
                     X, w, fit_input.y, n_classes=n_classes,
                     binomial=binomial, checkpoint_path=ckpt_path,
-                    checkpoint_tag=ckpt_tag, one_pass=one_pass, **kwargs
+                    checkpoint_tag=ckpt_tag, one_pass=one_pass,
+                    softmax=softmax, **kwargs
                 )
             else:
                 # asynchronous: the call returns once the one program is
@@ -650,13 +667,14 @@ class LogisticRegression(
                             X, w, fit_input.y, one_pass=one_pass, **kwargs
                         )
                     else:
-                        coef, b, loss, n_iter, hist = logreg_fit(
-                            X, w, fit_input.y, n_classes=n_classes, **kwargs
+                        coef, b, loss, n_iter, hist, n_evals = logreg_fit(
+                            X, w, fit_input.y, n_classes=n_classes,
+                            softmax=softmax, **kwargs
                         )
         # ONE batched device->host fetch for every output (each separate
         # np.asarray/float() would pay a full host sync)
         fetch = {"coef": coef, "b": b, "loss": loss, "n_iter": n_iter,
-                 "hist": hist}
+                 "hist": hist, "n_evals": n_evals}
         if standardization:
             fetch["std"] = std
             if mean is not None:
@@ -664,6 +682,13 @@ class LogisticRegression(
         with trace("solve_fetch"):
             host = jax.device_get(fetch)
         loss, n_iter = host["loss"], host["n_iter"]
+        # what the solver did, once a fit (`fit_report()["lbfgs"]`); the
+        # fused binomial program does not count its evaluations (None)
+        fact(
+            "lbfgs",
+            evaluations=None if host["n_evals"] is None else int(host["n_evals"]),
+            iterations=int(n_iter), classes=n_classes, evaluation=evaluation,
+        )
         if binomial:
             coef = np.asarray(host["coef"], np.float64).reshape(1, -1)
             intercept = np.array([float(host["b"])])

@@ -29,6 +29,8 @@ class LbfgsResult(NamedTuple):
     history_f: jax.Array  # (max_iter+1,) full objective per iteration
     # (entry 0 = initial objective; entries past n_iter stay NaN) — the
     # source of Spark's LogisticRegressionTrainingSummary.objectiveHistory
+    n_evals: jax.Array = None  # value+gradient evaluations run, with
+    # `count_evals` (the line search's retries included)
 
 
 def _pseudo_gradient(w: jax.Array, g: jax.Array, l1: jax.Array, l1_mask: jax.Array):
@@ -58,12 +60,14 @@ def lbfgs_minimize(
     l1: float = 0.0,
     l1_mask: jax.Array = None,
     ls_max: int = 20,
+    count_evals: bool = False,
 ) -> LbfgsResult:
     """Minimize loss_fn(w) + l1·‖w∘l1_mask‖₁ with L-BFGS (OWL-QN when l1>0).
 
     loss_fn must be smooth and differentiable (the L2 term belongs inside
     it); w0 is the flattened replicated parameter vector.  Runs as a single
-    jitted while_loop.
+    jitted while_loop.  `count_evals`: the loop also carries the number of
+    evaluations it ran (`n_evals`); without it the program is as before.
     """
     n = w0.shape[0]
     m = history
@@ -105,7 +109,7 @@ def lbfgs_minimize(
         return (l1 * l1_mask * jnp.abs(w)).sum()
 
     def body(state):
-        w, f, g, S, Y, rho, k, it, _, hist = state
+        w, f, g, S, Y, rho, k, it, _, hist, *evals = state
         pg = _pseudo_gradient(w, g, l1, l1_mask)
         with jax.named_scope("lbfgs_two_loop"):
             p = direction(pg, S, Y, rho, k)
@@ -148,7 +152,7 @@ def lbfgs_minimize(
 
         w_1 = project(w + t0 * p)
         f_1, g_1 = value_and_grad(w_1)
-        t, w_new, f_new, g_new, _ = jax.lax.while_loop(
+        t, w_new, f_new, g_new, retries = jax.lax.while_loop(
             ls_cond, ls_body, (t0, w_1, f_1, g_1, jnp.array(0, jnp.int32))
         )
         s = w_new - w
@@ -170,7 +174,8 @@ def lbfgs_minimize(
             jnp.abs(rel_impr) <= tol
         )
         hist = hist.at[it + 1].set(new_full)
-        return w_new, f_new, g_new, S, Y, rho, k, it + 1, converged, hist
+        evals = tuple(e + 1 + retries for e in evals)
+        return (w_new, f_new, g_new, S, Y, rho, k, it + 1, converged, hist) + evals
 
     def cond(state):
         it, converged = state[7], state[8]
@@ -191,11 +196,12 @@ def lbfgs_minimize(
         jnp.array(0, jnp.int32),
         jnp.array(False),
         hist0,
-    )
-    w, f, g, S, Y, rho, k, it, converged, hist = jax.lax.while_loop(
+    ) + ((jnp.array(1, jnp.int32),) if count_evals else ())
+    w, f, g, S, Y, rho, k, it, converged, hist, *evals = jax.lax.while_loop(
         cond, body, state0
     )
-    return LbfgsResult(w=w, f=f, n_iter=it, converged=converged, history_f=hist)
+    return LbfgsResult(w=w, f=f, n_iter=it, converged=converged, history_f=hist,
+                       n_evals=evals[0] if evals else None)
 
 
 def lbfgs_minimize_host(

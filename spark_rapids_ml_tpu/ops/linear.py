@@ -85,11 +85,11 @@ def _linreg_sufficient_stats_xla(X: jax.Array, w: jax.Array, y, shift=None):
         return (gram, *_moments(X, w, y, hi))
 
 
-def _bf16_parts(Z: jax.Array):
-    """Three bfloat16 arrays that add up to the float32 `Z` (to its last
-    bit but one or two: 24 significand bits in three of 8)."""
+def _bf16_parts(Z: jax.Array, n: int = 3):
+    """`n` bfloat16 arrays that add up to the float32 `Z` (at n = 3 to its
+    last bit but one or two: 24 significand bits in three of 8)."""
     parts, rest = [], Z
-    for _ in range(3):
+    for _ in range(n):
         # reduce_precision, not a cast there and back: XLA may elide that
         # pair (xla_allow_excess_precision) and leave no remainder
         part = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)

@@ -51,9 +51,12 @@ def ell_matvec(vals: jax.Array, cols: jax.Array, beta: jax.Array) -> jax.Array:
 
 
 def ell_matmat(vals: jax.Array, cols: jax.Array, W: jax.Array) -> jax.Array:
-    """(N, C) margins for multinomial W (C, d): gather W.T rows."""
+    """(N, C) margins for multinomial W (C, d): gather W.T rows.  At
+    `highest`: at default precision a float32 contraction is ONE bfloat16
+    pass on a TPU."""
     # W.T: (d, C); gathered (N, K, C)
-    return jnp.einsum("nk,nkc->nc", vals, jnp.take(W.T, cols, axis=0))
+    return jnp.einsum("nk,nkc->nc", vals, jnp.take(W.T, cols, axis=0),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 @partial(jax.jit, static_argnames=("d",))

@@ -396,7 +396,7 @@ class FitTelemetry:
         chunk_cache = _view_delta(deltas, "chunk_cache")
         if any(chunk_cache.values()):
             report["chunk_cache"] = chunk_cache
-        for section in ("fused", "stats", "pass_report", "forest"):
+        for section in ("fused", "stats", "pass_report", "forest", "lbfgs"):
             if facts.get(section):
                 report[section] = dict(facts[section])
         if solver_decision:

@@ -153,8 +153,8 @@ def test_fit_with_kernel_matches_autodiff_fit(tile128, route, n_dev):
             Xs, ws, ys, n_classes=2, binomial=True, one_pass=one_pass, **kw
         )
 
-    coef, b, loss, n_iter, _ = fit(plan)
-    coef0, b0, loss0, n_iter0, _ = fit(None)
+    coef, b, loss, n_iter = fit(plan)[:4]
+    coef0, b0, loss0, n_iter0 = fit(None)[:4]
     assert int(n_iter) == int(n_iter0) and 3 < int(n_iter) < 60
     np.testing.assert_allclose(np.asarray(coef), np.asarray(coef0), atol=1e-5)
     np.testing.assert_allclose(float(b), float(b0), atol=1e-5)
@@ -536,6 +536,43 @@ def test_the_memory_test_counts_what_the_program_holds(conf, hbm_bytes, in_place
     held = one_pass_program_bytes(rows, d, 10)
     assert 30e6 < held < 40e6
     assert fused_program_fits(X, held if in_place else 0, rows_in_place=in_place) is fits
+
+
+# -- the multinomial fit's blocked evaluation (ops/logistic.py `softmax_data_term`),
+# compiled for the same chip at the ImageNet linear probe's shape: its fused
+# program reads the rows in place, in row blocks, within the bound its router
+# counts (a (rows, 1,000) logits array alone would be 5.1 GB)
+
+def test_one_chip_multinomial_fit_reads_its_rows_in_place(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops.logistic import (
+        SoftmaxBlocks,
+        logreg_fit,
+        softmax_program_bytes,
+    )
+
+    one = SingleDeviceSharding(topo.devices[0])
+    rows, d, C, block = 1_281_167, 2048, 1000, 16_224  # `softmax_block_rows` on a v5e
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = logreg_fit.lower(
+        spec((rows, d)), spec((rows,)), spec((rows,), jnp.int32), n_classes=C,
+        l2=1e-5, l1=0.0, fit_intercept=True, tol=1e-30, max_iter=20,
+        softmax=SoftmaxBlocks(block),
+    ).compile()
+    text = compiled.as_text()
+    # no operation writes an array of the rows' size: they are read in place
+    written = [line for line in text.splitlines() if f"= f32[{rows},{d}]" in line]
+    assert all(
+        any(op in line for op in (" parameter(", " bitcast(", " get-tuple-element("))
+        for line in written
+    ), written
+    memory = compiled.memory_analysis()
+    beside = memory.temp_size_in_bytes + memory.argument_size_in_bytes - rows * d * 4
+    assert beside <= softmax_program_bytes(rows, d, C, 10, block) < 1.5e9
 
 
 # -- PCA's covariance (ops/pca.py `pca_scatter`), compiled for the same chip: the

@@ -119,14 +119,17 @@ WHOLE_FIT_READERS = ["fit_kernel_self_ms", "fit_longest_x"]
 
 # PR 39's, appended after them: the reader of the forest fact's trees to a dispatch
 DISPATCH_READERS = ["forest_trees_per_dispatch"]
+# appended after them: the multinomial evaluation's device time and the
+# `fact[lbfgs]` count it is divided by
+MLOGREG_READERS = ["mlogreg_eval_ms", "mlogreg_evals_per_fit"]
 
 
 def test_the_manifest_lists_the_readers_last_and_finds_them():
-    # each PR appends: PR 26's eight readers in their order, PR 29's four,
-    # PR 33's six, PR 35's three, PR 37's ten, PR 39's one
+    # each family of readers is appended after the ones before it, in the
+    # order of the lists above
     names = [m["name"] for m in MANIFEST["per_layer"]]
     appended = (READERS + KMEANS_READERS + FOREST_READERS + PCA_READERS
-                + CHILD_READERS + WHOLE_FIT_READERS + DISPATCH_READERS)
+                + CHILD_READERS + WHOLE_FIT_READERS + DISPATCH_READERS + MLOGREG_READERS)
     assert names[-len(appended):] == appended
     assert mf.problems(MANIFEST) == []
     for m in MANIFEST["per_layer"][-len(appended):]:
@@ -143,6 +146,8 @@ def test_the_manifest_lists_the_readers_last_and_finds_them():
     for m in MANIFEST["per_layer"]:
         if m["name"] in FOREST_READERS + DISPATCH_READERS:
             assert m["workloads"] == forest_cells, m["name"]
+        if m["name"] in MLOGREG_READERS:
+            assert m["workloads"] == ["mlogreg_fit_cached"], m["name"]
     assert MANIFEST["per_layer"][-1]["source"] == "program_counter"
 
 
