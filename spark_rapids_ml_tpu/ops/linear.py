@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.locks import named_lock
+
 # Sample-weight/fold-mask contract (parallel/device_cache.py): the
 # sufficient statistics weight every term by `w` and the host solver
 # consumes only those weighted sums (n enters as sw = w.sum()), so a w=0
@@ -318,23 +320,95 @@ def _fortran_view(A: np.ndarray) -> np.ndarray:
     return A if A.flags.f_contiguous else A.T
 
 
-def _normal_system(gram, sw: float, mean, scale, ridge: float) -> np.ndarray:
+def _normal_system(gram, sw: float, mean, scale, ridge: float,
+                   out: np.ndarray = None) -> np.ndarray:
     """The Gram centred by `mean` and divided by `scale` x `scale` (each
-    skipped where None) plus `ridge` on the diagonal, in float64, built in
-    ONE new buffer: the caller's array is never written, and no other
-    (d,d) array is made."""
+    skipped where None) plus `ridge` on the diagonal, in float64, written
+    into `out` (a C-ordered float64 (d,d) buffer; a new one where None) a
+    block of rows (~2 MB, which stays in cache) at a time: the Gram widened
+    into the block, then centred by a rank-1 update, scaled, the ridge
+    added.  Every element is written, so whatever the buffer held before (a
+    factor, another system) cannot reach this one; the caller's array is
+    never written, and no other (d,d) array is made."""
     from scipy.linalg.blas import dger
 
-    A = np.array(gram, dtype=np.float64)
-    if mean is not None:
-        # A -= sw·mean·meanᵀ, a rank-1 update in place
-        dger(-sw, mean, mean, a=_fortran_view(A), overwrite_a=1)
-    if scale is not None:
-        A /= scale[:, None]
-        A /= scale
-    if ridge:
-        A.flat[:: A.shape[0] + 1] += ridge
-    return A
+    d = gram.shape[0]
+    if out is None:
+        out = np.empty((d, d))
+    rows = max(1, (1 << 18) // d)
+    for r0 in range(0, d, rows):
+        r1 = min(r0 + rows, d)
+        block = out[r0:r1]
+        np.copyto(block, gram[r0:r1])
+        if mean is not None:
+            dger(-sw, mean, mean[r0:r1], a=block.T, overwrite_a=1)
+        if scale is not None:
+            block /= scale[r0:r1, None]
+            block /= scale
+        if ridge:
+            k = np.arange(r1 - r0)
+            block[k, r0 + k] += ridge
+    return out
+
+
+class _SystemWorkspace:
+    """The buffer of one `solve_linear_host`'s float64 (d,d) system, kept
+    in ONE slot across solves.  A new one is 72 MB at 3,000 columns:
+    mapped fresh, first-touched page by page as it is written (~70 ms a
+    fit on a v5e's host) and unmapped after (~8 ms).  A solve of the slot's
+    d writes its system into the slot's buffer; one of another d replaces
+    it.  The slot is taken without waiting: a solve that finds it held (a
+    second thread's) makes a buffer of its own, as every solve did before.
+    The slot keeps what the last solve held at its peak, so a run of solves
+    peaks no higher than one did; but nothing releases it, so d^2 x 8 bytes
+    stay resident for the life of the process (72 MB at 3,000 columns,
+    3.2 GB at 20,000), beneath whatever runs next: another estimator's fit,
+    or PCA's eigensolve with a (d,d) system of its own.
+
+    A context: the slot is held from the first `system` to `close`, which
+    the exit calls too."""
+
+    _lock = named_lock("linreg_system_workspace")
+    _kept = None  # the slot: a C-ordered float64 (d,d) array, or None
+
+    def __init__(self):
+        self._buf = None  # this solve's buffer, once it is assembled into
+        self._holds = False
+
+    def system(self, gram, sw: float, mean, scale, ridge: float) -> np.ndarray:
+        """The system in this solve's buffer; the instant
+        `linreg_solve_workspace[reused|new]` says how the buffer was had."""
+        from ..tracing import event
+
+        d = gram.shape[0]
+        cls = _SystemWorkspace
+        why = ""
+        if self._buf is None:
+            self._holds = cls._lock.acquire(blocking=False)
+            if not self._holds:
+                why, self._buf = "busy", np.empty((d, d))
+            else:
+                if cls._kept is None or cls._kept.shape != (d, d):
+                    why = "first" if cls._kept is None else "shape"
+                    cls._kept = None  # the old buffer unmapped before the new is made
+                    cls._kept = np.empty((d, d))
+                self._buf = cls._kept
+        event(f"linreg_solve_workspace[{'new' if why else 'reused'}]",
+              detail=f"d={d} {why}".rstrip())
+        return _normal_system(gram, sw, mean, scale, ridge, out=self._buf)
+
+    def close(self) -> None:
+        self._buf = None
+        if self._holds:
+            self._holds = False
+            _SystemWorkspace._lock.release()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.close()
+        return False
 
 
 def _quadratic_form(gram: np.ndarray, v: np.ndarray) -> float:
@@ -384,7 +458,8 @@ def solve_linear_host(
     # only read, in the dtype it came in: at d = 3000 a (d,d) float64 is
     # 72 MB, first-touched page by page, and the ten temporaries of a build
     # by whole-array expressions took longer than the factorisation.  The
-    # one such array made here is the system's buffer (`_normal_system`).
+    # one such array is the system's buffer, kept across solves
+    # (`_SystemWorkspace`).
     gram = np.asarray(gram)
     sxy = np.asarray(sxy, np.float64)
     s1 = np.asarray(s1, np.float64)
@@ -403,8 +478,9 @@ def solve_linear_host(
     std = np.where(std == 0.0, 1.0, std)
     scale = std if standardization else np.ones(d)
     sxy_s = sxy_c / scale
+    workspace = _SystemWorkspace()
     system = functools.partial(
-        _normal_system, gram, sw,
+        workspace.system, gram, sw,
         mean if fit_intercept else None, std if standardization else None,
     )
 
@@ -413,119 +489,121 @@ def solve_linear_host(
     n_iter = 0
 
     # every branch builds its float64 system under `linreg_solve_assemble`
-    # (the widening copy and first touch of the one (d,d) buffer) and
-    # solves it under `linreg_solve_factor`: both the host working, inside
-    # the caller's `linreg_host_solve`
+    # (the widening copy into the kept (d,d) buffer, first touched by the
+    # solve that made it) and solves it under `linreg_solve_factor`: both
+    # the host working, inside the caller's `linreg_host_solve`
     from ..tracing import event, trace
 
     def assembled(ridge: float) -> np.ndarray:
         with trace("linreg_solve_assemble", detail="work"):
             return system(ridge)
 
-    if reg_param == 0.0:
-        A = assembled(0.0)
-        with trace("linreg_solve_factor", detail="work"):
-            coef_s = np.linalg.lstsq(A, sxy_s, rcond=None)[0]
-    elif l1 == 0.0:
-        # ridge closed form; penalty in 1/(2n) objective units -> n·λ₂ on
-        # the un-normalized Gram (the reference's alpha×=m, regression.py:575-580).
-        # Symmetric positive definite by construction, so a Cholesky
-        # factorisation solves it (half an LU's work, no pivoting).  Where
-        # it is not numerically (a float32-accumulated Gram whose
-        # near-collinear columns the penalty does not lift) the
-        # factorisation says so and the pivoted LU takes it, as before.
-        # Cholesky reads one triangle: a Gram whose [i,j] and [j,i] were
-        # rounded apart (off the chip: 5e-9 of its norm) moves the
-        # coefficients against the LU's by about as much.
-        from scipy.linalg import LinAlgError, cho_factor, cho_solve
+    with workspace:
+        if reg_param == 0.0:
+            A = assembled(0.0)
+            with trace("linreg_solve_factor", detail="work"):
+                coef_s = np.linalg.lstsq(A, sxy_s, rcond=None)[0]
+        elif l1 == 0.0:
+            # ridge closed form; penalty in 1/(2n) objective units -> n·λ₂ on
+            # the un-normalized Gram (the reference's alpha×=m, regression.py:575-580).
+            # Symmetric positive definite by construction, so a Cholesky
+            # factorisation solves it (half an LU's work, no pivoting).  Where
+            # it is not numerically (a float32-accumulated Gram whose
+            # near-collinear columns the penalty does not lift) the
+            # factorisation says so and the pivoted LU takes it, as before.
+            # Cholesky reads one triangle: a Gram whose [i,j] and [j,i] were
+            # rounded apart (off the chip: 5e-9 of its norm) moves the
+            # coefficients against the LU's by about as much.
+            from scipy.linalg import LinAlgError, cho_factor, cho_solve
 
-        A = assembled(sw * l2)
-        with trace("linreg_solve_factor", detail="work"):
-            try:
-                factor = cho_factor(
-                    _fortran_view(A),
-                    lower=True, overwrite_a=True, check_finite=False,
-                )
-            except LinAlgError:
-                coef_s = None  # the factorisation wrote over its system
-            else:
-                event("linreg_solver[cholesky]")
-                coef_s = cho_solve(factor, sxy_s, check_finite=False)
-        if coef_s is None:
-            event("linreg_solver[lu_fallback]")
             A = assembled(sw * l2)
             with trace("linreg_solve_factor", detail="work"):
-                coef_s = np.linalg.solve(A, sxy_s)
-    else:
-        # FISTA on f(β)=1/(2n)(βᵀGβ - 2bᵀβ) + λ₂/2‖β‖², prox for λ₁‖β‖₁
-        from ..resilience import maybe_inject
-        from ..resilience.checkpoint import (
-            clear_checkpoint,
-            load_checkpoint,
-            save_checkpoint,
-        )
-
-        with trace("linreg_solve_assemble", detail="work"):
-            G = system(0.0)
-            G /= sw
-            b = sxy_s / sw
-        # the solve of this branch: the step size (an eigenvalue of the
-        # system) and the proximal iterations
-        with trace("linreg_solve_factor", detail="work"):
-            L = float(np.linalg.eigvalsh(G)[-1]) + l2
-            L = max(L, 1e-12)
-            beta = np.zeros(d)
-            z = beta.copy()
-            t_mom = 1.0
-            start_it = 0
-            resumed = (
-                load_checkpoint(checkpoint_path, checkpoint_tag)
-                if checkpoint_path
-                else None
-            )
-            if resumed is not None:
-                beta = np.asarray(resumed["beta"])
-                z = np.asarray(resumed["z"])
-                t_mom = float(resumed["t_mom"])
-                start_it = int(resumed["it"])
-                # a checkpoint saved at it==max_iter (crash between the final
-                # save and clear) skips the loop entirely — the diag count
-                # must still report the iterations already run
-                n_iter = start_it
-                event("fista_resume", detail=f"it={start_it}")
-            from ..telemetry import Heartbeat
-
-            hb = Heartbeat("fista", total=max_iter)
-            for it in range(start_it, max_iter):
-                maybe_inject("linreg_fista")
-                grad = G @ z - b + l2 * z
-                beta_new = _soft_threshold(z - grad / L, l1 / L)
-                t_new = 0.5 * (1.0 + np.sqrt(1.0 + 4.0 * t_mom * t_mom))
-                z = beta_new + ((t_mom - 1.0) / t_new) * (beta_new - beta)
-                delta = float(np.max(np.abs(beta_new - beta)))
-                beta = beta_new
-                t_mom = t_new
-                n_iter = it + 1
-                hb.beat(n_iter, detail=f"delta={delta:.3e}")
-                if checkpoint_path:
-                    save_checkpoint(
-                        checkpoint_path, checkpoint_tag,
-                        {"beta": beta, "z": z, "t_mom": t_mom, "it": n_iter},
+                try:
+                    factor = cho_factor(
+                        _fortran_view(A),
+                        lower=True, overwrite_a=True, check_finite=False,
                     )
-                if delta <= tol * max(1.0, float(np.max(np.abs(beta)))):
-                    break
-            # end-mark on normal completion (Heartbeat.close): a scrape
-            # after the fit shows no live fista series
-            hb.close()
-            if checkpoint_path:
-                clear_checkpoint(checkpoint_path)
-        coef_s = beta
+                except LinAlgError:
+                    coef_s = None  # the factorisation wrote over its system
+                else:
+                    event("linreg_solver[cholesky]")
+                    coef_s = cho_solve(factor, sxy_s, check_finite=False)
+            if coef_s is None:
+                event("linreg_solver[lu_fallback]")
+                A = assembled(sw * l2)
+                with trace("linreg_solve_factor", detail="work"):
+                    coef_s = np.linalg.solve(A, sxy_s)
+        else:
+            # FISTA on f(β)=1/(2n)(βᵀGβ - 2bᵀβ) + λ₂/2‖β‖², prox for λ₁‖β‖₁
+            from ..resilience import maybe_inject
+            from ..resilience.checkpoint import (
+                clear_checkpoint,
+                load_checkpoint,
+                save_checkpoint,
+            )
 
-    # the system's one (d,d) float64 buffer is dropped here, under a span,
-    # and not at the return, where it ran under none: unmapping 72 MB at
-    # 3,000 columns is milliseconds of the host's
-    with trace("linreg_solve_release", detail="work"):
-        A = factor = G = None  # whichever this branch made
+            with trace("linreg_solve_assemble", detail="work"):
+                G = system(0.0)
+                G /= sw
+                b = sxy_s / sw
+            # the solve of this branch: the step size (an eigenvalue of the
+            # system) and the proximal iterations
+            with trace("linreg_solve_factor", detail="work"):
+                L = float(np.linalg.eigvalsh(G)[-1]) + l2
+                L = max(L, 1e-12)
+                beta = np.zeros(d)
+                z = beta.copy()
+                t_mom = 1.0
+                start_it = 0
+                resumed = (
+                    load_checkpoint(checkpoint_path, checkpoint_tag)
+                    if checkpoint_path
+                    else None
+                )
+                if resumed is not None:
+                    beta = np.asarray(resumed["beta"])
+                    z = np.asarray(resumed["z"])
+                    t_mom = float(resumed["t_mom"])
+                    start_it = int(resumed["it"])
+                    # a checkpoint saved at it==max_iter (crash between the final
+                    # save and clear) skips the loop entirely — the diag count
+                    # must still report the iterations already run
+                    n_iter = start_it
+                    event("fista_resume", detail=f"it={start_it}")
+                from ..telemetry import Heartbeat
+
+                hb = Heartbeat("fista", total=max_iter)
+                for it in range(start_it, max_iter):
+                    maybe_inject("linreg_fista")
+                    grad = G @ z - b + l2 * z
+                    beta_new = _soft_threshold(z - grad / L, l1 / L)
+                    t_new = 0.5 * (1.0 + np.sqrt(1.0 + 4.0 * t_mom * t_mom))
+                    z = beta_new + ((t_mom - 1.0) / t_new) * (beta_new - beta)
+                    delta = float(np.max(np.abs(beta_new - beta)))
+                    beta = beta_new
+                    t_mom = t_new
+                    n_iter = it + 1
+                    hb.beat(n_iter, detail=f"delta={delta:.3e}")
+                    if checkpoint_path:
+                        save_checkpoint(
+                            checkpoint_path, checkpoint_tag,
+                            {"beta": beta, "z": z, "t_mom": t_mom, "it": n_iter},
+                        )
+                    if delta <= tol * max(1.0, float(np.max(np.abs(beta)))):
+                        break
+                # end-mark on normal completion (Heartbeat.close): a scrape
+                # after the fit shows no live fista series
+                hb.close()
+                if checkpoint_path:
+                    clear_checkpoint(checkpoint_path)
+            coef_s = beta
+
+        # the system's references are dropped here, under a span, and the
+        # slot given back: its buffer stays mapped for the next solve (one
+        # of its own, where the slot was held, is unmapped here)
+        with trace("linreg_solve_release", detail="work"):
+            A = factor = G = None  # whichever this branch made
+            workspace.close()
     coef = coef_s / scale
     intercept = float(ymean - mean @ coef) if fit_intercept else 0.0
     # training-summary statistics from the same sufficient stats (Spark's

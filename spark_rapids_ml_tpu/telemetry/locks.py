@@ -150,6 +150,12 @@ LOCK_CATALOG: Dict[str, Dict[str, Any]] = {
     "fit_multiple": {
         "kind": "lock", "module": "spark_rapids_ml_tpu/core.py",
     },
+    # ops/linear.py: the one slot of the host solve's float64 system
+    # buffer, taken without waiting (a solve that finds it held builds in
+    # a buffer of its own)
+    "linreg_system_workspace": {
+        "kind": "lock", "module": "spark_rapids_ml_tpu/ops/linear.py",
+    },
     # native.py: the one-shot native library build/load guard
     "native_build": {
         "kind": "lock", "module": "spark_rapids_ml_tpu/native.py",

@@ -323,18 +323,25 @@ def test_closed_form_ridge_solve_matches_textbook(
     assert events == ["linreg_solver[cholesky]"]
 
 
+def _indefinite_stats(dtype):
+    """`_ridge_stats` less enough of the centred Gram's lowest eigenvector
+    that the penalised system is no longer positive definite."""
+    gram, sxy, s1, sw, sy, syy = _ridge_stats(np.float64, weighted=True)
+    mean = s1 / sw
+    lam, V = np.linalg.eigh(gram - sw * np.outer(mean, mean))
+    gram = gram - (lam[0] + 2.0 * sw * _REG) * np.outer(V[:, 0], V[:, 0])
+    return (gram.astype(dtype), sxy.astype(dtype), s1.astype(dtype), sw, sy, syy)
+
+
 @pytest.mark.parametrize("dtype", [np.float32, np.float64], ids=["f32", "f64"])
 def test_closed_form_ridge_indefinite_system_takes_the_lu(dtype):
     """A Gram whose centred part has an eigenvalue under -sw*l2 (what a
     float32 accumulation over near-collinear columns can hand over) has no
     Cholesky factor: the solve answers what the pivoted LU answers."""
-    gram, sxy, s1, sw, sy, syy = _ridge_stats(np.float64, weighted=True)
-    mean = s1 / sw
-    lam, V = np.linalg.eigh(gram - sw * np.outer(mean, mean))
-    gram = gram - (lam[0] + 2.0 * sw * _REG) * np.outer(V[:, 0], V[:, 0])
-    stats = (gram.astype(dtype), sxy.astype(dtype), s1.astype(dtype), sw, sy, syy)
+    stats = _indefinite_stats(dtype)
+    sw = stats[3]
     g64, m64 = np.float64(stats[0]), np.float64(stats[2]) / sw
-    system = g64 - sw * np.outer(m64, m64) + sw * _REG * np.eye(len(s1))
+    system = g64 - sw * np.outer(m64, m64) + sw * _REG * np.eye(len(m64))
     assert np.linalg.eigvalsh(system)[0] < 0.0
 
     (coef, intercept, diag), events = _solve_and_events(stats, True, False)
@@ -351,19 +358,213 @@ def test_closed_form_ridge_solve_makes_one_matrix(dtype):
     """At d = 3000 a (d,d) float64 is 72 MB, first-touched page by page:
     the ten temporaries of the earlier build cost more than the
     factorisation (its peak by this probe: 5.0x d^2 x 8 bytes for a float32
-    Gram, 4.0x for a float64 one).  Now: the one system buffer, factored in
-    place, beside blocks of the Gram for the summary."""
-    import tracemalloc
-
+    Gram, 4.0x for a float64 one).  Now: the one system buffer, kept from
+    the solve before and factored in place, beside blocks of the Gram for
+    the assembly and the summary: no (d,d) array is made at all."""
     d = 1024  # the summary's blocks are ~2 MB: a quarter of a matrix here
     stats = _ridge_stats(dtype, weighted=False, n=2 * d, d=d)
     _solve_and_events(stats, True, True)  # imports and lazy set-up, untraced
+    events, peak = _solve_peak(stats)
+    assert events == ["linreg_solver[cholesky]"]
+    assert peak < 0.5 * d * d * 8, peak / (d * d * 8)
+
+
+def _solve_peak(stats):
+    """The solver's instants and the peak of what the solve allocated."""
+    import tracemalloc
+
     tracemalloc.start()
     try:
         tracemalloc.reset_peak()
         _, events = _solve_and_events(stats, True, True)
-        peak = tracemalloc.get_traced_memory()[1]
+        return events, tracemalloc.get_traced_memory()[1]
     finally:
         tracemalloc.stop()
+
+
+@pytest.mark.parametrize("why", ["first", "busy"])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64], ids=["f32", "f64"])
+def test_a_solve_into_a_new_system_buffer_makes_one_matrix(dtype, why, monkeypatch):
+    """Where the slot cannot serve (empty, or held by another solve), the
+    solve maps its one (d,d) float64 buffer and factors it in place, beside
+    blocks of the Gram: one matrix, no second."""
+    from spark_rapids_ml_tpu.ops.linear import _SystemWorkspace
+
+    d = 1024
+    stats = _ridge_stats(dtype, weighted=False, n=2 * d, d=d)
+    _solve_and_events(stats, True, True)  # imports and lazy set-up, untraced
+    if why == "first":
+        monkeypatch.setattr(_SystemWorkspace, "_kept", None)
+        events, peak = _solve_peak(stats)
+    else:
+        held = _held_slot()[0]
+        try:
+            events, peak = _solve_peak(stats)
+        finally:
+            held.close()
     assert events == ["linreg_solver[cholesky]"]
-    assert peak < 1.5 * d * d * 8, peak / (d * d * 8)
+    assert d * d * 8 <= peak < 1.5 * d * d * 8, peak / (d * d * 8)
+
+
+# ---- the system's buffer, kept across solves (`_SystemWorkspace`) ----
+
+# branch -> (regParam, elasticNetParam, the solver instants it records)
+_BRANCHES = {
+    "cholesky": (_REG, 0.0, ["linreg_solver[cholesky]"]),
+    "lu_fallback": (_REG, 0.0, ["linreg_solver[lu_fallback]"]),
+    "lstsq": (0.0, 0.0, []),
+    "fista": (_REG, 0.5, []),
+}
+
+
+def _workspace_solve(stats, reg_param=_REG, elasticnet_param=0.0, standardization=True):
+    """The solve, and its instants: the solver's, and how each assembly had
+    its buffer as (name, detail)."""
+    from spark_rapids_ml_tpu import tracing
+    from spark_rapids_ml_tpu.ops.linear import solve_linear_host
+
+    tracing.reset_trace()
+    out = solve_linear_host(
+        *stats, reg_param=reg_param, elasticnet_param=elasticnet_param,
+        fit_intercept=True, standardization=standardization, tol=1e-30, max_iter=10,
+    )
+    events = tracing.get_trace_events()
+    return (out,
+            [e.name for e in events if e.name.startswith("linreg_solver[")],
+            [(e.name, e.detail) for e in events
+             if e.name.startswith("linreg_solve_workspace[")])
+
+
+def _assert_same_answer(got, want, rel):
+    (coef, intercept, diag), (ref_coef, ref_intercept, ref_diag) = got, want
+    np.testing.assert_allclose(coef, ref_coef, rtol=rel, atol=0)
+    assert intercept == pytest.approx(ref_intercept, rel=rel, abs=0)
+    for k in ("mse", "rmse", "r2", "n_iter"):
+        assert diag[k] == pytest.approx(ref_diag[k], rel=rel, abs=0), k
+
+
+def _assert_textbook(out, stats):
+    (coef, intercept, diag), (ref_coef, ref_intercept, ref_diag) = (
+        out, _textbook_ridge(*stats, True, False))
+    np.testing.assert_allclose(coef, ref_coef, rtol=0, atol=1e-10 * np.abs(ref_coef).max())
+    assert intercept == pytest.approx(ref_intercept, rel=1e-10, abs=1e-10)
+    for k in ("mse", "rmse", "r2"):
+        assert diag[k] == pytest.approx(ref_diag[k], rel=1e-12), k
+
+
+def _held_slot():
+    """A solve in progress elsewhere: its assembly holds the slot until
+    `close`, and its system must come through another solve unwritten."""
+    from spark_rapids_ml_tpu.ops.linear import _SystemWorkspace
+
+    held = _SystemWorkspace()
+    stats = _ridge_stats(np.float64, weighted=False, seed=11)
+    system = held.system(stats[0], stats[3], stats[2] / stats[3], None, 1.0)
+    return held, system, system.copy()
+
+
+@pytest.mark.parametrize("standardization", [False, True], ids=["raw", "std"])
+@pytest.mark.parametrize("branch", sorted(_BRANCHES))
+def test_a_kept_system_buffer_answers_as_a_fresh_one(branch, standardization):
+    """The second solve of a d writes into the buffer the first left (its
+    factor, of another system, in one triangle) and answers what a solve
+    into a buffer of its own answers, on every branch: the LU's second
+    assembly over the factorisation that refused, OLS' `lstsq` that reads
+    both triangles, FISTA's system divided in place.  The caller's
+    statistics stay as they came."""
+    reg_param, enet, solvers = _BRANCHES[branch]
+    stats = (_indefinite_stats(np.float32) if branch == "lu_fallback"
+             else _ridge_stats(np.float32, weighted=True))
+    for a in stats[:3]:
+        a.setflags(write=False)  # as a fetched device array is
+    before = [a.tobytes() for a in stats[:3]]
+
+    _workspace_solve(_ridge_stats(np.float64, weighted=False, seed=7))
+    kept, kept_solvers, kept_how = _workspace_solve(
+        stats, reg_param, enet, standardization)
+    held, system, want = _held_slot()
+    try:
+        fresh, fresh_solvers, fresh_how = _workspace_solve(
+            stats, reg_param, enet, standardization)
+    finally:
+        held.close()
+
+    assembled = 2 if branch == "lu_fallback" else 1
+    assert kept_how == [("linreg_solve_workspace[reused]", "d=24")] * assembled
+    assert fresh_how[0] == ("linreg_solve_workspace[new]", "d=24 busy")
+    assert kept_solvers == fresh_solvers == solvers
+    np.testing.assert_array_equal(system, want)
+    _assert_same_answer(kept, fresh, 1e-12)
+    assert [a.tobytes() for a in stats[:3]] == before
+
+
+
+@pytest.mark.parametrize("why", ["first", "shape"])
+def test_a_solve_of_another_d_replaces_the_kept_buffer(why, monkeypatch):
+    """An empty slot, or one that holds another d, gets a new buffer, which
+    the slot keeps in the old one's place for the next solve of this d."""
+    from spark_rapids_ml_tpu.ops.linear import _SystemWorkspace
+
+    if why == "first":
+        monkeypatch.setattr(_SystemWorkspace, "_kept", None)
+    else:
+        _workspace_solve(_ridge_stats(np.float64, weighted=False, d=24))
+    stats = _ridge_stats(np.float64, weighted=True, d=16)
+    out, solvers, how = _workspace_solve(stats, standardization=False)
+    assert how == [("linreg_solve_workspace[new]", f"d=16 {why}")]
+    assert solvers == ["linreg_solver[cholesky]"]
+    _assert_textbook(out, stats)
+    assert _SystemWorkspace._kept.shape == (16, 16)
+    assert _workspace_solve(stats)[2] == [("linreg_solve_workspace[reused]", "d=16")]
+
+
+def test_a_solve_that_finds_the_slot_held_builds_in_a_buffer_of_its_own():
+    """Two solves at once (threads of `fitMultiple`, two estimators): the
+    second makes its own buffer, as every solve did before the slot, answers
+    what the textbook does and leaves the first's system as it was; once the
+    first gives the slot back the next solve has it again."""
+    stats = _ridge_stats(np.float32, weighted=True)
+    held, system, want = _held_slot()
+    try:
+        out, solvers, how = _workspace_solve(stats, standardization=False)
+        np.testing.assert_array_equal(system, want)
+    finally:
+        held.close()
+    assert how == [("linreg_solve_workspace[new]", "d=24 busy")]
+    assert solvers == ["linreg_solver[cholesky]"]
+    _assert_textbook(out, stats)
+    assert _workspace_solve(stats)[2] == [("linreg_solve_workspace[reused]", "d=24")]
+
+
+def test_solves_on_eight_threads_each_answer_their_own_system():
+    """Solves at once on eight threads against the one slot, the interpreter
+    switching threads every microsecond, over systems of two widths:
+    whichever solve holds the slot, and whatever width it keeps, no solve's
+    system is written by another's."""
+    import sys
+    from concurrent.futures import ThreadPoolExecutor
+
+    from spark_rapids_ml_tpu.ops.linear import solve_linear_host
+
+    problems = ([_ridge_stats(np.float64, weighted=True, seed=s) for s in range(6)]
+                + [_ridge_stats(np.float64, weighted=True, d=16, seed=s) for s in range(2)])
+
+    def solve(k):
+        return solve_linear_host(
+            *problems[k], reg_param=_REG, elasticnet_param=0.0, fit_intercept=True,
+            standardization=False, tol=1e-30, max_iter=10)
+
+    def work(i):
+        for j in range(24):
+            k = (i + j) % len(problems)
+            _assert_textbook(solve(k), problems[k])
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = 8
+        with ThreadPoolExecutor(threads) as pool:
+            for done in [pool.submit(work, i) for i in range(threads)]:
+                done.result(timeout=120)
+    finally:
+        sys.setswitchinterval(switch)

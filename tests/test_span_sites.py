@@ -202,6 +202,11 @@ def test_route_records_its_spans(route, monkeypatch):
             "linreg_solve_summary"]
         assert [c["name"] for c in solve["children"][1]["children"]] == [
             "linreg_solver[cholesky]"]
+        # ... into the kept buffer, the instant inside the assembly saying
+        # how it was had (`new` where no earlier solve of this d kept one)
+        (how,) = solve["children"][0]["children"]
+        assert how["name"] in ("linreg_solve_workspace[new]",
+                               "linreg_solve_workspace[reused]"), how
     if route in ("pca", "pca_subspace"):
         # each instant under the span whose work it names; the eigensolve
         # holds the device loop and one span a float64 sweep, as many as the
@@ -290,6 +295,22 @@ def test_route_records_its_spans(route, monkeypatch):
                 if n["name"] == "stage_put" and n["seconds"] >= 1e-3)
             for r in reports)
         assert tiled >= 0.9, f"a stage_put covered to {tiled:.2f}"
+
+    # a later fit of the same estimator builds its system in the buffer the
+    # fit before kept; no PCA route builds in it
+    for report in reports:
+        workspace = [n for n, _ in _walk(report["spans"])
+                     if n["name"].startswith("linreg_solve_workspace[")]
+        if route != "ridge":
+            assert not workspace, workspace
+        elif report is not first:
+            solve = _find(report, "linreg_host_solve")
+            assert [c["name"] for c in solve["children"]] == [
+                "linreg_solve_assemble", "linreg_solve_factor", "linreg_solve_release",
+                "linreg_solve_summary"]
+            assert [c["name"] for c in solve["children"][0]["children"]] == [
+                "linreg_solve_workspace[reused]"]
+            assert workspace[0]["detail"] == f"d={d}"
 
     # one iteration span an iteration, the rest of the vocabulary once a fit
     if route == "kmeans_stepwise":
