@@ -571,8 +571,9 @@ class LogisticRegression(
                 X = X.astype(jnp.bfloat16)
             # which evaluation either route runs is read from the rows
             # alone: one read of them an evaluation (a Pallas kernel) for
-            # dense float32 binomial rows on TPUs, the rows in blocks for
-            # dense float32 multinomial rows on TPUs, autodiff otherwise
+            # dense float32 binomial rows on TPUs, for dense float32
+            # multinomial rows on TPUs another kernel over row tiles or the
+            # rows in blocks, autodiff otherwise
             from ..ops.logistic import (
                 one_pass_program_bytes,
                 softmax_plan,
@@ -586,7 +587,7 @@ class LogisticRegression(
             if not binomial:
                 softmax, why_kernel = softmax_plan(X, n_classes)
             evaluation = (
-                "one_pass" if one_pass else "softmax_blocks" if softmax else "autodiff"
+                "one_pass" if one_pass else softmax.evaluation if softmax else "autodiff"
             )
             # fused single-program L-BFGS while the device holds what that
             # program holds beside the resident rows
@@ -608,8 +609,7 @@ class LogisticRegression(
                     held = one_pass_program_bytes(shard_rows, d, kwargs["history"])
                 else:
                     held = softmax_program_bytes(
-                        shard_rows, d, n_classes, kwargs["history"],
-                        softmax.block_rows,
+                        shard_rows, d, n_classes, kwargs["history"], softmax
                     )
                 fits = fused_program_fits(X, held, rows_in_place=True)
                 host_dispatch = bool(ckpt_path) or not fits

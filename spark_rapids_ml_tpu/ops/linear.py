@@ -100,6 +100,23 @@ def _bf16_parts(Z: jax.Array, n: int = 3):
     return parts
 
 
+def _split_product(a_parts, b_parts, dims) -> jax.Array:
+    """dot_general(a, b, dims) of float32 operands from as many bfloat16
+    parts each (`_bf16_parts(x, n)`): the f32 sum of the part products
+    a_i.b_j with i + j < n, smallest first (one part: the one default pass;
+    two: lo.hi + hi.lo + hi.hi; three: XLA's six of `highest`), each one
+    exact bfloat16 MXU pass accumulated in f32."""
+    n = len(a_parts)
+    terms = sorted(((i, j) for i in range(n) for j in range(n) if i + j < n),
+                   key=lambda t: (-t[0] - t[1], -t[0]))
+    out = None
+    for i, j in terms:
+        part = jax.lax.dot_general(a_parts[i], b_parts[j], dims,
+                                   preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+    return out
+
+
 def _rows_dot(a: jax.Array, b: jax.Array) -> jax.Array:
     """a^T b of bfloat16 (rows, p) and (rows, q): one MXU pass, every
     product exact in the float32 it is accumulated in."""

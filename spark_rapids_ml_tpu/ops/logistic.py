@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from .lbfgs import lbfgs_minimize
-from .linear import _bf16_parts, _on_tpu
+from .linear import _bf16_parts, _on_tpu, _split_product
 
 # Sample-weight/fold-mask contract (parallel/device_cache.py): the loss,
 # gradient, label range, and standardization moments all weight rows by
@@ -215,12 +215,22 @@ def _solve_multinomial(
 # GB beside 10.5 GB of rows on a 15.75 GB chip.  `softmax_data_term` reads
 # the resident rows in place, one `dynamic_slice` of `block_rows` rows a
 # step of a `fori_loop` (the Gram's block programs and `take_rows` read them
-# so), and per block takes the logits, the log-sum-exp, the residual
-# w (P - Y) with Y a compare of the label ids with a class iota, the
-# gradient residual^T X_b and the value's sum: nothing of (rows, C) beyond
-# one block.  The rows' count need not divide: the last block starts
+# so), and per block takes the logits, the log-sum-exp, the residual w P,
+# the gradient's part (w P)^T X_b and the value's sum: nothing of (rows, C)
+# beyond one block.  The rows' count need not divide: the last block starts
 # `block_rows` from the end and drops by weight the rows a block before it
 # took.
+#
+# The gradient is (w (P - Y))^T X, and its labels' part (w Y)^T X is a
+# constant of the fit, taken once before the solver's loop and exactly
+# (`_label_sums`: the one-hot labels are exact in one bfloat16 part, w x in
+# three).  Cut into two bfloat16 parts with the labels in it, each row's
+# own-class residual P_y - 1 ~ -0.999 would keep 16 bits of ~1 and lose
+# P_y's change between two nearby points: the first curvature pair of a
+# 1,000-class fit read 9e-4 of the objective off its float32 value at
+# iteration 2 that way (PERF.md §6), so far that any change of the
+# float32 rounding flips the reference's closest line-search decisions
+# (PERF.md §6).  Cut without them, w P keeps 16 bits of itself.
 #
 # Both products are float32-faithful on a TPU, where a float32 matmul at
 # default precision is ONE bfloat16 pass: each operand is cut into two
@@ -237,37 +247,39 @@ _PASSES = _PARTS * (_PARTS + 1) // 2  # bfloat16 MXU passes a product takes
 # two products at 2,048 features are tens of milliseconds of MXU work behind
 # each loop step
 _SOFTMAX_MAX_BLOCK_ROWS = 16_384
+# rows to a block of the labels' sums: the block's w x and its three
+# bfloat16 parts are 0.17 GB at 2,048 features
+_LABEL_BLOCK_ROWS = 8_192
 
 
 class SoftmaxBlocks(NamedTuple):
     """The blocked evaluation takes this multinomial fit's evaluations:
     `block_rows` rows of each device's shard a step; `mesh` is the mesh the
-    rows are sharded over, None where they lie on one device."""
+    rows are sharded over, None where they lie on one device.  (Where the
+    rows allow it the one-pass kernel takes them instead:
+    `ops/pallas_softmax.SoftmaxTiles`.)"""
 
     block_rows: int
     mesh: Optional[Mesh] = None
+    evaluation = "softmax_blocks"  # the name `lbfgs_eval_kernel[...]` gives
 
+    def shard(self, X, w, y, Wm, b):
+        """One device's (value, gradients but for the labels' part) over
+        its shard of the rows."""
+        return _softmax_shard(X, w, y, Wm, b, self.block_rows)
 
-def _split_product(a_parts, b_parts, dims) -> jax.Array:
-    """dot_general(a, b, dims) of float32 operands from as many bfloat16
-    parts each (`_bf16_parts(x, n)`): the f32 sum of the part products
-    a_i.b_j with i + j < n, smallest first (one part: the one default pass;
-    two: lo.hi + hi.lo + hi.hi; three: XLA's six of `highest`), each one
-    exact bfloat16 MXU pass accumulated in f32."""
-    n = len(a_parts)
-    terms = sorted(((i, j) for i in range(n) for j in range(n) if i + j < n),
-                   key=lambda t: (-t[0] - t[1], -t[0]))
-    out = None
-    for i, j in terms:
-        part = jax.lax.dot_general(a_parts[i], b_parts[j], dims,
-                                   preferred_element_type=jnp.float32)
-        out = part if out is None else out + part
-    return out
+    def held_bytes(self, d: int, C: int) -> int:
+        """What the evaluation holds beside the rows and the L-BFGS pairs:
+        a dozen vectors of C (d + 1), their accumulators and one block's
+        temporaries (`softmax_row_bytes`, which over-counts what XLA keeps
+        of a block)."""
+        return 4 * 12 * C * (d + 1) + self.block_rows * softmax_row_bytes(d, C)
 
 
 def _softmax_shard(X, w, y, Wm, b, block_rows: int):
-    """One device's (Σᵢ wᵢ nllᵢ, its gradient in W (C, d) and in b (C,))
-    over its shard of the rows, `block_rows` rows a step."""
+    """One device's (Σᵢ wᵢ nllᵢ, Σᵢ wᵢ Pᵢ xᵢ (C, d), Σᵢ wᵢ Pᵢ (C,)) over
+    its shard of the rows, `block_rows` rows a step: the value and the
+    gradients but for their labels' part (`_label_sums`)."""
     rows, d = X.shape
     C = Wm.shape[0]
     W_parts = _bf16_parts(Wm.astype(jnp.float32), _PARTS)
@@ -290,7 +302,7 @@ def _softmax_shard(X, w, y, Wm, b, block_rows: int):
         s = e.sum(axis=1, keepdims=True)
         hit = yb[:, None] == classes
         nll = jnp.log(s[:, 0]) - jnp.where(hit, z, 0.0).sum(axis=1)
-        r = wb[:, None] * (e / s - hit.astype(jnp.float32))
+        r = wb[:, None] * (e / s)  # the labels' part is `_label_sums`'
         grad = _split_product(_bf16_parts(r, _PARTS), X_parts, (((0,), (0,)), ((), ())))
         return value + (wb * nll).sum(), gW + grad, gb + r.sum(axis=0)
 
@@ -299,35 +311,91 @@ def _softmax_shard(X, w, y, Wm, b, block_rows: int):
     return jax.lax.fori_loop(0, -(-rows // block_rows), step, zero)
 
 
-@partial(jax.jit, static_argnames=("plan",))
-def _softmax_evaluate(plan: SoftmaxBlocks, X, w, y, Wm, b):
-    """(value, gradient in W, gradient in b) over all the rows: every
-    device runs the blocks of its shard and one psum joins them.  A jit of
-    its own, so that a fit's re-jit of its evaluation
-    (`logreg_fit_host_dispatch`) finds it traced."""
-    local = partial(_softmax_shard, block_rows=plan.block_rows)
+def _label_shard(X, w, y, C: int):
+    """One device's labels' part of the gradient, a constant of the fit:
+    (Σᵢ wᵢ [yᵢ = c] xᵢ (C, d), Σᵢ wᵢ [yᵢ = c] (C,)) over its shard,
+    exact but for float32's own sums: the one-hot labels are exact in one
+    bfloat16 part and wᵢ xᵢ in three, so the three part products (three
+    MXU passes once a fit) drop nothing."""
+    rows, d = X.shape
+    block_rows = min(rows, _LABEL_BLOCK_ROWS)
+    classes = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+    offsets = jax.lax.broadcasted_iota(jnp.int32, (block_rows,), 0)
+
+    def step(k, carry):
+        M, m = carry
+        first = k * block_rows
+        at = jnp.minimum(first, rows - block_rows)
+        Xb = jax.lax.dynamic_slice_in_dim(X, at, block_rows, 0)
+        yb = jax.lax.dynamic_slice_in_dim(y, at, block_rows, 0).astype(jnp.int32)
+        wb = jax.lax.dynamic_slice_in_dim(w, at, block_rows, 0).astype(jnp.float32)
+        wb = jnp.where(at + offsets >= first, wb, 0.0)
+        hit = yb[:, None] == classes
+        labels = hit.astype(jnp.bfloat16)
+        parts = _bf16_parts(wb[:, None] * Xb.astype(jnp.float32), 3)
+        block = None
+        for part in reversed(parts):  # smallest first
+            term = jax.lax.dot_general(labels, part, (((0,), (0,)), ((), ())),
+                                       preferred_element_type=jnp.float32)
+            block = term if block is None else block + term
+        return M + block, m + jnp.where(hit, wb[:, None], 0.0).sum(axis=0)
+
+    zero = (jnp.zeros((C, d), jnp.float32), jnp.zeros((C,), jnp.float32))
+    return jax.lax.fori_loop(0, -(-rows // block_rows), step, zero)
+
+
+def _over_shards(plan, local, X, w, y, *replicated):
+    """`local(X, w, y, *replicated)` on each device's shard of the rows,
+    one psum joining them where `plan.mesh` shards them."""
     if plan.mesh is None:
-        return local(X, w, y, Wm, b)
+        return local(X, w, y, *replicated)
     axis = plan.mesh.axis_names[0]
     return jax.shard_map(
         lambda *args: jax.lax.psum(local(*args), axis), mesh=plan.mesh,
-        in_specs=(P(axis, None), P(axis), P(axis), P(), P()),
+        in_specs=(P(axis, None), P(axis), P(axis)) + (P(),) * len(replicated),
         out_specs=P(), check_vma=False,
-    )(X, w, y, Wm, b)
+    )(X, w, y, *replicated)
 
 
-def softmax_data_term(plan: SoftmaxBlocks, X: jax.Array, w: jax.Array, y: jax.Array):
+@partial(jax.jit, static_argnames=("plan", "C"))
+def _label_sums(plan, X, w, y, C: int):
+    """The labels' part of the gradient over all the rows
+    (`_label_shard`)."""
+    return _over_shards(plan, partial(_label_shard, C=C), X, w, y)
+
+
+@partial(jax.jit, static_argnames=("plan",))
+def _softmax_evaluate(plan, X, w, y, Wm, b, labels=None):
+    """(value, gradient in W, gradient in b) over all the rows: every
+    device runs the blocks of its shard (a `SoftmaxBlocks` plan) or the
+    one-pass kernel over its tiles (`ops/pallas_softmax.SoftmaxTiles`), one
+    psum joins them, and the labels' part (`_label_sums`, taken here where
+    the caller has not) comes off the gradients.  A jit of its own, so that
+    a fit's re-jit of its evaluation (`logreg_fit_host_dispatch`) finds it
+    traced."""
+    if labels is None:
+        labels = _label_sums(plan, X, w, y, Wm.shape[0])
+    value, gW, gb = _over_shards(plan, plan.shard, X, w, y, Wm, b)
+    return value, gW - labels[0], gb - labels[1]
+
+
+def softmax_data_term(plan, X: jax.Array, w: jax.Array, y: jax.Array, C: int,
+                      labels=None):
     """(W, b) -> Σᵢ wᵢ·(logsumexp(xᵢWᵀ + b) - (xᵢWᵀ + b)_yᵢ) over ALL the
-    rows, whose forward pass takes the gradient in the same read of each
-    block and keeps it as its residual: `jax.value_and_grad` of a loss
-    built on it reads the rows once an evaluation."""
+    rows of a C-class fit, whose forward pass takes the gradient in the
+    same read of each block and keeps it as its residual:
+    `jax.value_and_grad` of a loss built on it reads the rows once an
+    evaluation.  The labels' part of the gradient (`_label_sums`) is taken
+    here, once, where the caller does not hand it over."""
+    if labels is None:
+        labels = _label_sums(plan, X, w, y, C)
 
     @jax.custom_vjp
     def data_term(Wm, b):
-        return _softmax_evaluate(plan, X, w, y, Wm, b)[0]
+        return _softmax_evaluate(plan, X, w, y, Wm, b, labels)[0]
 
     def forward(Wm, b):
-        value, gW, gb = _softmax_evaluate(plan, X, w, y, Wm, b)
+        value, gW, gb = _softmax_evaluate(plan, X, w, y, Wm, b, labels)
         return value, (gW, gb)
 
     def backward(grads, ct):
@@ -337,10 +405,10 @@ def softmax_data_term(plan: SoftmaxBlocks, X: jax.Array, w: jax.Array, y: jax.Ar
     return data_term
 
 
-def _softmax_term(plan: Optional[SoftmaxBlocks], X, w, y):
+def _softmax_term(plan, X, w, y, C: int, labels=None):
     """`_multinomial_problem`'s `data_term` for the rows `X`: None without
     a plan (autodiff)."""
-    return None if plan is None else softmax_data_term(plan, X, w, y)
+    return None if plan is None else softmax_data_term(plan, X, w, y, C, labels)
 
 
 def softmax_row_bytes(d: int, C: int) -> int:
@@ -366,27 +434,25 @@ def softmax_block_rows(X: jax.Array, C: int) -> int:
     return min(shard_rows, -(-even // 8) * 8)
 
 
-def softmax_program_bytes(shard_rows: int, d: int, C: int, history: int,
-                          block_rows: int) -> int:
+def softmax_program_bytes(shard_rows: int, d: int, C: int, history: int, plan) -> int:
     """Upper bound of the bytes one device holds beside its `shard_rows` x
     `d` shard of the rows while the fused multinomial fit runs with the
-    blocked evaluation (`logreg_fit(..., softmax=plan)`), from its shapes:
-    the L-BFGS state (2 x `history` pairs and a dozen vectors of
-    C (d + 1)), the evaluation's accumulators, one block's temporaries
-    (`softmax_row_bytes`) and four rows-length 4-byte vectors (`w`, `y`
-    and their views).  `tests/test_pallas_logistic.py` holds the compile
-    for a v5e under it."""
-    n_param = C * (d + 1)
-    return (4 * ((2 * history + 12) * n_param + 4 * shard_rows)
-            + block_rows * softmax_row_bytes(d, C))
+    evaluation `plan` (`logreg_fit(..., softmax=plan)`), from its shapes:
+    four rows-length 4-byte vectors (`w`, `y` and their views), the L-BFGS
+    pairs (2 x `history` vectors of C (d + 1)) and what the evaluation
+    holds (`plan.held_bytes`).  `tests/test_pallas_logistic.py` holds the
+    compiles for a v5e under it."""
+    return 4 * 2 * history * C * (d + 1) + 4 * 4 * shard_rows + plan.held_bytes(d, C)
 
 
-def softmax_plan(X: jax.Array, n_classes: int) -> Tuple[Optional[SoftmaxBlocks], str]:
-    """Whether the blocked evaluation takes a dense multinomial fit's
-    evaluations, read from the input alone, and why (the `detail` of the
-    fit's `lbfgs_eval_kernel[...]` instant): float32 rows on TPUs, on one
-    device or sharded by rows over a mesh's first axis.  Elsewhere autodiff
-    keeps them (its products at `highest`)."""
+def softmax_plan(X: jax.Array, n_classes: int) -> Tuple[Optional[tuple], str]:
+    """Which evaluation takes a dense multinomial fit's evaluations, read
+    from the input alone, and why (the `detail` of the fit's
+    `lbfgs_eval_kernel[...]` instant): float32 rows on TPUs, on one device
+    or sharded by rows over a mesh's first axis, take the one-pass kernel
+    (`ops/pallas_softmax.SoftmaxTiles`) where they lie row-major and C and
+    d fit its VMEM plan, else the blocks (`SoftmaxBlocks`).  Elsewhere
+    autodiff keeps them (its products at `highest`)."""
     facts = f"{X.dtype} {tuple(X.shape)}, multinomial, {n_classes} classes"
     if X.dtype != jnp.float32:
         return None, f"{facts}: the blocks read float32 rows"
@@ -400,10 +466,15 @@ def softmax_plan(X: jax.Array, n_classes: int) -> Tuple[Optional[SoftmaxBlocks],
                 and spec[:1] == (sharding.mesh.axis_names[0],) and not any(spec[1:])):
             return None, f"{facts}: rows not sharded over a mesh's first axis alone ({sharding})"
         mesh = sharding.mesh
+    from .pallas_softmax import tiles_plan
+
+    where = f"passes={_PASSES} on {len(X.devices())} TPU(s)"
+    tiles, why = tiles_plan(X, n_classes, mesh)
+    if tiles is not None:
+        return tiles, f"{facts}: {why}, {where}"
     plan = SoftmaxBlocks(softmax_block_rows(X, n_classes), mesh)
     return plan, (
-        f"{facts}: C={n_classes}, block_rows={plan.block_rows}, "
-        f"passes={_PASSES} on {len(X.devices())} TPU(s)"
+        f"{facts}: {why}; C={n_classes}, block_rows={plan.block_rows}, {where}"
     )
 
 
@@ -451,7 +522,7 @@ def logreg_fit(
     return _solve_multinomial(
         partial(_dense_logits, X), n_classes, X.shape[1], dtype, w, y,
         l2, l1, fit_intercept, tol, max_iter, history, ls_max,
-        _softmax_term(softmax, X, w, y),
+        _softmax_term(softmax, X, w, y, n_classes),
     )
 
 
@@ -642,9 +713,11 @@ def logreg_fit_host_dispatch(
             1 if binomial else n_classes, d, dtype, fit_intercept
         )
         l1_mask = np.asarray(l1_mask, np.float64)
+        # the labels' part of a multinomial gradient, once a fit
+        labels = None if softmax is None else _label_sums(softmax, X, w, y, n_classes)
 
     @jax.jit
-    def vg_fn(theta, dat, w_, y_):
+    def vg_fn(theta, dat, w_, y_, labels_=None):
         # problem built INSIDE the trace: dat/w_/y_ are tracers here, so
         # the shared builders close over arguments, not concrete arrays
         if binomial:
@@ -655,7 +728,7 @@ def logreg_fit_host_dispatch(
         else:
             loss_fn, _, _, _ = _multinomial_problem(
                 lambda Wm: lfn(dat, Wm), n_classes, d, dtype, w_, y_, l2,
-                fit_intercept, _softmax_term(softmax, dat, w_, y_),
+                fit_intercept, _softmax_term(softmax, dat, w_, y_, n_classes, labels_),
             )
         return jax.value_and_grad(_eval_scope(loss_fn))(theta)
 
@@ -671,7 +744,7 @@ def logreg_fit_host_dispatch(
         # value and gradient
         with trace("lbfgs_eval"):
             with trace("lbfgs_eval_dispatch", detail="work"):
-                out = vg_fn(jnp.asarray(theta_np, dtype), operands, w, y)
+                out = vg_fn(jnp.asarray(theta_np, dtype), operands, w, y, labels)
             with trace("lbfgs_eval_wait", detail="wait"):
                 f, g = jax.device_get(out)
         return float(f), np.asarray(g, np.float64)

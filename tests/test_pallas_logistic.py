@@ -572,7 +572,72 @@ def test_one_chip_multinomial_fit_reads_its_rows_in_place(topo):
     ), written
     memory = compiled.memory_analysis()
     beside = memory.temp_size_in_bytes + memory.argument_size_in_bytes - rows * d * 4
-    assert beside <= softmax_program_bytes(rows, d, C, 10, block) < 1.5e9
+    assert beside <= softmax_program_bytes(rows, d, C, 10, SoftmaxBlocks(block)) < 1.5e9
+
+
+# -- and through the one-pass kernel (ops/pallas_softmax.py), the route that
+# shape takes on a v5e: the rows read in place by the kernel's tiles, W's
+# parts and the gradient beside them, the rest of a tile in VMEM
+
+def test_one_chip_multinomial_fit_through_the_kernel_reads_its_rows_in_place(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops import pallas_softmax as ps
+    from spark_rapids_ml_tpu.ops.logistic import logreg_fit, softmax_program_bytes
+
+    one = SingleDeviceSharding(topo.devices[0])
+    rows, d, C = 1_281_167, 2048, 1000
+    plan = ps.SoftmaxTiles(ps.tile_rows(d, C))
+    assert plan.tile_rows == 256
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = logreg_fit.lower(
+        spec((rows, d)), spec((rows,)), spec((rows,), jnp.int32), n_classes=C,
+        l2=1e-5, l1=0.0, fit_intercept=True, tol=1e-30, max_iter=20, softmax=plan,
+    ).compile()
+    text = compiled.as_text()
+    # the rows go to the kernel as they lie, row-major, and nothing writes
+    # an array of their size
+    assert f"f32[{rows},{d}]{{1,0:T(8,128)}}" in text.split("\n", 1)[0]
+    written = [line for line in text.splitlines() if f"= f32[{rows},{d}]" in line]
+    assert all(
+        any(op in line for op in (" parameter(", " bitcast(", " get-tuple-element("))
+        for line in written
+    ), written
+    # the first evaluation, the line search's first trial and its retries
+    assert text.count("tpu_custom_call") == 3
+    memory = compiled.memory_analysis()
+    beside = memory.temp_size_in_bytes + memory.argument_size_in_bytes - rows * d * 4
+    assert beside <= softmax_program_bytes(rows, d, C, 10, plan) < 0.5e9
+
+
+@pytest.mark.parametrize("rows,d,C", [(300, 128, 5), (5_003, 256, 10), (40_000, 3584, 1000), (20_000, 3840, 1000)],
+                         ids=["shard_under_a_tile", "ragged", "3584_features", "3840_features"])
+def test_the_softmax_kernel_compiles_where_its_plan_takes_the_rows(topo, rows, d, C):
+    """The tile its plan picks, for shards shorter than a tile, a ragged
+    last tile, and the widest features a 1,000-class plan takes at 256 rows
+    a tile and at 128 (3,968 it declines): its VMEM plan is an upper bound,
+    so the compile holds under the kernel's limit."""
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.ops import pallas_softmax as ps
+    from spark_rapids_ml_tpu.ops.logistic import _softmax_evaluate
+
+    one = SingleDeviceSharding(topo.devices[0])
+    tile = ps.tile_rows(d, C)
+    assert tile == (256 if d < 3840 else 128)
+    assert ps.tile_rows(3968, 1000) is None
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    compiled = _softmax_evaluate.lower(
+        ps.SoftmaxTiles(tile), spec((rows, d)), spec((rows,)), spec((rows,), jnp.int32),
+        spec((C, d)), spec((C,)),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 # -- PCA's covariance (ops/pca.py `pca_scatter`), compiled for the same chip: the
