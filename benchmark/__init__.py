@@ -1,3 +1,3 @@
-# Benchmark package — the analog of reference python/benchmark/ (§2.13):
-# data generation + a CLI registry of per-algorithm benchmarks comparing the
-# TPU backend against the strongest same-host CPU baseline (sklearn).
+# Benchmark data — the analog of reference python/benchmark/ (§2.13):
+# seeded synthetic data generation (`gen_data.py`, `gen_data_distributed.py`).
+# The chip benchmark itself is `chipbench/`.

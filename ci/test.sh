@@ -1,9 +1,9 @@
 #!/usr/bin/env bash
 #
 # CI gate — the analog of the reference's ci/test.sh (lint + unit tests +
-# benchmark smoke; pre-merge vs nightly split via --runslow).
+# smokes; pre-merge vs nightly split via --runslow).
 #
-#   ./ci/test.sh            # pre-merge: lint + full suite + bench smoke
+#   ./ci/test.sh            # pre-merge: lint + full suite + smokes
 #   ./ci/test.sh --runslow  # nightly: adds slow-marked scale tests
 #   ./ci/test.sh --fast     # iteration tier: lint + framework-contract
 #                           # subset (~4 min); NOT a merge gate
@@ -68,8 +68,7 @@ mods = [
     "spark_rapids_ml_tpu.streaming", "spark_rapids_ml_tpu.metrics",
     "spark_rapids_ml_tpu.stats", "spark_rapids_ml_tpu.monitor",
     "spark_rapids_ml_tpu.resilience", "spark_rapids_ml_tpu.telemetry",
-    "benchmark.benchmark_runner", "benchmark.gen_data",
-    "benchmark.gen_data_distributed",
+    "benchmark.gen_data", "benchmark.gen_data_distributed",
 ]
 for m in mods:
     importlib.import_module(m)
@@ -125,10 +124,9 @@ run_batch tests/test_common_estimator.py tests/test_metrics.py \
 run_batch tests/test_logistic_regression.py tests/test_sparse_logreg.py \
     tests/test_f32_and_weights.py tests/test_random_forest.py "$@"
 run_batch tests/test_knn.py tests/test_ann.py tests/test_dbscan.py \
-    tests/test_pallas_knn.py tests/test_sparse_fit.py \
-    tests/test_staging_pipeline.py "$@"
+    tests/test_sparse_fit.py tests/test_staging_pipeline.py "$@"
 run_batch tests/test_umap.py tests/test_streaming.py \
-    tests/test_benchmark.py tests/test_connect_plugin.py \
+    tests/test_gen_data.py tests/test_connect_plugin.py \
     tests/test_jvm_protocol.py tests/test_native.py tests/test_tracing.py \
     tests/test_resilience.py tests/test_elastic.py tests/test_telemetry.py \
     tests/test_serving.py tests/test_serving_control.py \
@@ -903,10 +901,6 @@ with tempfile.TemporaryDirectory() as td:
     assert "pass-a" in stacks and "pass-b" in stacks
     print("hang-doctor smoke OK:", wf["cycles"][0]["description"])
 EOF
-
-echo "== pod benchmark smoke (2-process jax.distributed) =="
-python benchmark/pod/launch.py --num_processes 2 --devices_per_process 2 \
-    -- kmeans --num_rows 20000 --num_cols 16 --mode tpu --max_iter 10
 
 echo "== multi-host data path smoke: sharded ingest, wire reduce, dead rank =="
 # three contracts in one 2-process run (wire reduce backend, so it holds

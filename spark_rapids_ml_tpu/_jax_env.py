@@ -1,6 +1,6 @@
 #
 # Where the persistent XLA compilation cache lives — the one place in the
-# repo that names it.  Entry scripts (chip_smoke.py, benchmark/*.py)
+# repo that names it.  Entry scripts (chip_smoke.py, chipbench/run.py)
 # call `configure_compile_cache()` before their first use
 # of jax; the library itself sets nothing on import.
 #

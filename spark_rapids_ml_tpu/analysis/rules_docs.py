@@ -1,9 +1,9 @@
 #
 # module-ref rule — stale prose.  Comments, docstrings and the docs
 # pages are full of cross-references ("see parallel/mesh.py", "the
-# `pallas_knn` conf"); when a file is renamed or a conf retired those
-# references rot silently (the `pallas_knn_enabled`-era comments PR-2
-# cleaned up by hand).  Two checks:
+# `distance_precision` conf"); when a file is renamed or a conf retired
+# those references rot silently, and used to be cleaned up by hand.
+# Two checks:
 #
 #   - a path-like reference with a directory component
 #     (`resilience/faults.py`, `docs/performance.md`) must resolve

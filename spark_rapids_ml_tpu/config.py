@@ -216,21 +216,6 @@ _DEFAULTS: Dict[str, Any] = {
     # instead; this conf arms sites for whole-process runs (CI smoke,
     # bench rehearsals).
     "fault_inject_spec": "",
-    # Fused Pallas distance+top-k kernel for brute-force kNN (the cuVS
-    # fusedL2Knn analog, ops/pallas_knn.py).  RETIRED from the default
-    # path ("win or delete", ROADMAP Design 5): three on-chip rounds
-    # measured it LOSING — 0.28x, 0.38x, and in PR 21's chip run 0.21x
-    # XLA's matmul+top_k at 100k x 10k x k=32, there also inexact (one
-    # bf16 pass: 85 % neighbour agreement with the exact-f32 XLA path) —
-    # and the "auto" measured probe burned a cold compile +
-    # 6 timed evaluations per shape bucket of warm-up time re-discovering
-    # that verdict every process.  "off" (default) uses the XLA
-    # blocked/coltiled kernels outright; "auto" re-enables the per-bucket
-    # measured probe (ops/knn.py knn_topk_single, the umap_kernel=auto
-    # discipline) for future backends where the tradeoff may flip; "on"
-    # forces the fused kernel everywhere (CPU runs the Pallas
-    # interpreter — slow, experiments/tests only).
-    "pallas_knn": "off",
     # MXU matmul precision for rank/threshold-critical distance kernels
     # (kNN/ANN/DBSCAN; ops/precision.py).  "highest" = exact f32 (cuML
     # parity; TPU default bf16 passes mis-rank near-tied neighbors —

@@ -184,23 +184,3 @@ def prefetch_iter(it, depth: int):
             yield item
     finally:
         stop.set()
-
-
-def host_load_metadata() -> dict:
-    """Self-describing-artifact host metadata (bench/rehearsal/ANN JSON):
-    loadavg, cpu count, and a `contended` flag meaning FOREIGN load —
-    ~1.0 is allowed for the measuring process itself, which alone pins
-    loadavg to 1 on a 1-core host.  One owner so the bench and the
-    run-once scripts can never disagree on what 'contended' means."""
-    import os
-
-    try:
-        load = os.getloadavg()
-    except OSError:
-        return {}
-    ncpu = os.cpu_count() or 1
-    return {
-        "host_loadavg_start": [round(v, 2) for v in load],
-        "host_cpus": ncpu,
-        "contended": load[0] > 1.0 + 0.5 * ncpu,
-    }

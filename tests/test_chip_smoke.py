@@ -305,31 +305,8 @@ def test_native_library_from_another_machine_is_rebuilt(monkeypatch, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# a measuring script without a TPU stops; one process for each chip
+# the dry run's devices and the config's explicit keys
 # ---------------------------------------------------------------------------
-
-
-def test_unpinned_measurement_without_a_tpu_exits(monkeypatch, capsys):
-    from benchmark.base import NO_TPU_RC, require_tpu_unless_cpu_pinned
-
-    # the CI path: the caller pinned the CPU and gets its label
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert require_tpu_unless_cpu_pinned("t") == "cpu x8"
-    # unpinned, jax's silent CPU fallback is refused
-    monkeypatch.delenv("JAX_PLATFORMS")
-    with pytest.raises(SystemExit) as ei:
-        require_tpu_unless_cpu_pinned("t")
-    assert ei.value.code == NO_TPU_RC
-    assert "t: no TPU" in capsys.readouterr().err
-
-
-def test_pod_launcher_refuses_local_emulation_on_tpus(capsys):
-    from benchmark.pod import launch
-
-    with pytest.raises(SystemExit) as ei:
-        launch.main(["--platform", "tpu", "--", "kmeans"])
-    assert ei.value.code == 2
-    assert "local emulation is a CPU mode" in capsys.readouterr().err
 
 
 def test_dryrun_takes_its_devices_from_the_caller():

@@ -364,9 +364,9 @@ def test_plan_reads_the_mesh_from_the_rows(as_on_tpu):
 
 
 def test_no_conf_key_selects_the_kernel():
-    """Who takes the kernel is read from the input: no key was added (101
-    keys, PERF.md §6, PR 31) and none names it."""
-    assert len(_DEFAULTS) == 101
+    """Who takes the kernel is read from the input: no key was added for
+    it (100 keys) and none names it."""
+    assert len(_DEFAULTS) == 100
     assert not [k for k in _DEFAULTS if "logistic" in k or "one_pass" in k]
 
 
